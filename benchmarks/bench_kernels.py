@@ -1,16 +1,13 @@
 """Registry-driven Pallas kernel microbench (DESIGN.md §16).
 
-Times every kernel in ``analysis/pallas_check.default_registry()`` — the
-same 10 entries the tile prover walks, so bench coverage and bounds
-coverage cannot drift apart — and joins each against its XLA HLO cost:
-us/call plus achieved GFLOP/s / GB/s / roofline fraction vs the TPU-v5e
-bound, per (kernel, shape, format).  Results land in BENCH_kernels.json
-with a full provenance stamp and a ledger row.
+Times every kernel in ``obs.profile.default_registry()`` and records each
+with its XLA HLO cost: us/call per (kernel, shape, format), plus achieved
+GFLOP/s / GB/s / roofline fraction when the run is on a chip with published
+peaks (``roofline/hw.py``).  Results land in BENCH_kernels.json with a full
+provenance stamp and a ledger row.
 
-Absolute numbers on this container are CPU interpret-mode times — the
-roofline fractions are deliberately tiny; the artifact's job is to stop
-those numbers masquerading as hardware results and to give TPU runs a
-trajectory to land on.
+Off a TPU the kernels run in the Pallas interpreter: those times are
+control-flow checks, not speed results, and no roofline share is reported.
 """
 from __future__ import annotations
 

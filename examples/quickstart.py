@@ -22,7 +22,7 @@ w = jax.random.normal(jax.random.PRNGKey(1), (64,))
 g = jax.grad(lambda x: jnp.sum(hyft_softmax(x, HYFT32) * w))(z)
 print("hyft-grad norm:", float(jnp.linalg.norm(g)))
 
-# 3. the Pallas kernel (interpret mode on CPU, compiled on TPU)
+# 3. the Pallas kernel (interpreted on CPU, compiled by Mosaic on a TPU)
 s_kernel = ops.hyft_softmax(z, HYFT16)
 print("kernel == emulation:",
       bool(jnp.all(s_kernel == hyft_softmax(z, HYFT16))))

@@ -1,13 +1,11 @@
-"""Static analysis driver (DESIGN.md #14): run the four invariant passes
+"""Static analysis driver (DESIGN.md #14): run the three invariant passes
 and exit non-zero on any finding.
 
     PYTHONPATH=src python scripts/check.py --all [--verbose]
-    PYTHONPATH=src python scripts/check.py --lint --pallas
+    PYTHONPATH=src python scripts/check.py --lint --retrace
 
 Passes:
   --jaxpr    format-flow audit of the real serving/training executables
-  --pallas   BlockSpec tile bounds / divisibility / ref-dtype check over
-             the kernel registry
   --retrace  steady-state serving (warm buckets, 8 admissions) compiles
              nothing new, for the continuous and spec schedulers
   --lint     AST rules over src/repro and scripts/ (traced-bool, host-call,
@@ -32,7 +30,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--jaxpr", action="store_true")
-    ap.add_argument("--pallas", action="store_true")
     ap.add_argument("--retrace", action="store_true")
     ap.add_argument("--lint", action="store_true")
     ap.add_argument("--bench-regress", action="store_true",
@@ -40,10 +37,9 @@ def main(argv=None) -> int:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
-    explicit = (args.jaxpr or args.pallas or args.retrace or args.lint
-                or args.bench_regress)
+    explicit = args.jaxpr or args.retrace or args.lint or args.bench_regress
     if args.all or not explicit:
-        args.jaxpr = args.pallas = args.retrace = args.lint = True
+        args.jaxpr = args.retrace = args.lint = True
 
     # lint is pure AST -- run it first so syntax-level breakage is reported
     # even when tracing-based passes cannot build the executables
@@ -57,9 +53,6 @@ def main(argv=None) -> int:
         passes.append(("jaxpr", lambda: jaxpr_audit.run(stats=stats)))
     else:
         stats = {}
-    if args.pallas:
-        from repro.analysis import pallas_check
-        passes.append(("pallas", lambda: pallas_check.run()))
     if args.retrace:
         from repro.analysis import retrace
         passes.append(("retrace", lambda: retrace.run()))

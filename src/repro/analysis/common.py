@@ -58,7 +58,7 @@ def walk_eqns(jaxpr: jcore.Jaxpr,
 def eqn_location(eqn: jcore.JaxprEqn) -> str:
     """Best-effort ``file:line`` for an eqn, preferring repo frames over the
     caller's trace harness."""
-    frames = list(source_info_util.user_frames(eqn.source_info))
+    frames = list(source_info_util.user_frames(eqn.source_info.traceback))
     for fr in frames:
         if "/src/repro/" in fr.file_name.replace("\\", "/"):
             return f"{fr.file_name}:{fr.start_line}"

@@ -188,7 +188,7 @@ def default_targets() -> list[AuditTarget]:
         scfg = ServeConfig(max_len=L, cache_dtype=cache_dtype, n_slots=n,
                            decode_burst=4, attn_mode="kernel", draft_k=K)
         m = resolve_attn_mode(model, scfg.attn_mode)
-        bkey = scheduler._burst_key_cfg(scfg)
+        bkey = scheduler.exec_key_cfg(scfg)
         cache = m.init_cache(params, n, L, cache_dtype)
         return scfg, bkey, m, cache
 

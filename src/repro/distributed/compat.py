@@ -1,26 +1,18 @@
-"""Version-portable imports/constructors for fast-moving JAX APIs.
+"""The one spelling of fast-moving JAX APIs, for src *and* tests.
 
-One blessed spelling for src *and* tests — when JAX moves or reshapes an
-API, this is the only file that chases it.
+Written for the installed JAX 0.9 line (``requirements.txt``); when JAX
+moves an API again, this is the only file that chases it.
 """
 from __future__ import annotations
 
-try:  # newer JAX exports shard_map at the top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # the long-standing experimental home
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax import shard_map  # noqa: F401
 
 
 def abstract_mesh(shape, axis_names):
-    """Construct ``jax.sharding.AbstractMesh`` across JAX versions.
+    """``jax.sharding.AbstractMesh`` of ``shape`` over ``axis_names``.
 
-    Newer JAX takes one ``((name, size), ...)`` shape tuple; older releases
-    took ``(shape, axis_names)``.  Spec math on an AbstractMesh needs no
-    device allocation, so production geometries (16x16, 2x16x16) are
-    testable on a single CPU.
+    Spec math on an AbstractMesh needs no device allocation, so production
+    geometries (16x16, 2x16x16) are testable on a single CPU.
     """
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
+    return AbstractMesh(tuple(shape), tuple(axis_names))

@@ -21,7 +21,12 @@ so they stay resident in VMEM and serve as carry; finalization happens at
 the last step.
 
 Mask contract (DESIGN.md §3): ``kv_len_mask`` is an optional float32
-``(B, Sk)`` array, 1.0 = valid KV position, 0.0 = padded/invalid.  Masking
+``(B, Sk)`` array, 1.0 = valid KV position, 0.0 = padded/invalid.  It rides
+into the kernels as ``(B, 1, Sk)`` so each block is a ``(1, bk)`` lane row:
+Mosaic tiles the last two block dims, which must be multiples of (8, 128)
+or span the array, so a ``(1, bk)`` block of a 2-D ``(B, Sk)`` array is
+refused while the 3-D form compiles.  Per-row stats (m, l, delta) travel
+as ``(BH, Sq, 1)`` columns for the same reason.  Masking
 happens on the *float scores before FP2FX* (identical to the unfused path):
 invalid scores become ``NEG_BIG``, the converter saturates them to the
 fixed-point minimum and the exponent unit flushes their probability to zero.
@@ -118,8 +123,8 @@ def _flash_fwd_kernel(*refs, cfg: HyftConfig, sm_scale: float, causal: bool,
         qi = q_offset + iq * block_q + jax.lax.broadcasted_iota(I32, z.shape, 0)
         ki = ik * block_k + jax.lax.broadcasted_iota(I32, z.shape, 1)
         z = jnp.where(qi >= ki, z, NEG_BIG)
-    if has_mask:  # pre-FP2FX, same as the unfused path
-        z = jnp.where(mask_ref[0][None, :] > F32(0), z, NEG_BIG)
+    if has_mask:  # pre-FP2FX, same as the unfused path; (1, bk) row
+        z = jnp.where(mask_ref[0] > F32(0), z, NEG_BIG)
 
     # ---- Hyft stage 1: FP2FX + (strided) block max, merged with running max
     z_raw = nm.fp2fx(z, cfg.frac_bits, cfg.total_bits)
@@ -160,7 +165,7 @@ def _flash_fwd_impl(q3, k3, v3, maskf, *, cfg: HyftConfig, sm_scale: float,
     """Blocked forward on pre-padded 3D operands.
 
     q3: (BH, Sq, D); k3/v3: (BHkv, Sk, D); maskf: (B, Sk) float or None.
-    Returns (o (BH,Sq,D) f32, m (BH,Sq) i32 raw, l (BH,Sq) f32).
+    Returns (o (BH,Sq,D) f32, m (BH,Sq,1) i32 raw, l (BH,Sq,1) f32).
     """
     BH, Sq, D = q3.shape
     Sk = k3.shape[1]
@@ -180,8 +185,8 @@ def _flash_fwd_impl(q3, k3, v3, maskf, *, cfg: HyftConfig, sm_scale: float,
     operands = [q3, k3, v3]
     if has_mask:
         in_specs.append(
-            pl.BlockSpec((1, bk), lambda b, i, j, h=Hq_per_b: (b // h, j)))
-        operands.append(maskf)
+            pl.BlockSpec((1, 1, bk), lambda b, i, j, h=Hq_per_b: (b // h, 0, j)))
+        operands.append(maskf[:, None, :])
     o, m_st, l_st = pl.pallas_call(
         kern,
         grid=grid,
@@ -198,7 +203,8 @@ def _flash_fwd_impl(q3, k3, v3, maskf, *, cfg: HyftConfig, sm_scale: float,
         ],
         interpret=interpret,
     )(*operands)
-    return o, m_st[:, 0].reshape(BH, Sq), l_st[:, 0].reshape(BH, Sq)
+    return (o, m_st[:, :1].reshape(BH, Sq, 1),
+            l_st[:, :1].reshape(BH, Sq, 1))
 
 
 # --------------------------------------------------------------------------
@@ -208,7 +214,8 @@ def _flash_fwd_impl(q3, k3, v3, maskf, *, cfg: HyftConfig, sm_scale: float,
 
 def _recompute_probs(q, k, mask_row, m_row, l_row, *, cfg, sm_scale, causal,
                      qi0, ki0):
-    """Hyft probabilities of one (bq, bk) tile from the saved final row stats.
+    """Hyft probabilities of one (bq, bk) tile from the saved final row stats
+    (``mask_row`` (1, bk) or None; ``m_row``/``l_row`` (bq, 1) columns).
 
     Identical arithmetic to the chunked path's ``probs``: elementwise, so the
     result is independent of how the forward blocked the KV axis."""
@@ -219,7 +226,7 @@ def _recompute_probs(q, k, mask_row, m_row, l_row, *, cfg, sm_scale, causal,
         ki = ki0 + jax.lax.broadcasted_iota(I32, z.shape, 1)
         z = jnp.where(qi >= ki, z, NEG_BIG)
     if mask_row is not None:
-        z = jnp.where(mask_row[None, :] > F32(0), z, NEG_BIG)
+        z = jnp.where(mask_row > F32(0), z, NEG_BIG)
     z_raw = nm.fp2fx(z, cfg.frac_bits, cfg.total_bits)
     e, m = nm.exp_unit(z_raw - m_row, cfg.frac_bits, cfg.mant_bits)
     e_b, m_b = nm.lod_refloat(l_row, cfg.mant_bits)
@@ -247,11 +254,11 @@ def _flash_bwd_dq_kernel(*refs, cfg: HyftConfig, sm_scale: float,
     do = do_ref[0].astype(F32)
     p = _recompute_probs(
         q, k, mask_ref[0] if has_mask else None,
-        m_ref[0][:, None], l_ref[0][:, None], cfg=cfg, sm_scale=sm_scale,
+        m_ref[0], l_ref[0], cfg=cfg, sm_scale=sm_scale,
         causal=causal, qi0=q_offset + iq * block_q, ki0=ik * block_k)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=F32)
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0])
     dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                              preferred_element_type=F32) * sm_scale
     dq_ref[...] = dq_ref[...] + dq[None]
@@ -281,13 +288,13 @@ def _flash_bwd_dkv_kernel(*refs, cfg: HyftConfig, sm_scale: float,
     do = do_ref[0].astype(F32)
     p = _recompute_probs(
         q, k, mask_ref[0] if has_mask else None,
-        m_ref[0][:, None], l_ref[0][:, None], cfg=cfg, sm_scale=sm_scale,
+        m_ref[0], l_ref[0], cfg=cfg, sm_scale=sm_scale,
         causal=causal, qi0=q_offset + iq * block_q, ki0=ik * block_k)
     dv = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                              preferred_element_type=F32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=F32)
-    ds = p * (dp - delta_ref[0][:, None])
+    ds = p * (dp - delta_ref[0])
     dk = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                              preferred_element_type=F32) * sm_scale
     dk_ref[...] = dk_ref[...] + dk[None]
@@ -302,10 +309,12 @@ def _flash_bwd_impl(q3, k3, v3, maskf, do3, o3, m2, l2, *, cfg, sm_scale,
     nq, nk = Sq // bq, Sk // bk
     has_mask = maskf is not None
     hq_per_b = BH // batch
-    delta = jnp.sum(do3.astype(F32) * o3.astype(F32), axis=-1)  # (BH, Sq)
+    delta = jnp.sum(do3.astype(F32) * o3.astype(F32), axis=-1,
+                    keepdims=True)  # (BH, Sq, 1), like m2/l2
+    mask3 = maskf[:, None, :] if has_mask else None
 
     # ---- dq: (bh, q, kv) grid, kv innermost, dq block as carry ------------
-    row_spec = pl.BlockSpec((1, bq), lambda b, i, j: (b, i))
+    row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, D), lambda b, i, j, g=group: (b // g, j, 0)),
@@ -315,9 +324,9 @@ def _flash_bwd_impl(q3, k3, v3, maskf, do3, o3, m2, l2, *, cfg, sm_scale,
     ]
     operands = [q3, k3, v3, do3, delta, m2, l2]
     if has_mask:
-        in_specs.append(
-            pl.BlockSpec((1, bk), lambda b, i, j, h=hq_per_b: (b // h, j)))
-        operands.append(maskf)
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bk), lambda b, i, j, h=hq_per_b: (b // h, 0, j)))
+        operands.append(mask3)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, cfg=cfg, sm_scale=sm_scale,
                           causal=causal, block_q=bq, block_k=bk,
@@ -335,7 +344,7 @@ def _flash_bwd_impl(q3, k3, v3, maskf, do3, o3, m2, l2, *, cfg, sm_scale,
     qrow3 = pl.BlockSpec(
         (1, bq, D), lambda b, j, t, g=group, n=nq: (b * g + t // n, t % n, 0))
     qrow2 = pl.BlockSpec(
-        (1, bq), lambda b, j, t, g=group, n=nq: (b * g + t // n, t % n))
+        (1, bq, 1), lambda b, j, t, g=group, n=nq: (b * g + t // n, t % n, 0))
     in_specs = [
         qrow3, qrow3, qrow2, qrow2, qrow2,
         pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
@@ -344,9 +353,9 @@ def _flash_bwd_impl(q3, k3, v3, maskf, do3, o3, m2, l2, *, cfg, sm_scale,
     operands = [q3, do3, delta, m2, l2, k3, v3]
     hkv_per_b = BHkv // batch
     if has_mask:
-        in_specs.append(
-            pl.BlockSpec((1, bk), lambda b, j, t, h=hkv_per_b: (b // h, j)))
-        operands.append(maskf)
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bk), lambda b, j, t, h=hkv_per_b: (b // h, 0, j)))
+        operands.append(mask3)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, cfg=cfg, sm_scale=sm_scale,
                           causal=causal, block_q=bq, block_k=bk, nq=nq,
@@ -423,7 +432,7 @@ _flash_attn.defvjp(_flash_attn_fwd, _flash_attn_bwd)
 def flash_hyft_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          cfg: HyftConfig, sm_scale: float | None = None,
                          causal: bool = True, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = True,
+                         block_k: int = 128, *, interpret: bool,
                          return_stats: bool = False,
                          kv_len_mask: jax.Array | None = None,
                          q_offset: int = 0):
@@ -446,7 +455,8 @@ def flash_hyft_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     _, Hkv, Sk, _ = k.shape
     assert Hq % Hkv == 0
     scale = sm_scale if sm_scale is not None else D ** -0.5
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
+    # sublane-aligned query rows: a 1-row decode query pads to 8 rows
+    bq, bk = min(block_q, -(-Sq // 8) * 8), min(block_k, Sk)
     pad_q, pad_k = (-Sq) % bq, (-Sk) % bk
     maskf = None
     if kv_len_mask is not None:
@@ -498,7 +508,7 @@ def _decode_tile(q, k, v, maskrow, cfg: HyftConfig, sm_scale: float):
     """L1 of the decode tree: local Hyft stages 1-2 for one KV split.
 
     q (gp, dh) — GQA group folded into rows; k/v (bk, dh) fp32 (already
-    dequantized); maskrow (bk,) shared across rows, or (gp, bk) per-row
+    dequantized); maskrow (1, bk) shared across rows, or (gp, bk) per-row
     (the verify kernel's causal-within-draft mask).  Returns (acc (gp, dh),
     m_loc (gp, 1) raw, l_loc (gp, 1)) — the split-local (max, fixed-sum,
     acc) stats.  Shared verbatim by the contiguous split-K kernel, the
@@ -507,8 +517,7 @@ def _decode_tile(q, k, v, maskrow, cfg: HyftConfig, sm_scale: float):
     """
     z = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32) * sm_scale
-    mrow = maskrow if maskrow.ndim == 2 else maskrow[None, :]
-    z = jnp.where(mrow > F32(0), z, NEG_BIG)
+    z = jnp.where(maskrow > F32(0), z, NEG_BIG)
     z_raw = nm.fp2fx(z, cfg.frac_bits, cfg.total_bits)
     zsub = z_raw[:, :: cfg.step] if cfg.step > 1 else z_raw
     m_loc = jnp.max(zsub, axis=-1, keepdims=True)
@@ -538,29 +547,128 @@ def _splitk_combine(acc, m_st, l_st, cfg: HyftConfig):
     return hyft_finalize(acc_glob, l_glob[..., None], cfg)
 
 
-def _decode_fwd_kernel(*refs, cfg: HyftConfig, sm_scale: float,
-                       quantized: bool):
+def _dequant(x, s_ref, h):
+    """Fused fp2fx8 dequant of one (bk, D) K/V tile.  ``s_ref`` holds the
+    split's (Hkv, bk) per-(head, position) scales as lane rows (a block that
+    spans the head axis, so Mosaic accepts any split width); head ``h``'s row
+    is broadcast over D and transposed into the (bk, D) multiplier — the
+    very products of ``x * scale[:, None]``."""
+    row = s_ref[0, pl.ds(h, 1), :]                      # (1, bk)
+    return x * jnp.broadcast_to(row, (x.shape[1], row.shape[1])).T
+
+
+def _splitk_kernel(*refs, cfg: HyftConfig, sm_scale: float, quantized: bool,
+                   paged: bool, group: int):
+    """One (batch, KV split, kv head) step of the split-K machine: the L1
+    tile stats through ``_decode_tile``.  Shared by the contiguous and paged
+    decode and verify kernels, so a page IS a split and the bitwise story
+    reduces to the combine order.  ``group`` > 0 marks the verify layout:
+    the (sp, bk) per-draft-lane mask expands over the GQA group rows."""
+    if paged:
+        refs = refs[1:]  # the block table is consumed by the index maps
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, acc_ref, m_ref, l_ref = refs
     else:
         q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref = refs
-    q = q_ref[0].astype(F32)              # (gp, dh) — GQA group as rows
-    k = k_ref[0].astype(F32)              # (bk, dh)
-    v = v_ref[0].astype(F32)
+    q = q_ref[0].astype(F32)              # (rows, dh) — GQA group (x draft)
+    if paged:                             # (ps, dh) — one physical page
+        k, v, mask = k_ref[0, 0], v_ref[0, 0], mask_ref[0, 0]
+    else:                                 # (bk, dh)
+        k, v, mask = k_ref[0], v_ref[0], mask_ref[0]
+    k, v = k.astype(F32), v.astype(F32)
     if quantized:                         # dequant fused into the load
-        k = k * ks_ref[0][:, None]
-        v = v * vs_ref[0][:, None]
-    acc, m_loc, l_loc = _decode_tile(q, k, v, mask_ref[0], cfg, sm_scale)
+        h = pl.program_id(2)
+        k, v = _dequant(k, ks_ref, h), _dequant(v, vs_ref, h)
+    if group:
+        mask = _verify_mask_rows(mask, group)
+    acc, m_loc, l_loc = _decode_tile(q, k, v, mask, cfg, sm_scale)
     acc_ref[...] = acc[None, None]
     m_ref[...] = jnp.broadcast_to(m_loc[None, None], m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_loc[None, None], l_ref.shape)
+
+
+def _splitk_stats(q3, k, v, mask, *, k_scale, v_scale, block_tables, Hkv: int,
+                  bk: int, cfg: HyftConfig, sm_scale: float, group: int,
+                  interpret: bool):
+    """Run ``_splitk_kernel`` over a (batch, KV split, kv head) grid and
+    return the per-split local stats (acc, m, l) for ``_splitk_combine``.
+
+    q3: (B * Hkv, rows, D).  Contiguous layout: k/v (B * Hkv, Sk, D),
+    scales (B, Hkv, Sk), mask (B, mr, Sk), splits of ``bk``.  Paged layout
+    (``block_tables`` (B, nb)): k/v (n_pages, Hkv, ps, D), scales
+    (n_pages, Hkv, ps), mask (B, nb, mr, ps); pages are the splits and
+    ``bk`` is the page size.  The kv head is the innermost grid axis, so a
+    scale block (all heads of one split) stays resident across it.
+    """
+    BHkv, rows, D = q3.shape
+    B = BHkv // Hkv
+    paged = block_tables is not None
+    quantized = k_scale is not None
+    mr = mask.shape[-2]
+    if paged:
+        ns = block_tables.shape[1]
+        kv_spec = pl.BlockSpec((1, 1, bk, D),
+                               lambda b, j, h, bt: (bt[b, j], h, 0, 0))
+        s_spec = pl.BlockSpec((1, Hkv, bk), lambda b, j, h, bt: (bt[b, j], 0, 0))
+        m_spec = pl.BlockSpec((1, 1, mr, bk), lambda b, j, h, bt: (b, j, 0, 0))
+    else:
+        ns = k.shape[1] // bk
+        kv_spec = pl.BlockSpec((1, bk, D), lambda b, j, h: (b * Hkv + h, j, 0))
+        s_spec = pl.BlockSpec((1, Hkv, bk), lambda b, j, h: (b, 0, j))
+        m_spec = pl.BlockSpec((1, mr, bk), lambda b, j, h: (b, 0, j))
+    in_specs = [pl.BlockSpec((1, rows, D),
+                             lambda b, j, h, *_: (b * Hkv + h, 0, 0)),
+                kv_spec, kv_spec]
+    operands = [q3, k, v]
+    if quantized:
+        in_specs += [s_spec, s_spec]
+        operands += [k_scale, v_scale]
+    in_specs.append(m_spec)
+    operands.append(mask)
+    out_specs = [
+        pl.BlockSpec((1, 1, rows, w), lambda b, j, h, *_: (b * Hkv + h, j, 0, 0))
+        for w in (D, 128, 128)]
+    out_shape = [jax.ShapeDtypeStruct((BHkv, ns, rows, w), dt)
+                 for w, dt in ((D, F32), (128, I32), (128, F32))]
+    kern = functools.partial(_splitk_kernel, cfg=cfg, sm_scale=sm_scale,
+                             quantized=quantized, paged=paged, group=group)
+    if paged:
+        from jax.experimental.pallas import tpu as pltpu
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, ns, Hkv), in_specs=in_specs,
+            out_specs=out_specs)
+        return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
+                              interpret=interpret)(
+            block_tables.astype(I32), *operands)
+    return pl.pallas_call(kern, grid=(B, ns, Hkv), in_specs=in_specs,
+                          out_specs=out_specs, out_shape=out_shape,
+                          interpret=interpret)(*operands)
+
+
+def _pad_kv(k, v, k_scale, v_scale, pad_k):
+    """Zero-pad contiguous K/V (and their scales) along the KV axis."""
+    k = _pad0(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    v = _pad0(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    if k_scale is not None:
+        k_scale = _pad0(k_scale, ((0, 0), (0, 0), (0, pad_k)))
+        v_scale = _pad0(v_scale, ((0, 0), (0, 0), (0, pad_k)))
+    return k, v, k_scale, v_scale
+
+
+def _group_rows(q, Hkv: int, rows: int):
+    """(B, Hq, 1, D) decode queries -> (B * Hkv, rows, D), the GQA group
+    folded into sublane-aligned tile rows."""
+    B, Hq, _, D = q.shape
+    q3 = q[:, :, 0, :].reshape(B, Hkv, Hq // Hkv, D)
+    q3 = _pad0(q3, ((0, 0), (0, 0), (0, rows - Hq // Hkv), (0, 0)))
+    return q3.reshape(B * Hkv, rows, D)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "cfg", "sm_scale", "block_k", "interpret"))
 def flash_hyft_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                       cfg: HyftConfig, sm_scale: float | None = None,
-                      block_k: int = 256, interpret: bool = True,
+                      block_k: int = 256, *, interpret: bool,
                       kv_len_mask: jax.Array | None = None,
                       k_scale: jax.Array | None = None,
                       v_scale: jax.Array | None = None):
@@ -586,53 +694,16 @@ def flash_hyft_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     maskf = (kv_len_mask.astype(F32) if kv_len_mask is not None
              else jnp.ones((B, Sk), F32))
     if pad_k:
-        k = _pad0(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-        v = _pad0(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        k, v, k_scale, v_scale = _pad_kv(k, v, k_scale, v_scale, pad_k)
         maskf = _pad0(maskf, ((0, 0), (0, pad_k)))
-        if k_scale is not None:
-            k_scale = _pad0(k_scale, ((0, 0), (0, 0), (0, pad_k)))
-            v_scale = _pad0(v_scale, ((0, 0), (0, 0), (0, pad_k)))
     Skp = Sk + pad_k
-    ns = Skp // bk
     gp = -(-g // 8) * 8  # sublane-aligned group rows
 
-    q3 = q[:, :, 0, :].reshape(B, Hkv, g, D)
-    q3 = _pad0(q3, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    q3 = q3.reshape(B * Hkv, gp, D)
-    k3 = k.reshape(B * Hkv, Skp, D)
-    v3 = v.reshape(B * Hkv, Skp, D)
-
-    quantized = k_scale is not None
-    in_specs = [
-        pl.BlockSpec((1, gp, D), lambda b, j: (b, 0, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-    ]
-    operands = [q3, k3, v3]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, bk), lambda b, j: (b, j))] * 2
-        operands += [k_scale.reshape(B * Hkv, Skp),
-                     v_scale.reshape(B * Hkv, Skp)]
-    in_specs.append(pl.BlockSpec((1, bk), lambda b, j, h=Hkv: (b // h, j)))
-    operands.append(maskf)
-
-    acc, m_st, l_st = pl.pallas_call(
-        functools.partial(_decode_fwd_kernel, cfg=cfg, sm_scale=scale,
-                          quantized=quantized),
-        grid=(B * Hkv, ns),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, gp, D), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, 1, gp, 128), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, 1, gp, 128), lambda b, j: (b, j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, ns, gp, D), F32),
-            jax.ShapeDtypeStruct((B * Hkv, ns, gp, 128), I32),
-            jax.ShapeDtypeStruct((B * Hkv, ns, gp, 128), F32),
-        ],
-        interpret=interpret,
-    )(*operands)
+    acc, m_st, l_st = _splitk_stats(
+        _group_rows(q, Hkv, gp), k.reshape(B * Hkv, Skp, D),
+        v.reshape(B * Hkv, Skp, D), maskf[:, None, :], k_scale=k_scale,
+        v_scale=v_scale, block_tables=None, Hkv=Hkv, bk=bk, cfg=cfg,
+        sm_scale=scale, group=0, interpret=interpret)
 
     # ---- L2: integer-max / fixed-sum tree combine across KV splits
     out = _splitk_combine(acc, m_st, l_st, cfg)
@@ -649,39 +720,21 @@ def flash_hyft_decode(q: jax.Array, k: jax.Array, v: jax.Array,
 # and a per-sequence block table mapping virtual KV block j to a physical
 # page.  The kernel below is the same split-K machine with pages as splits:
 # the block table rides in as a scalar-prefetch operand so the BlockSpec
-# index maps can route grid step (b, j) to physical page bt[b, j] (the DMA
-# for page j+1 issues while page j computes — on TPU the gather is free).
-# Each page emits the same local (max, fixed-sum, acc) stats via
+# index maps can route grid step (b, j, h) to physical page bt[b, j] (the
+# DMA for the next page issues while this one computes — on TPU the gather
+# is free).  Each page emits the same local (max, fixed-sum, acc) stats via
 # ``_decode_tile`` and the combine is ``_splitk_combine`` — so with pages
 # laid out sequentially (bt[b, j] == j over a contiguous pool) the result
 # is bitwise identical to ``flash_hyft_decode`` at block_k == page_size.
-
-
-def _paged_decode_kernel(*refs, cfg: HyftConfig, sm_scale: float,
-                         quantized: bool):
-    if quantized:
-        (bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref,
-         acc_ref, m_ref, l_ref) = refs
-    else:
-        bt_ref, q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref = refs
-    del bt_ref  # consumed by the index maps (scalar prefetch)
-    q = q_ref[0].astype(F32)              # (gp, dh)
-    k = k_ref[0, 0].astype(F32)           # (ps, dh) — one physical page
-    v = v_ref[0, 0].astype(F32)
-    if quantized:                         # dequant fused into the page load
-        k = k * ks_ref[0, 0][:, None]
-        v = v * vs_ref[0, 0][:, None]
-    acc, m_loc, l_loc = _decode_tile(q, k, v, mask_ref[0], cfg, sm_scale)
-    acc_ref[...] = acc[None, None]
-    m_ref[...] = jnp.broadcast_to(m_loc[None, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_loc[None, None], l_ref.shape)
+# The validity mask rides in as (B, nb, 1, ps), one full-width row block
+# per page, so any page size compiles (int8 pages down to 16 tokens).
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "sm_scale", "interpret"))
 def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, block_tables: jax.Array,
                             cfg: HyftConfig, sm_scale: float | None = None,
-                            interpret: bool = True,
+                            *, interpret: bool,
                             kv_len_mask: jax.Array | None = None,
                             k_scale: jax.Array | None = None,
                             v_scale: jax.Array | None = None):
@@ -701,8 +754,6 @@ def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
     contiguous pool this is bitwise identical to ``flash_hyft_decode`` at
     ``block_k == page_size`` (same tile arithmetic, same combine order).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     B, Hq, Sq, D = q.shape
     _, Hkv, ps, _ = k_pages.shape
     nb = block_tables.shape[1]
@@ -710,51 +761,14 @@ def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
     g = Hq // Hkv
     scale = sm_scale if sm_scale is not None else D ** -0.5
     gp = -(-g // 8) * 8  # sublane-aligned group rows
-    Lv = nb * ps         # virtual KV length
     maskf = (kv_len_mask.astype(F32) if kv_len_mask is not None
-             else jnp.ones((B, Lv), F32))
+             else jnp.ones((B, nb * ps), F32))
 
-    q3 = q[:, :, 0, :].reshape(B, Hkv, g, D)
-    q3 = _pad0(q3, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-    q3 = q3.reshape(B * Hkv, gp, D)
-
-    quantized = k_scale is not None
-    in_specs = [
-        pl.BlockSpec((1, gp, D), lambda b, j, bt: (b, 0, 0)),
-        pl.BlockSpec((1, 1, ps, D),
-                     lambda b, j, bt, h=Hkv: (bt[b // h, j], b % h, 0, 0)),
-        pl.BlockSpec((1, 1, ps, D),
-                     lambda b, j, bt, h=Hkv: (bt[b // h, j], b % h, 0, 0)),
-    ]
-    operands = [q3, k_pages, v_pages]
-    if quantized:
-        in_specs += [pl.BlockSpec(
-            (1, 1, ps), lambda b, j, bt, h=Hkv: (bt[b // h, j], b % h, 0))] * 2
-        operands += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1, ps), lambda b, j, bt, h=Hkv: (b // h, j)))
-    operands.append(maskf)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B * Hkv, nb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, gp, D), lambda b, j, bt: (b, j, 0, 0)),
-            pl.BlockSpec((1, 1, gp, 128), lambda b, j, bt: (b, j, 0, 0)),
-            pl.BlockSpec((1, 1, gp, 128), lambda b, j, bt: (b, j, 0, 0)),
-        ],
-    )
-    acc, m_st, l_st = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, cfg=cfg, sm_scale=scale,
-                          quantized=quantized),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, nb, gp, D), F32),
-            jax.ShapeDtypeStruct((B * Hkv, nb, gp, 128), I32),
-            jax.ShapeDtypeStruct((B * Hkv, nb, gp, 128), F32),
-        ],
-        interpret=interpret,
-    )(block_tables.astype(I32), *operands)
+    acc, m_st, l_st = _splitk_stats(
+        _group_rows(q, Hkv, gp), k_pages, v_pages,
+        maskf.reshape(B, nb, 1, ps), k_scale=k_scale, v_scale=v_scale,
+        block_tables=block_tables, Hkv=Hkv, bk=ps, cfg=cfg, sm_scale=scale,
+        group=0, interpret=interpret)
 
     out = _splitk_combine(acc, m_st, l_st, cfg)
     return out[:, :g].reshape(B, Hkv, g, D).reshape(B, Hq, 1, D)
@@ -791,6 +805,14 @@ def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
 # loads exactly as in the decode kernels.
 
 
+# Most chunk lanes per verify kernel call.  The per-split stats are
+# (B * Hkv, splits, Hq // Hkv * lanes, D) fp32, so a 512-token chunk over
+# 16-token pages would hold ~3 GB of them per layer at olmo-1b widths; a
+# longer chunk runs as a ``lax.map`` over lane blocks, each lane's
+# arithmetic unchanged.
+VERIFY_LANE_BLOCK = 128
+
+
 def _verify_mask_rows(mask, group: int):
     """(sp, bk) per-draft-lane mask -> (group * sp, bk) tile rows.  The
     mask depends only on the draft lane, so it rides in UN-duplicated and
@@ -801,52 +823,12 @@ def _verify_mask_rows(mask, group: int):
         group * sp, bk)
 
 
-def _verify_fwd_kernel(*refs, cfg: HyftConfig, sm_scale: float,
-                       quantized: bool, group: int):
-    if quantized:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref = refs
-    q = q_ref[0].astype(F32)              # (rows, dh) — (group, draft) rows
-    k = k_ref[0].astype(F32)              # (bk, dh)
-    v = v_ref[0].astype(F32)
-    if quantized:                         # dequant fused into the load
-        k = k * ks_ref[0][:, None]
-        v = v * vs_ref[0][:, None]
-    mask = _verify_mask_rows(mask_ref[0], group)
-    acc, m_loc, l_loc = _decode_tile(q, k, v, mask, cfg, sm_scale)
-    acc_ref[...] = acc[None, None]
-    m_ref[...] = jnp.broadcast_to(m_loc[None, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_loc[None, None], l_ref.shape)
-
-
-def _verify_paged_kernel(*refs, cfg: HyftConfig, sm_scale: float,
-                         quantized: bool, group: int):
-    if quantized:
-        (bt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref,
-         acc_ref, m_ref, l_ref) = refs
-    else:
-        bt_ref, q_ref, k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref = refs
-    del bt_ref  # consumed by the index maps (scalar prefetch)
-    q = q_ref[0].astype(F32)              # (rows, dh)
-    k = k_ref[0, 0].astype(F32)           # (ps, dh) — one physical page
-    v = v_ref[0, 0].astype(F32)
-    if quantized:                         # dequant fused into the page load
-        k = k * ks_ref[0, 0][:, None]
-        v = v * vs_ref[0, 0][:, None]
-    mask = _verify_mask_rows(mask_ref[0], group)
-    acc, m_loc, l_loc = _decode_tile(q, k, v, mask, cfg, sm_scale)
-    acc_ref[...] = acc[None, None]
-    m_ref[...] = jnp.broadcast_to(m_loc[None, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_loc[None, None], l_ref.shape)
-
-
 @functools.partial(jax.jit, static_argnames=(
     "cfg", "sm_scale", "block_k", "interpret"))
 def flash_hyft_verify(q: jax.Array, k: jax.Array, v: jax.Array,
                       kv_pos_mask: jax.Array, cfg: HyftConfig,
                       sm_scale: float | None = None, block_k: int = 256,
-                      interpret: bool = True,
+                      *, interpret: bool,
                       block_tables: jax.Array | None = None,
                       k_scale: jax.Array | None = None,
                       v_scale: jax.Array | None = None):
@@ -869,12 +851,36 @@ def flash_hyft_verify(q: jax.Array, k: jax.Array, v: jax.Array,
         ``kv_index <= pos + t`` plus any cache-length masking.  Ragged
         draft lengths across the batch ride in here (a padded draft row's
         outputs are discarded by the caller).
+    A chunk longer than ``VERIFY_LANE_BLOCK`` runs in lane blocks.
     Returns (B, Hq, Sq, D) fp32.  Forward-only.  At Sq == 1 this is bitwise
     identical to ``flash_hyft_decode`` (same splits) / ``_decode_paged``
     (pages as splits): the tile arithmetic is the shared ``_decode_tile``
     and the combine the shared ``_splitk_combine``; only the mask gained a
     row axis.
     """
+    maskf = kv_pos_mask.astype(F32)       # (B, Sq, Lk)
+    kw = dict(cfg=cfg, sm_scale=sm_scale, block_k=block_k,
+              interpret=interpret, block_tables=block_tables,
+              k_scale=k_scale, v_scale=v_scale)
+    B, Hq, Sq, D = q.shape
+    block_q = VERIFY_LANE_BLOCK
+    if Sq <= block_q:
+        return _verify_lanes(q, k, v, maskf, **kw)
+    nq = -(-Sq // block_q)
+    pad = nq * block_q - Sq               # padded lanes: fully masked
+    qs = _pad0(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    qs = qs.reshape(B, Hq, nq, block_q, D).transpose(2, 0, 1, 3, 4)
+    ms = _pad0(maskf, ((0, 0), (0, pad), (0, 0)))
+    ms = ms.reshape(B, nq, block_q, -1).transpose(1, 0, 2, 3)
+    out = jax.lax.map(lambda a: _verify_lanes(a[0], k, v, a[1], **kw),
+                      (qs, ms))
+    out = out.transpose(1, 2, 0, 3, 4).reshape(B, Hq, nq * block_q, D)
+    return out[:, :, :Sq]
+
+
+def _verify_lanes(q, k, v, maskf, *, cfg, sm_scale, block_k, interpret,
+                  block_tables, k_scale, v_scale):
+    """``flash_hyft_verify`` on one block of chunk lanes (one kernel call)."""
     B, Hq, Sq, D = q.shape
     Hkv = k.shape[1]
     assert Hq % Hkv == 0
@@ -882,101 +888,33 @@ def flash_hyft_verify(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = sm_scale if sm_scale is not None else D ** -0.5
     sp = -(-Sq // 8) * 8                  # sublane-aligned draft rows
     rows = g * sp                         # tile rows: (group, draft) folded
-    maskf = kv_pos_mask.astype(F32)       # (B, Sq, Lk)
 
     q3 = q.reshape(B, Hkv, g, Sq, D)
     q3 = _pad0(q3, ((0, 0), (0, 0), (0, 0), (0, sp - Sq), (0, 0)))
     q3 = q3.reshape(B * Hkv, rows, D)
 
-    quantized = k_scale is not None
-
     if block_tables is not None:  # ---- paged layout: pages as splits ----
-        from jax.experimental.pallas import tpu as pltpu
-
         ps = k.shape[2]
         nb = block_tables.shape[1]
         maskE = _pad0(maskf, ((0, 0), (0, sp - Sq), (0, 0)))  # (B, sp, Lv)
-        in_specs = [
-            pl.BlockSpec((1, rows, D), lambda b, j, bt: (b, 0, 0)),
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, j, bt, h=Hkv: (bt[b // h, j], b % h, 0, 0)),
-            pl.BlockSpec((1, 1, ps, D),
-                         lambda b, j, bt, h=Hkv: (bt[b // h, j], b % h, 0, 0)),
-        ]
-        operands = [q3, k, v]
-        if quantized:
-            in_specs += [pl.BlockSpec(
-                (1, 1, ps),
-                lambda b, j, bt, h=Hkv: (bt[b // h, j], b % h, 0))] * 2
-            operands += [k_scale, v_scale]
-        in_specs.append(
-            pl.BlockSpec((1, sp, ps), lambda b, j, bt, h=Hkv: (b // h, 0, j)))
-        operands.append(maskE)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B * Hkv, nb),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, rows, D), lambda b, j, bt: (b, j, 0, 0)),
-                pl.BlockSpec((1, 1, rows, 128), lambda b, j, bt: (b, j, 0, 0)),
-                pl.BlockSpec((1, 1, rows, 128), lambda b, j, bt: (b, j, 0, 0)),
-            ],
-        )
-        acc, m_st, l_st = pl.pallas_call(
-            functools.partial(_verify_paged_kernel, cfg=cfg, sm_scale=scale,
-                              quantized=quantized, group=g),
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((B * Hkv, nb, rows, D), F32),
-                jax.ShapeDtypeStruct((B * Hkv, nb, rows, 128), I32),
-                jax.ShapeDtypeStruct((B * Hkv, nb, rows, 128), F32),
-            ],
-            interpret=interpret,
-        )(block_tables.astype(I32), *operands)
+        maskE = maskE.reshape(B, sp, nb, ps).transpose(0, 2, 1, 3)
+        acc, m_st, l_st = _splitk_stats(
+            q3, k, v, maskE, k_scale=k_scale, v_scale=v_scale,
+            block_tables=block_tables, Hkv=Hkv, bk=ps, cfg=cfg,
+            sm_scale=scale, group=g, interpret=interpret)
     else:  # ---- contiguous layout: block_k splits, as flash_hyft_decode ----
         Sk = k.shape[2]
         bk = min(block_k, -(-Sk // 128) * 128)  # lane-aligned KV blocks
         pad_k = (-Sk) % bk
         if pad_k:
-            k = _pad0(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-            v = _pad0(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+            k, v, k_scale, v_scale = _pad_kv(k, v, k_scale, v_scale, pad_k)
             maskf = _pad0(maskf, ((0, 0), (0, 0), (0, pad_k)))
-            if quantized:
-                k_scale = _pad0(k_scale, ((0, 0), (0, 0), (0, pad_k)))
-                v_scale = _pad0(v_scale, ((0, 0), (0, 0), (0, pad_k)))
         Skp = Sk + pad_k
-        ns = Skp // bk
         maskE = _pad0(maskf, ((0, 0), (0, sp - Sq), (0, 0)))  # (B, sp, Skp)
-        in_specs = [
-            pl.BlockSpec((1, rows, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
-        ]
-        operands = [q3, k.reshape(B * Hkv, Skp, D), v.reshape(B * Hkv, Skp, D)]
-        if quantized:
-            in_specs += [pl.BlockSpec((1, bk), lambda b, j: (b, j))] * 2
-            operands += [k_scale.reshape(B * Hkv, Skp),
-                         v_scale.reshape(B * Hkv, Skp)]
-        in_specs.append(
-            pl.BlockSpec((1, sp, bk), lambda b, j, h=Hkv: (b // h, 0, j)))
-        operands.append(maskE)
-        acc, m_st, l_st = pl.pallas_call(
-            functools.partial(_verify_fwd_kernel, cfg=cfg, sm_scale=scale,
-                              quantized=quantized, group=g),
-            grid=(B * Hkv, ns),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, rows, D), lambda b, j: (b, j, 0, 0)),
-                pl.BlockSpec((1, 1, rows, 128), lambda b, j: (b, j, 0, 0)),
-                pl.BlockSpec((1, 1, rows, 128), lambda b, j: (b, j, 0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * Hkv, ns, rows, D), F32),
-                jax.ShapeDtypeStruct((B * Hkv, ns, rows, 128), I32),
-                jax.ShapeDtypeStruct((B * Hkv, ns, rows, 128), F32),
-            ],
-            interpret=interpret,
-        )(*operands)
+        acc, m_st, l_st = _splitk_stats(
+            q3, k.reshape(B * Hkv, Skp, D), v.reshape(B * Hkv, Skp, D), maskE,
+            k_scale=k_scale, v_scale=v_scale, block_tables=None, Hkv=Hkv,
+            bk=bk, cfg=cfg, sm_scale=scale, group=g, interpret=interpret)
 
     out = _splitk_combine(acc, m_st, l_st, cfg)        # (BH, rows, D)
     out = out.reshape(B, Hkv, g, sp, D)[:, :, :, :Sq]

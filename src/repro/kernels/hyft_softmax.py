@@ -10,6 +10,12 @@ the kernel, so kernel and oracle agree bit-for-bit.
 Tiling: grid over row blocks, each program owns a ``(block_rows, cols)`` tile
 (full row resident — the standalone kernel targets rows that fit VMEM; longer
 rows use the fused flash kernel which blocks the row dimension online).
+
+The kernels read and write fp32 only; the wrappers cast to and from the
+format's ``cfg.dtype``.  Mosaic on v5e cannot pack or load 16-bit floats
+(``tpu.pack_subelements`` f32->f16 is refused), and the oracle's result is
+the fp32 datapath output cast to ``cfg.dtype``, so casting outside the
+kernel gives the same bits.
 """
 from __future__ import annotations
 
@@ -26,7 +32,7 @@ F32 = jnp.float32
 
 
 def _fwd_kernel(z_ref, o_ref, *, cfg: HyftConfig):
-    z = z_ref[...].astype(F32)
+    z = z_ref[...]
     # --- input pre-processor: FP2FX + (strided) max search -----------------
     z_raw = nm.fp2fx(z, cfg.frac_bits, cfg.total_bits)
     zmax = jnp.max(z_raw[:, :: cfg.step] if cfg.step > 1 else z_raw,
@@ -38,19 +44,19 @@ def _fwd_kernel(z_ref, o_ref, *, cfg: HyftConfig):
     denom = jnp.sum(addend, axis=-1, keepdims=True)
     e_b, m_b = nm.lod_refloat(denom, cfg.mant_bits)
     # --- hybrid DIV unit: log-subtract division ------------------------------
-    o_ref[...] = nm.log_div(e, m, e_b, m_b, cfg.mant_bits).astype(o_ref.dtype)
+    o_ref[...] = nm.log_div(e, m, e_b, m_b, cfg.mant_bits)
 
 
 def _bwd_kernel(s_ref, dy_ref, dz_ref, *, cfg: HyftConfig):
-    s = s_ref[...].astype(F32)
-    dy = dy_ref[...].astype(F32)
+    s = s_ref[...]
+    dy = dy_ref[...]
     # --- reuse of the DIV/MUL unit as log-domain multiplier (Eq. 10) --------
     prods = nm.log_mul(dy, s, cfg.mant_bits, half_range=True)
     # --- signed fixed-point adder tree for the dot product -------------------
     prods_q = nm.fx_quantize(prods, cfg.bwd_acc_bits)
     dot = jnp.sum(prods_q, axis=-1, keepdims=True)
     diff = nm.fx_quantize(dy, cfg.bwd_acc_bits) - dot
-    dz_ref[...] = nm.log_mul(diff, s, cfg.mant_bits, half_range=True).astype(dz_ref.dtype)
+    dz_ref[...] = nm.log_mul(diff, s, cfg.mant_bits, half_range=True)
 
 
 def _row_blocks(rows: int, cols: int, block_rows: int | None) -> int:
@@ -67,12 +73,12 @@ def _row_blocks(rows: int, cols: int, block_rows: int | None) -> int:
 
 @functools.partial(jax.jit, static_argnames=("cfg", "block_rows", "interpret"))
 def hyft_softmax_fwd_kernel(z: jax.Array, cfg: HyftConfig,
-                            block_rows: int | None = None,
-                            interpret: bool = True) -> jax.Array:
+                            block_rows: int | None = None, *,
+                            interpret: bool) -> jax.Array:
     """Row-tiled forward kernel. ``z``: (..., cols); softmax over last axis."""
     shape = z.shape
     cols = shape[-1]
-    z2 = z.reshape(-1, cols)
+    z2 = z.reshape(-1, cols).astype(F32)
     rows = z2.shape[0]
     br = _row_blocks(rows, cols, block_rows)
     pad = (-rows) % br
@@ -84,22 +90,23 @@ def hyft_softmax_fwd_kernel(z: jax.Array, cfg: HyftConfig,
         grid=grid,
         in_specs=[pl.BlockSpec((br, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(z2.shape, cfg.dtype),
+        out_shape=jax.ShapeDtypeStruct(z2.shape, F32),
         interpret=interpret,
     )(z2)
     if pad:
         out = out[:rows]
-    return out.reshape(shape)
+    return out.reshape(shape).astype(cfg.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "block_rows", "interpret"))
 def hyft_softmax_bwd_kernel(s: jax.Array, dy: jax.Array, cfg: HyftConfig,
-                            block_rows: int | None = None,
-                            interpret: bool = True) -> jax.Array:
+                            block_rows: int | None = None, *,
+                            interpret: bool) -> jax.Array:
     """Row-tiled backward kernel: dz = s * (dy - <dy, s>) in Hyft arithmetic."""
     shape = s.shape
     cols = shape[-1]
-    s2, dy2 = s.reshape(-1, cols), dy.reshape(-1, cols)
+    s2 = s.reshape(-1, cols).astype(F32)
+    dy2 = dy.reshape(-1, cols).astype(F32)
     rows = s2.shape[0]
     br = _row_blocks(rows, cols, block_rows)
     pad = (-rows) % br
@@ -113,9 +120,9 @@ def hyft_softmax_bwd_kernel(s: jax.Array, dy: jax.Array, cfg: HyftConfig,
         in_specs=[pl.BlockSpec((br, cols), lambda i: (i, 0)),
                   pl.BlockSpec((br, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(s2.shape, cfg.dtype),
+        out_shape=jax.ShapeDtypeStruct(s2.shape, F32),
         interpret=interpret,
     )(s2, dy2)
     if pad:
         out = out[:rows]
-    return out.reshape(shape)
+    return out.reshape(shape).astype(cfg.dtype)

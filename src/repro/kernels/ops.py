@@ -1,9 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels + the attention contract.
 
-``interpret`` defaults to auto: compiled on TPU, interpreter elsewhere (this
-container is CPU-only; TPU is the lowering target).  ``hyft_softmax`` is
-differentiable — its VJP is the backward *kernel* (the accelerator's reused
-DIV/MUL datapath), mirroring ``repro.core.hyft.hyft_softmax``.
+``interpret`` is decided here and nowhere else (``_auto_interpret``): the
+kernels compile through Mosaic on a TPU backend and run in the Pallas
+interpreter on any other.  The kernel entry points take it as a required
+argument, so no caller can fall into the interpreter on a chip by leaving
+it out.  ``hyft_softmax`` is differentiable — its VJP is the backward
+*kernel* (the accelerator's reused DIV/MUL datapath), mirroring
+``repro.core.hyft.hyft_softmax``.
 
 Mask/stats contract (DESIGN.md §3) — shared by all three attention modes
 (``unfused`` / ``chunked`` / ``kernel``):

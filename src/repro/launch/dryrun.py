@@ -34,7 +34,7 @@ from repro.configs.base import TrainConfig
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
 from repro.models import build_model
-from repro.roofline import analysis
+from repro.roofline import analysis, hw
 from repro.train import state as train_state
 from repro.train.step import make_step_fn
 
@@ -187,7 +187,10 @@ def run_cell(arch, shape_name, mesh_kind, opts: CellOpts, force=False):
         tf = analysis.scan_trip_factor(
             build_cfg(arch, opts), meta["kind"], shape.seq, shape.batch,
             meta.get("microbatch", 0))
-        roof = analysis.analyze(cost, hlo, chips, trip_factor=tf)
+        # an analytic projection onto the production target (a v5e pod),
+        # not a measurement: nothing here ran on a chip
+        roof = analysis.analyze(cost, hlo, chips, device_kind=hw.V5E,
+                                trip_factor=tf)
         mf = analysis.model_flops(build_cfg(arch, opts), meta["tokens"],
                                   "train" if meta["kind"] == "train"
                                   else "infer")

@@ -138,12 +138,14 @@ def main():
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config, smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.configs.base import ServeConfig
     from repro.models import build_model
     from repro.models.layers import unbox
     from repro.serve.engine import generate
     from repro.serve.scheduler import Request, SlotPoolEngine
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)

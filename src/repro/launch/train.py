@@ -10,7 +10,34 @@ Examples:
   # jax.distributed.initialize() (multi-host) and the production mesh.
 """
 import argparse
-import os
+
+
+def build_trainer(cfg, tcfg, ocfg, mesh, seed: int = 0):
+    """The model, train state, jitted step and state shardings, as the CLI
+    builds them.  The state is initialised under ``jit`` with the state
+    shardings as its outputs, so a sharded model is created in place on
+    its devices and never lands whole on device 0 first."""
+    import functools
+
+    import jax
+    from repro.configs import input_specs
+    from repro.configs.shapes import ShapeSpec
+    from repro.distributed import sharding as shd
+    from repro.models import build_model
+    from repro.train.state import init_state, state_shardings
+    from repro.train.step import build_train_step
+
+    model = build_model(cfg)
+    rules = shd.default_rules(mesh, cfg)
+    state_sh = state_shardings(mesh, model, ocfg, rules)
+    specs = input_specs(cfg, ShapeSpec("cli", "train", tcfg.seq_len,
+                                       tcfg.global_batch))
+    batch_sh = shd.batch_shardings(mesh, specs, rules)
+    with mesh:
+        state = jax.jit(functools.partial(init_state, model, ocfg),
+                        out_shardings=state_sh)(jax.random.PRNGKey(seed))
+        step = build_train_step(model, tcfg, ocfg, mesh, state_sh, batch_sh)
+    return model, state, step, state_sh
 
 
 def main():
@@ -35,23 +62,19 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    import jax
     from repro import optim
     from repro.configs import get_config, smoke_config
     from repro.configs.base import TrainConfig
     from repro.data.synthetic import DataConfig, lm_batch
-    from repro.distributed import sharding as shd
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
-    from repro.models import build_model
     from repro.train.loop import run_train
-    from repro.train.state import init_state, state_shardings
-    from repro.train.step import build_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
     cfg = cfg.with_(softmax_impl=args.softmax)
-    model = build_model(cfg)
 
     tcfg = TrainConfig(global_batch=args.global_batch, seq_len=args.seq,
                        microbatch=args.microbatch, lr=args.lr,
@@ -62,17 +85,9 @@ def main():
                       global_batch=args.global_batch, seed=args.seed)
 
     mesh = make_host_mesh((args.data_mesh, args.model_mesh))
-    rules = shd.default_rules(mesh, cfg)
-    state_sh = state_shardings(mesh, model, ocfg, rules)
-    from repro.configs import input_specs
-    from repro.configs.shapes import ShapeSpec
-    specs = input_specs(cfg, ShapeSpec("cli", "train", args.seq,
-                                       args.global_batch))
-    batch_sh = shd.batch_shardings(mesh, specs, rules)
-
+    _, state, step, state_sh = build_trainer(cfg, tcfg, ocfg, mesh,
+                                             seed=args.seed)
     with mesh:
-        state = init_state(model, ocfg, jax.random.PRNGKey(args.seed))
-        step = build_train_step(model, tcfg, ocfg, mesh, state_sh, batch_sh)
         state, hist = run_train(state, step, lambda s: lm_batch(dcfg, s),
                                 tcfg, ckpt_dir=args.ckpt_dir,
                                 state_sh=state_sh)

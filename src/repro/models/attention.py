@@ -11,7 +11,8 @@ Modes (``AttnMode``):
              differentiable via a recompute-based custom VJP (flash-style
              backward using the saved row stats).  This is the beyond-paper
              memory-roofline lever for long sequences.
-  kernel   — the Pallas flash kernel (TPU runtime; interpret mode in tests).
+  kernel   — the Pallas flash kernels: compiled by Mosaic on a TPU backend,
+             run by the Pallas interpreter on CPU (the tests).
 
 Sequence-parallel decode (``sp_decode_attention``) implements the paper's
 L1/L2 Hyft tree *across devices*: each model-axis shard computes local
@@ -271,12 +272,17 @@ def attention_fwd(q, k, v, cfg, *, causal=True, q_offset=0, kv_len_mask=None):
     All three modes honor the shared mask contract (``repro.kernels.ops``):
     ``kv_len_mask`` (B, Sk) marks valid KV positions, so decode and serving
     stay on the fused/online paths instead of dropping to unfused.  The only
-    remaining fallbacks are non-Hyft softmax impls, a traced ``q_offset``
-    (the fused paths need it static for the causal mask), and a KV length
-    the chunk size doesn't divide (chunked mode only).
+    remaining fallbacks are non-Hyft softmax impls and a KV length the chunk
+    size doesn't divide (chunked mode only).  The fused paths need a static
+    ``q_offset`` for the causal mask: kernel mode raises on a traced one
+    rather than quietly running the unfused path in its place.
     """
     hcfg = hyft_config_for(cfg.softmax_impl)
     mode = getattr(cfg, "attn_mode", "unfused")
+    if hcfg is not None and mode == "kernel" and not isinstance(q_offset, int):
+        raise ValueError(
+            "attn_mode='kernel' needs a static int q_offset; a traced offset "
+            "would silently drop to the unfused path")
     if hcfg is not None and isinstance(q_offset, int):
         from repro.kernels import ops
         maskf = ops.as_mask_f(kv_len_mask)
@@ -532,23 +538,38 @@ def cache_update_paged(cache, k_new, v_new, pos_b, block_tables,
 
 def cache_update_block_paged(cache, k_new, v_new, pos_b, block_tables,
                              n_valid, write_mask=None):
-    """Paged twin of ``cache_update_block_ragged``: token ``j`` of row ``b``
-    scatters through the block table at virtual position ``pos_b[b] + j``.
-    Lanes past ``n_valid[b]``, rows with ``write_mask`` False, and lanes
-    past the table's virtual extent are redirected to the null page — the
-    usual paged "no write" that can never race a live page.
+    """Paged multi-token scatter: token ``j`` of row ``b`` lands through the
+    block table at virtual position ``pos_b[b] + j``, all tokens in ONE
+    scatter per buffer.  Lanes past ``n_valid[b]``, rows with
+    ``write_mask`` False, and lanes past the table's virtual extent are
+    redirected to the null page — the usual paged "no write" that can never
+    race a live page.  fp2fx8 scales are per (head, position), so
+    quantizing the block at once gives the bits of token-by-token writes.
     """
     B, _, S, _ = k_new.shape
-    Lv = block_tables.shape[1] * cache["k"].shape[2]
+    ps = cache["k"].shape[2]
+    Lv = block_tables.shape[1] * ps
     base = jnp.ones((B,), bool) if write_mask is None else write_mask
-    nv = jnp.asarray(n_valid, I32)
-    for j in range(S):
-        gate = base & (j < nv) & (pos_b + j < Lv)
-        pj = jnp.clip(pos_b + j, 0, Lv - 1)
-        cache = cache_update_paged(cache, k_new[:, :, j:j + 1],
-                                   v_new[:, :, j:j + 1], pj, block_tables,
-                                   gate)
-    return cache
+    lane = jnp.arange(S, dtype=I32)[None, :]
+    pos = pos_b[:, None] + lane                                  # (B, S)
+    gate = (base[:, None] & (lane < jnp.asarray(n_valid, I32)[:, None])
+            & (pos < Lv))
+    pos = jnp.clip(pos, 0, Lv - 1)
+    page = jnp.take_along_axis(block_tables, pos // ps, axis=1)
+    page = jnp.where(gate, page, 0)
+    off = pos % ps
+
+    def scat(pool, new):  # new (B, Hkv, S[, D]) -> rows (B, S, Hkv[, D])
+        return pool.at[page, :, off].set(
+            jnp.moveaxis(new, 2, 1).astype(pool.dtype))
+
+    if cache_is_quantized(cache):
+        kr, ks = fp2fx8_quantize(k_new)
+        vr, vs = fp2fx8_quantize(v_new)
+        return {"k": scat(cache["k"], kr), "v": scat(cache["v"], vr),
+                "k_scale": scat(cache["k_scale"], ks),
+                "v_scale": scat(cache["v_scale"], vs)}
+    return {"k": scat(cache["k"], k_new), "v": scat(cache["v"], v_new)}
 
 
 def paged_gather_kv(cache, block_tables):

@@ -58,7 +58,7 @@ def moe_apply(p, x, cfg):
     # capacity-bounded one-hot dispatch (Switch-style, deterministic)
     onehot = jax.nn.one_hot(gate_idx, E, dtype=F32)            # (B,S,k,E)
     pos = jnp.cumsum(onehot.reshape(B, S * k, E), axis=1).reshape(B, S, k, E)
-    pos = pos * onehot - 1.0                                   # slot per (token,choice)
+    pos = (pos * onehot - 1.0).astype(jnp.int32)               # slot per (token,choice)
     keep = (pos >= 0) & (pos < cap)
     slot = jax.nn.one_hot(jnp.where(keep, pos, -1), cap, dtype=F32)  # (B,S,k,E,cap)
 

@@ -111,11 +111,13 @@ class Roofline:
                 "roofline_fraction": self.roofline_fraction}
 
 
-def analyze(cost: dict, hlo_text: str, chips: int,
+def analyze(cost: dict, hlo_text: str, chips: int, device_kind: str,
             trip_factor: float = 1.0) -> Roofline:
-    """``trip_factor`` corrects XLA's known while-loop undercount: HLO cost
-    analysis counts each loop body ONCE regardless of trip count (verified on
-    this backend — see EXPERIMENTS.md §Dry-run).  Our models put virtually
+    """Roofline terms against the published peaks of ``device_kind``
+    (``hw.peaks``; an unlisted device raises).  ``trip_factor`` corrects
+    XLA's known while-loop undercount: HLO cost analysis counts each loop
+    body ONCE regardless of trip count (verified on this backend — see
+    EXPERIMENTS.md §Dry-run).  Our models put virtually
     all compute inside ``lax.scan`` (layers x microbatches x token steps), so
     we scale per-device flops/bytes/collectives by the statically-known trip
     product (``scan_trip_factor`` below).  Loop-external work (embeddings,
@@ -125,10 +127,11 @@ def analyze(cost: dict, hlo_text: str, chips: int,
     bytes_dev = float(cost.get("bytes accessed", 0.0)) * trip_factor
     coll = collective_bytes(hlo_text)
     coll_dev = float(sum(coll.values())) * trip_factor
+    pk = hw.peaks(device_kind)
     return Roofline(
-        compute_s=flops_dev / hw.PEAK_FLOPS_BF16,
-        memory_s=bytes_dev / hw.HBM_BW,
-        collective_s=coll_dev / hw.ICI_BW,
+        compute_s=flops_dev / pk.flops_bf16,
+        memory_s=bytes_dev / pk.hbm_bw,
+        collective_s=coll_dev / pk.ici_bw,
         hlo_flops_global=flops_dev * chips,
         hlo_bytes_global=bytes_dev * chips,
         coll_bytes_device=coll_dev,

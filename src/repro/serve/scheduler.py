@@ -180,10 +180,12 @@ _SCATTER_CACHE: dict = {}
 _AXES_CACHE: dict = {}
 
 
-def _burst_key_cfg(scfg: ServeConfig) -> ServeConfig:
-    """Burst compilations depend on the decode arithmetic, not the admission
-    policy: lockstep mode ignores EOS, so normalize both fields and let the
-    schedulers share one compiled burst (spec honors EOS like continuous).
+def exec_key_cfg(scfg: ServeConfig) -> ServeConfig:
+    """The ServeConfig that keys the compiled serving executables (decode
+    burst, prefill chunk, spec step).  Burst compilations depend on the
+    decode arithmetic, not the admission policy: lockstep mode ignores EOS,
+    so normalize both fields and let the schedulers share one compiled
+    burst (spec honors EOS like continuous).
     The chunk-scheduling knobs are admission policy too — a prefill-chunk
     executable is keyed by its width alone, so chunked and whole-prompt
     runs share compilations — and so are the host-only robustness knobs
@@ -221,7 +223,7 @@ def build_burst(model, scfg: ServeConfig, steps: int):
     the burst plus fp2fx8 scale/saturation stats of the final cache —
     computed in-jit at the cost of a few row reductions per step.
     """
-    kcfg = _burst_key_cfg(scfg)
+    kcfg = exec_key_cfg(scfg)
     eos = kcfg.eos_id
     ck = (model.cfg, kcfg, steps)
     if ck in _BURST_CACHE:
@@ -460,7 +462,7 @@ class SlotPoolEngine:
         if self.spec:
             from repro.serve import spec as spec_mod
             self._spec_step = spec_mod.build_spec_step(
-                self.model, _burst_key_cfg(scfg), scfg.draft_k)
+                self.model, exec_key_cfg(scfg), scfg.draft_k)
         else:
             self._burst = build_burst(self.model, scfg,
                                       max(1, scfg.decode_burst))
@@ -607,7 +609,7 @@ class SlotPoolEngine:
             layers = scan_trip_factor(cfg, "decode", 1, 1, 1)
             for w in sorted(widths):
                 pc = engine.build_prefill_chunk(
-                    self.model, _burst_key_cfg(scfg), w)
+                    self.model, exec_key_cfg(scfg), w)
                 args = (self.params, self.cache, jnp.zeros((n, w), I32),
                         jnp.zeros(n, I32), jnp.ones(n, I32),
                         jnp.zeros(n, bool))
@@ -856,7 +858,7 @@ class SlotPoolEngine:
         with self.obs.tracer.span("prefill_chunk", width=width,
                                   rows=len(rows)):
             pc = engine.build_prefill_chunk(self.model,
-                                            _burst_key_cfg(scfg), width)
+                                            exec_key_cfg(scfg), width)
             # jnp.asarray copies the host mirror, so mutating self.lengths
             # below cannot race the dispatched call
             t_in = time.perf_counter()

@@ -7,12 +7,10 @@ import textwrap
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.analysis import lint
 from repro.analysis import jaxpr_audit
-from repro.analysis import pallas_check
 from repro.analysis.common import Finding
 from repro.analysis.retrace import RetraceError, RetraceGuard, serve_steady_state
 
@@ -87,75 +85,6 @@ def test_jaxpr_donation_check():
 @pytest.mark.slow
 def test_jaxpr_repo_clean():
     assert jaxpr_audit.run() == []
-
-
-# -- Pallas tile checker -----------------------------------------------------
-
-
-def _toy_kernel_entry(index_map):
-    from jax.experimental import pallas as pl
-
-    def kern(x_ref, o_ref):
-        o_ref[...] = x_ref[...]
-
-    def make():
-        x = jnp.zeros((8, 16), F32)
-        fn = pl.pallas_call(
-            kern, grid=(4,),
-            in_specs=[pl.BlockSpec((2, 16), index_map)],
-            out_specs=pl.BlockSpec((2, 16), index_map),
-            out_shape=jax.ShapeDtypeStruct((8, 16), F32),
-            interpret=True)
-        return fn, (x,)
-    return pallas_check.KernelEntry("toy", make)
-
-
-def test_pallas_catches_out_of_bounds_index_map():
-    # block row i+1 of 4 runs off the 8-row array at the last grid point;
-    # the checker proves it by evaluating the map over the whole grid —
-    # the kernel itself is never run
-    entry = _toy_kernel_entry(lambda i: (i + 1, 0))
-    assert any(f.rule == "tile.out-of-bounds"
-               for f in pallas_check.check_entry(entry))
-
-
-def test_pallas_clean_index_map_passes():
-    entry = _toy_kernel_entry(lambda i: (i, 0))
-    assert pallas_check.check_entry(entry) == []
-
-
-def test_pallas_catches_unaligned_block():
-    from jax.experimental import pallas as pl
-
-    def kern(x_ref, o_ref):
-        o_ref[...] = x_ref[...]
-
-    def make():
-        x = jnp.zeros((10, 16), F32)  # 10 % 3 != 0
-        fn = pl.pallas_call(
-            kern, grid=(4,),
-            in_specs=[pl.BlockSpec((3, 16), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((3, 16), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((10, 16), F32),
-            interpret=True)
-        return fn, (x,)
-
-    entry = pallas_check.KernelEntry("unaligned", make)
-    assert any(f.rule == "tile.unaligned"
-               for f in pallas_check.check_entry(entry))
-
-
-def test_pallas_catches_bad_ref_dtype():
-    entry = _toy_kernel_entry(lambda i: (i, 0))
-    entry = pallas_check.KernelEntry(
-        "toy", entry.make, expect_dtypes={0: "int8"})
-    assert any(f.rule == "tile.bad-dtype"
-               for f in pallas_check.check_entry(entry))
-
-
-@pytest.mark.slow
-def test_pallas_repo_registry_clean():
-    assert pallas_check.run() == []
 
 
 # -- retrace guard -----------------------------------------------------------

@@ -220,3 +220,17 @@ def test_train_step_attn_mode_override():
     toks = jax.random.randint(jax.random.PRNGKey(2), (2, 8), 0, 32, jnp.int32)
     state, metrics = step(state, {"tokens": toks, "targets": toks})
     assert jnp.isfinite(metrics["loss"])
+
+
+def test_kernel_mode_refuses_traced_q_offset():
+    """Kernel mode never quietly swaps in the unfused path: a traced
+    ``q_offset`` (which the fused kernels cannot take) is an error."""
+    from repro.configs import get_config, smoke_config
+    from repro.models.attention import attention_fwd
+    cfg = smoke_config(get_config("olmo-1b")).with_(softmax_impl="hyft16",
+                                                    attn_mode="kernel")
+    q, k, v, _ = _qkvw(Sq=8, Sk=8)
+    with pytest.raises(ValueError, match="static int q_offset"):
+        jax.jit(lambda off: attention_fwd(q, k, v, cfg, q_offset=off))(3)
+    # a static offset takes the kernel
+    assert attention_fwd(q, k, v, cfg, q_offset=3).shape == q.shape

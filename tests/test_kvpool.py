@@ -219,6 +219,41 @@ def test_fp2fx8_roundtrip_error_bounds():
     assert np.all(np.asarray(fp2fx8_dequantize(raw, s)) == 0.0)
 
 
+@pytest.mark.parametrize("cache_dtype", ["float32", "fp2fx8"])
+def test_block_paged_write_matches_token_by_token(cache_dtype):
+    """The one-scatter chunk write lands every live page bit for bit where
+    S sequential one-token writes put it: scattered block tables, ragged
+    ``n_valid``, a gated-off row, and lanes past the table's extent.  Only
+    the null page (the masked-write sink, never read) may differ."""
+    from types import SimpleNamespace
+    from repro.models.attention import (cache_update_block_paged,
+                                        cache_update_paged, paged_cache_init)
+    rng = np.random.default_rng(6)
+    B, Hkv, D, ps, nb, S = 3, 2, 8, 4, 3, 7
+    cfg = SimpleNamespace(n_kv_heads=Hkv, d_head=D)
+    pool = paged_cache_init(cfg, B * nb, ps, cache_dtype)
+    pool = {n: jnp.asarray(rng.normal(size=x.shape) * 3).astype(x.dtype)
+            if x.dtype == F32 else x for n, x in pool.items()}
+    bt = jnp.asarray(rng.permutation(B * nb) + 1, jnp.int32).reshape(B, nb)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, S, D)) * 5, F32)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, S, D)), F32)
+    pos = jnp.asarray([1, 9, 6], jnp.int32)    # row 1 runs past nb * ps
+    n_valid = jnp.asarray([7, 6, 3], jnp.int32)
+    gate = jnp.asarray([True, True, False])
+    block = cache_update_block_paged(pool, k, v, pos, bt, n_valid, gate)
+    seq = pool
+    for j in range(S):
+        g = gate & (j < n_valid) & (pos + j < nb * ps)
+        seq = cache_update_paged(seq, k[:, :, j:j + 1], v[:, :, j:j + 1],
+                                 jnp.clip(pos + j, 0, nb * ps - 1), bt, g)
+    assert set(block) == set(seq)
+    for name in block:
+        a, b = np.asarray(block[name])[1:], np.asarray(seq[name])[1:]
+        assert a.dtype == b.dtype and np.array_equal(a, b), name
+    assert not np.array_equal(np.asarray(block["k"])[1:],
+                              np.asarray(pool["k"])[1:])
+
+
 # --------------------------------------------------------------------------
 # paged decode kernel: bitwise equality with the contiguous split-K kernel
 # --------------------------------------------------------------------------

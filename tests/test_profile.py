@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.obs import Obs, ledger, profile
+from repro.roofline import hw
 
 M, K, N = 64, 128, 32
 
@@ -43,7 +44,7 @@ def test_exec_cost_matmul_flops_match_analytic():
 
 def test_join_cost_fields_and_roofline_fraction():
     cost = {"flops": 2e9, "bytes": 8e9, "transcendentals": 0.0}
-    j = profile.join_cost(cost, wall_s=1.0)
+    j = profile.join_cost(cost, wall_s=1.0, device_kind=hw.V5E)
     assert j["achieved_gflops"] == pytest.approx(2.0)
     assert j["achieved_gbps"] == pytest.approx(8.0)
     # 8 GB at 819 GB/s dominates 2 GFLOP at 197 TFLOP/s
@@ -53,7 +54,26 @@ def test_join_cost_fields_and_roofline_fraction():
     assert 0 < j["roofline_fraction"] < 1
 
 
-def test_costbook_record_observe_emits_metrics():
+@pytest.mark.parametrize("kind", ["cpu", "TPU v99"])
+def test_join_cost_unknown_device_raises(kind):
+    """A device with no published peaks has no roofline share."""
+    with pytest.raises(ValueError, match="no published peaks"):
+        profile.join_cost({"flops": 1.0, "bytes": 1.0}, 1.0, device_kind=kind)
+
+
+def test_costbook_off_tpu_reports_no_share():
+    """On the CPU backend a dispatch's wall is recorded, never joined."""
+    obs = Obs.enabled()
+    f, a, b = _matmul()
+    obs.profile.record("mm", f, a, b)
+    assert obs.profile.observe("mm", 1e-3) is None
+    assert obs.metrics.find("perf.roofline_fraction", executable="mm") is None
+    assert obs.metrics.find("perf.wall_s", executable="mm").count == 1
+    assert "roofline_fraction" not in obs.profile.summary()["mm"]
+
+
+def test_costbook_record_observe_emits_metrics(monkeypatch):
+    monkeypatch.setattr(profile, "roofline_kind", lambda: hw.V5E)
     obs = Obs.enabled()
     f, a, b = _matmul()
     c = obs.profile.record("mm", f, a, b)
@@ -87,13 +107,14 @@ def test_costbook_trip_factor_scales_cost():
 
 
 def test_microbench_smoke_one_kernel():
-    from repro.analysis.pallas_check import default_registry
-    entries = [e for e in default_registry() if e.name == "softmax_fwd"]
+    entries = [e for e in profile.default_registry()
+               if e.name == "softmax_fwd"]
     rows = profile.microbench(entries=entries, iters=1)
     (row,) = rows
     assert row["kernel"] == "softmax_fwd" and row["format"] == "float32"
     assert row["us_per_call"] > 0
-    assert "roofline_fraction" in row  # CPU backend provides cost analysis
+    assert row["flops"] > 0  # CPU backend provides cost analysis
+    assert "roofline_fraction" not in row  # but a CPU wall has no share
 
 
 def test_xla_profile_capture_window(tmp_path):

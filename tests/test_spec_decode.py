@@ -173,6 +173,34 @@ def test_verify_lanes_match_decode_per_frontier(paged):
         assert jnp.all(dt == ver[:, :, t:t + 1])
 
 
+@pytest.mark.parametrize("paged", [False, True])
+def test_verify_lane_blocks_match_one_call(paged, monkeypatch):
+    """A chunk longer than ``VERIFY_LANE_BLOCK`` runs as lane blocks (the
+    last one padded); each lane's arithmetic is unchanged, so the result is
+    bitwise that of one call over all lanes."""
+    from repro.core.registry import hyft_config_for
+    from repro.kernels import flash_attention as fa
+    from repro.kernels.flash_attention import flash_hyft_verify
+    cfg = hyft_config_for("hyft16")
+    rng = np.random.default_rng(4)
+    B, Hq, Hkv, Sk, D, S = 2, 4, 2, 40, 16, 20
+    qs = jnp.asarray(rng.normal(size=(B, Hq, S, D)), F32)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, Sk, D)), F32)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, Sk, D)), F32)
+    pos = jnp.asarray([5, 12])[:, None] + jnp.arange(S)[None, :]
+    m3 = (jnp.arange(Sk)[None, None, :] <= pos[:, :, None]).astype(F32)
+    kw = dict(interpret=True)
+    if paged:
+        k, v, bt = _paged_pool(k, v, ps=8)
+        kw["block_tables"] = bt
+    assert S <= fa.VERIFY_LANE_BLOCK
+    whole = flash_hyft_verify(qs, k, v, m3, cfg, **kw)
+    monkeypatch.setattr(fa, "VERIFY_LANE_BLOCK", 8)
+    jax.clear_caches()  # the jitted entry reads the block size at trace time
+    blocked = flash_hyft_verify(qs, k, v, m3, cfg, **kw)
+    assert jnp.all(whole == blocked)
+
+
 # --------------------------------------------------------------------------
 # greedy spec == vanilla greedy, across layouts
 # --------------------------------------------------------------------------
