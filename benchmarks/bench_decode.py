@@ -95,7 +95,6 @@ def _e2e_section(report, max_new, batch):
     from repro.configs.base import ServeConfig
     from repro.models import build_model
     from repro.models.layers import unbox
-    from repro.obs.profile import CostBook
     from repro.serve.engine import generate
 
     cfg = smoke_config(get_config("olmo-1b")).with_(
@@ -113,28 +112,16 @@ def _e2e_section(report, max_new, batch):
                            decode_loop=loop)
         out = generate(model, params, b, scfg, max_new=max_new)  # compile
         jax.block_until_ready(out)
-        # timed pass carries a cost book: real cost_analysis() FLOPs/bytes
-        # per executable, joined against the walls generate measures
-        book = CostBook(enabled=True)
         t0 = time.perf_counter()
-        out = generate(model, params, b, scfg, max_new=max_new, profile=book)
+        out = generate(model, params, b, scfg, max_new=max_new)
         jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         tps = batch * max_new / dt
         rows.append({"loop": loop, "cache": cache_dtype,
                      "tokens_per_s": tps,
-                     "us_per_token": dt / (batch * max_new) * 1e6,
-                     "roofline": book.summary()})
+                     "us_per_token": dt / (batch * max_new) * 1e6})
         report(f"bench_decode_e2e,loop={loop},cache={cache_dtype},"
                f"tokens_per_s={tps:.1f},us_per_token={dt / (batch * max_new) * 1e6:.1f}")
-        for name, r in book.summary().items():
-            if "roofline_fraction" in r:
-                report(f"bench_decode_roofline,loop={loop},"
-                       f"cache={cache_dtype},exe={name},"
-                       f"gflops={r['achieved_gflops']:.3f},"
-                       f"gbps={r['achieved_gbps']:.3f},"
-                       f"frac={r['roofline_fraction']:.2e},"
-                       f"bound={r['bound_dominant']}")
     return rows
 
 

@@ -150,8 +150,8 @@ def _latency_stats(done):
     ttft = np.array([c.ttft for c in done.values() if c.ttft is not None])
     if ttft.size == 0:
         ttft = np.zeros(1)
-    gaps = [np.diff(c.token_times) for c in done.values()
-            if len(c.token_times) > 1]
+    gaps = [np.diff(c.delivered_times) for c in done.values()
+            if len(c.delivered_times) > 1]
     tbt = np.concatenate(gaps) if gaps else np.zeros(1)
     return {"ttft_p50_ms": float(np.percentile(ttft, 50) * 1e3),
             "ttft_p99_ms": float(np.percentile(ttft, 99) * 1e3),
@@ -161,16 +161,9 @@ def _latency_stats(done):
 
 def run_engine(model, params, reqs, scfg, obs=None):
     """Serve ``reqs`` on a prewarmed engine; returns (metrics dict,
-    completions dict) — callers compare completions across engines.
-
-    When the engine owns its Obs bundle (``obs=None``) the §16 cost book is
-    switched on: prewarm records each executable's ``cost_analysis()``
-    FLOPs/bytes and the serving loop joins them with measured dispatch
-    walls, reported as the ``roofline`` block per engine."""
+    completions dict) — callers compare completions across engines."""
     from repro.serve.scheduler import SlotPoolEngine
     eng = SlotPoolEngine(model, params, scfg, obs=obs)
-    if obs is None:
-        eng.obs.profile.enabled = True  # before prewarm: that's record time
     # compile every admission/burst shape up front: admission group shapes
     # depend on wall-clock arrival timing, so an untimed warmup run would
     # not reliably cover them and a mid-run trace would pollute the timing
@@ -197,12 +190,6 @@ def run_engine(model, params, reqs, scfg, obs=None):
            "model_calls": st["model_calls"],
            "tokens_per_model_call": (st["tokens_emitted"] /
                                      max(1, st["model_calls"]))}
-    # cost-analysis join per dispatched executable (only rows that were
-    # actually observed carry achieved/roofline columns)
-    roof = {name: r for name, r in eng.obs.profile.summary().items()
-            if "roofline_fraction" in r}
-    if roof:
-        out["roofline"] = roof
     out.update(_latency_stats(done))
     if scfg.scheduler == "spec":
         out.update(
@@ -218,16 +205,6 @@ def run_engine(model, params, reqs, scfg, obs=None):
             pages_peak=st["pages_peak"],
             preemptions=st["preemptions"])
     return out, done
-
-
-def _report_roofline(report, tag, r):
-    """One achieved-vs-peak line per executable an engine dispatched."""
-    for name, j in r.get("roofline", {}).items():
-        report(f"bench_serve_roofline,{tag},exe={name},"
-               f"calls={j['calls']},gflops={j['achieved_gflops']:.3f},"
-               f"gbps={j['achieved_gbps']:.3f},"
-               f"frac={j['roofline_fraction']:.2e},"
-               f"bound={j['bound_dominant']}")
 
 
 def make_mixed_workload(cfg, n, rng, short, long_, frac_long, new, rate_hz):
@@ -306,7 +283,6 @@ def run(report, smoke: bool = False, prefix_only: bool = False,
                    f"tokens_per_s={r['tokens_per_s']:.1f},"
                    f"p50_ms={r['p50_ms']:.0f},p99_ms={r['p99_ms']:.0f},"
                    f"occupancy={r['occupancy']:.2f}")
-            _report_roofline(report, mode, r)
         speed = (results["engines"]["continuous"]["tokens_per_s"] /
                  results["engines"]["lockstep"]["tokens_per_s"])
         results["continuous_vs_lockstep"] = speed
@@ -349,7 +325,6 @@ def run(report, smoke: bool = False, prefix_only: bool = False,
         report(f"bench_serve,prefix_{name},"
                f"tokens_per_s={r['tokens_per_s']:.1f},"
                f"prefill_tokens={r['prefill_tokens']}{extra}")
-        _report_roofline(report, f"prefix_{name}", r)
     pspeed = (results["prefix_engines"]["paged_prefix"]["tokens_per_s"] /
               results["prefix_engines"]["dense"]["tokens_per_s"])
     results["paged_prefix_vs_dense"] = pspeed
@@ -404,7 +379,6 @@ def _run_spec(report, results, cfg, model, params, rng, smoke, burst):
         report(f"bench_serve,spec_{name},"
                f"tokens_per_s={r['tokens_per_s']:.1f},"
                f"model_calls={r['model_calls']}{extra}")
-        _report_roofline(report, f"spec_{name}", r)
     sspeed = (results["spec_engines"]["spec"]["tokens_per_s"] /
               results["spec_engines"]["baseline"]["tokens_per_s"])
     results["spec_vs_baseline"] = sspeed
@@ -581,8 +555,8 @@ def _run_obs(report, results, cfg, model, params, smoke,
         actual = sum(len(c.tokens) for c in done.values())
         ttfts = np.array([c.ttft for c in done.values()
                           if c.ttft is not None])
-        gaps = [np.diff(c.token_times) for c in done.values()
-                if len(c.token_times) > 1]
+        gaps = [np.diff(c.delivered_times) for c in done.values()
+                if len(c.delivered_times) > 1]
         tbts = np.concatenate(gaps) if gaps else np.zeros(0)
         out = {"metric_tokens_emitted": tok, "completion_tokens": actual,
                "tokens_match": tok == actual}

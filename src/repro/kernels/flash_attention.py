@@ -202,6 +202,7 @@ def _flash_fwd_impl(q3, k3, v3, maskf, *, cfg: HyftConfig, sm_scale: float,
             jax.ShapeDtypeStruct((BH * Sq, 128), F32),
         ],
         interpret=interpret,
+        name="flash_hyft_attention",
     )(*operands)
     return (o, m_st[:, :1].reshape(BH, Sq, 1),
             l_st[:, :1].reshape(BH, Sq, 1))
@@ -336,6 +337,7 @@ def _flash_bwd_impl(q3, k3, v3, maskf, do3, o3, m2, l2, *, cfg, sm_scale,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), F32),
         interpret=interpret,
+        name="flash_hyft_attention_bwd_dq",
     )(*operands)
 
     # ---- dk/dv: (bh_kv, kv, group*q) grid, dk/dv blocks as carry ----------
@@ -371,6 +373,7 @@ def _flash_bwd_impl(q3, k3, v3, maskf, do3, o3, m2, l2, *, cfg, sm_scale,
             jax.ShapeDtypeStruct((BHkv, Sk, D), F32),
         ],
         interpret=interpret,
+        name="flash_hyft_attention_bwd_dkv",
     )(*operands)
     return dq, dk, dv
 
@@ -589,7 +592,7 @@ def _splitk_kernel(*refs, cfg: HyftConfig, sm_scale: float, quantized: bool,
 
 def _splitk_stats(q3, k, v, mask, *, k_scale, v_scale, block_tables, Hkv: int,
                   bk: int, cfg: HyftConfig, sm_scale: float, group: int,
-                  interpret: bool):
+                  interpret: bool, name: str):
     """Run ``_splitk_kernel`` over a (batch, KV split, kv head) grid and
     return the per-split local stats (acc, m, l) for ``_splitk_combine``.
 
@@ -599,6 +602,8 @@ def _splitk_stats(q3, k, v, mask, *, k_scale, v_scale, block_tables, Hkv: int,
     (n_pages, Hkv, ps), mask (B, nb, mr, ps); pages are the splits and
     ``bk`` is the page size.  The kv head is the innermost grid axis, so a
     scale block (all heads of one split) stays resident across it.
+    ``name`` is the kernel's name in the compiled program and the profile:
+    the public entry point that called it.
     """
     BHkv, rows, D = q3.shape
     B = BHkv // Hkv
@@ -638,11 +643,11 @@ def _splitk_stats(q3, k, v, mask, *, k_scale, v_scale, block_tables, Hkv: int,
             num_scalar_prefetch=1, grid=(B, ns, Hkv), in_specs=in_specs,
             out_specs=out_specs)
         return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                              interpret=interpret)(
+                              interpret=interpret, name=name)(
             block_tables.astype(I32), *operands)
     return pl.pallas_call(kern, grid=(B, ns, Hkv), in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
-                          interpret=interpret)(*operands)
+                          interpret=interpret, name=name)(*operands)
 
 
 def _pad_kv(k, v, k_scale, v_scale, pad_k):
@@ -703,7 +708,8 @@ def flash_hyft_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         _group_rows(q, Hkv, gp), k.reshape(B * Hkv, Skp, D),
         v.reshape(B * Hkv, Skp, D), maskf[:, None, :], k_scale=k_scale,
         v_scale=v_scale, block_tables=None, Hkv=Hkv, bk=bk, cfg=cfg,
-        sm_scale=scale, group=0, interpret=interpret)
+        sm_scale=scale, group=0, interpret=interpret,
+        name="flash_hyft_decode")
 
     # ---- L2: integer-max / fixed-sum tree combine across KV splits
     out = _splitk_combine(acc, m_st, l_st, cfg)
@@ -768,7 +774,7 @@ def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
         _group_rows(q, Hkv, gp), k_pages, v_pages,
         maskf.reshape(B, nb, 1, ps), k_scale=k_scale, v_scale=v_scale,
         block_tables=block_tables, Hkv=Hkv, bk=ps, cfg=cfg, sm_scale=scale,
-        group=0, interpret=interpret)
+        group=0, interpret=interpret, name="flash_hyft_decode_paged")
 
     out = _splitk_combine(acc, m_st, l_st, cfg)
     return out[:, :g].reshape(B, Hkv, g, D).reshape(B, Hq, 1, D)
@@ -901,7 +907,8 @@ def _verify_lanes(q, k, v, maskf, *, cfg, sm_scale, block_k, interpret,
         acc, m_st, l_st = _splitk_stats(
             q3, k, v, maskE, k_scale=k_scale, v_scale=v_scale,
             block_tables=block_tables, Hkv=Hkv, bk=ps, cfg=cfg,
-            sm_scale=scale, group=g, interpret=interpret)
+            sm_scale=scale, group=g, interpret=interpret,
+            name="flash_hyft_verify")
     else:  # ---- contiguous layout: block_k splits, as flash_hyft_decode ----
         Sk = k.shape[2]
         bk = min(block_k, -(-Sk // 128) * 128)  # lane-aligned KV blocks
@@ -914,7 +921,8 @@ def _verify_lanes(q, k, v, maskf, *, cfg, sm_scale, block_k, interpret,
         acc, m_st, l_st = _splitk_stats(
             q3, k.reshape(B * Hkv, Skp, D), v.reshape(B * Hkv, Skp, D), maskE,
             k_scale=k_scale, v_scale=v_scale, block_tables=None, Hkv=Hkv,
-            bk=bk, cfg=cfg, sm_scale=scale, group=g, interpret=interpret)
+            bk=bk, cfg=cfg, sm_scale=scale, group=g, interpret=interpret,
+            name="flash_hyft_verify")
 
     out = _splitk_combine(acc, m_st, l_st, cfg)        # (BH, rows, D)
     out = out.reshape(B, Hkv, g, sp, D)[:, :, :, :Sq]

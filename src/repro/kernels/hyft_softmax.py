@@ -92,6 +92,7 @@ def hyft_softmax_fwd_kernel(z: jax.Array, cfg: HyftConfig,
         out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(z2.shape, F32),
         interpret=interpret,
+        name="hyft_softmax_fwd",
     )(z2)
     if pad:
         out = out[:rows]
@@ -122,6 +123,7 @@ def hyft_softmax_bwd_kernel(s: jax.Array, dy: jax.Array, cfg: HyftConfig,
         out_specs=pl.BlockSpec((br, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(s2.shape, F32),
         interpret=interpret,
+        name="hyft_softmax_bwd",
     )(s2, dy2)
     if pad:
         out = out[:rows]

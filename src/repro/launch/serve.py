@@ -214,9 +214,8 @@ def main():
                 frames=frames, deadline=args.deadline))
         eng = SlotPoolEngine(model, params, scfg, key=sample_key, obs=obs)
         if obs is not None:
-            # compile (and §16 cost-record) every executable up front, so
-            # the trace separates compile spans from steady-state serving
-            # and the cost book has rows for the roofline counter tracks
+            # compile every executable up front, so the trace separates
+            # compile spans from steady-state serving
             eng.prewarm(max(len(r.tokens) for r in reqs))
         try:
             with xla_profile(args.xla_profile):
@@ -268,8 +267,7 @@ def main():
     with xla_profile(args.xla_profile):
         out = generate(model, params, batch, scfg, max_new=args.max_new,
                        key=sample_key,
-                       tracer=obs.tracer if obs is not None else None,
-                       profile=obs.profile if obs is not None else None)
+                       tracer=obs.tracer if obs is not None else None)
         jax.block_until_ready(out)
     for i, row in enumerate(out.tolist()):
         print(f"[{i}] {row}")

@@ -4,7 +4,10 @@ One ``Obs`` object threads all three pillars (DESIGN.md §15) through an
 engine:
 
   tracer   — host-side span tracer emitting Chrome trace-event JSON
-             (``repro.obs.trace``; load the file in Perfetto / chrome://tracing)
+             (``repro.obs.trace``; load the file in Perfetto /
+             chrome://tracing); every span is also a ``jax.profiler``
+             annotation, so a profiler capture (``--xla-profile``) shows
+             the host phases on the device ops' clock
   metrics  — typed counters / gauges / streaming-percentile histograms
              (``repro.obs.metrics``); the scheduler's legacy ``stats`` dict
              is a read-only view over this registry
@@ -12,12 +15,9 @@ engine:
              softmax-input exponent range, fp2fx8 scale histograms, int8
              saturation, convert volume — fed per burst when
              ``ServeConfig.telemetry`` is on
-  profile  — per-executable cost book (``repro.obs.profile``, DESIGN.md
-             §16): FLOPs/bytes captured at compile time, joined with
-             measured dispatch wall-times into achieved GFLOP/s / GB/s /
-             roofline-fraction gauges and trace counter tracks.  Capture
-             is gated on ``profile.enabled`` (on for the ``--trace``
-             bundle) so plain engines never pay the extra re-trace.
+
+Device time, and with it every roofline share, comes from a profiler
+capture (``repro.obs.profile.xla_profile``), never from host walls.
 
 Every ``SlotPoolEngine`` owns an Obs (a fresh disabled-tracer one by
 default, so two engines never share counters unless the caller passes a
@@ -32,7 +32,6 @@ from typing import Optional
 
 from repro.obs.metrics import Registry
 from repro.obs.numerics import NumericsMonitor
-from repro.obs.profile import CostBook
 from repro.obs.trace import NULL_TRACER, Tracer, compile_watch  # noqa: F401
 
 
@@ -43,7 +42,6 @@ class Obs:
     metrics: Registry = dataclasses.field(default_factory=Registry)
     numerics: NumericsMonitor = dataclasses.field(
         default_factory=NumericsMonitor)
-    profile: CostBook = dataclasses.field(default_factory=CostBook)
     # periodic metrics JSONL export (None = no export); snapshots are
     # appended from the serving loop every ``snapshot_every_s`` seconds and
     # once more at the end of every run
@@ -51,17 +49,11 @@ class Obs:
     snapshot_every_s: float = 1.0
     _last_snapshot: float = dataclasses.field(default=0.0, repr=False)
 
-    def __post_init__(self):
-        # the cost book emits through THIS bundle's registry/tracer
-        self.profile.bind(self.metrics, self.tracer)
-
     @classmethod
     def enabled(cls, metrics_path: Optional[str] = None,
                 snapshot_every_s: float = 1.0) -> "Obs":
-        """An Obs with the tracer + cost profiling ON (the ``--trace``
-        bundle)."""
+        """An Obs with the tracer ON (the ``--trace`` bundle)."""
         return cls(tracer=Tracer(enabled=True),
-                   profile=CostBook(enabled=True),
                    metrics_path=metrics_path,
                    snapshot_every_s=snapshot_every_s)
 

@@ -8,14 +8,16 @@ Three layers on top of the §15 spans/metrics substrate:
                    triggers a second backend compile — so capture at
                    prewarm/build time costs a fraction of the compile the
                    executable is paying anyway.
-  ``CostBook``   — the per-executable cost ledger the serving engine feeds:
-                   costs recorded at compile time (the same prewarm that
-                   runs under ``compile_watch``), wall times observed per
-                   dispatch.  On a chip listed in ``roofline/hw.py`` the
+  ``CostBook``   — a per-executable cost ledger: costs recorded at
+                   compile time, wall times observed per dispatch by its
+                   caller.  On a chip listed in ``roofline/hw.py`` the
                    join emits achieved GFLOP/s, GB/s, and the roofline
-                   fraction against that chip's peaks into the metrics
+                   fraction against that chip's peaks into a bound metrics
                    registry (``perf.*{executable=...}``) and as trace
-                   counter events on the Perfetto timeline.
+                   counter events.  Nothing on the serving path feeds it:
+                   a host wall over ``cost_analysis()`` cannot see inside
+                   a Pallas ``tpu_custom_call``, so device time and
+                   roofline shares come from the profiler trace instead.
   ``microbench`` — kernel timing over ``default_registry()``: us/call per
                    (kernel, shape, format), plus achieved-vs-peak on a
                    listed chip — the BENCH_kernels.json rows.

@@ -19,7 +19,6 @@ key threading.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -192,21 +191,14 @@ def build_prefill_chunk(model, scfg: ServeConfig, width: int):
 
 
 def generate(model, params, batch: dict, scfg: ServeConfig, max_new: int,
-             key=None, tracer=None, profile=None):
+             key=None, tracer=None):
     """Prefill the prompt then decode ``max_new`` tokens. Returns (B, max_new).
 
     ``tracer``: optional ``repro.obs.trace.Tracer`` — the host decode loop
-    and the prefill/scan dispatches run under spans when provided.
-    ``profile``: optional ``repro.obs.profile.CostBook`` — executable costs
-    are recorded before each dispatch and joined with measured walls (the
-    extra ``block_until_ready`` syncs only happen with a book attached)."""
+    and the prefill/scan dispatches run under spans when provided."""
     if tracer is None:
         from repro.obs.trace import NULL_TRACER
         tracer = NULL_TRACER
-    if profile is not None and profile.enabled:
-        from repro.roofline.analysis import scan_trip_factor
-    else:
-        profile = scan_trip_factor = None
     key = key if key is not None else jax.random.PRNGKey(0)
     from repro.models import resolve_attn_mode
     model = resolve_attn_mode(model, scfg.attn_mode)
@@ -224,19 +216,8 @@ def generate(model, params, batch: dict, scfg: ServeConfig, max_new: int,
         nv = (jnp.asarray(lens, I32) if lens is not None
               else jnp.full((B,), S, I32))
         pc = build_prefill_chunk(model, scfg, S)
-        pc_args = (params, cache, toks, jnp.zeros((B,), I32), nv,
-                   jnp.ones((B,), bool))
-        if profile is not None:  # record before the call: cache is donated
-            profile.record(f"prefill_chunk[w={S}]", pc, *pc_args,
-                           trip_factor=scan_trip_factor(
-                               model.cfg, "prefill", S, 1, 1))
-            t_pc = time.perf_counter()
-            last, cache = pc(*pc_args)
-            jax.block_until_ready(last)
-            profile.observe(f"prefill_chunk[w={S}]",
-                            time.perf_counter() - t_pc)
-        else:
-            last, cache = pc(*pc_args)
+        last, cache = pc(params, cache, toks, jnp.zeros((B,), I32), nv,
+                         jnp.ones((B,), bool))
         pos = S
     else:
         logits, cache, pos = build_prefill(model)(params, cache, batch)
@@ -254,12 +235,7 @@ def generate(model, params, batch: dict, scfg: ServeConfig, max_new: int,
     if scfg.decode_loop == "host":
         out = [tok]
         step = build_serve_step(model, scfg)
-        if profile is not None and max_new > 1:
-            profile.record("serve_step", step, params, cache, tok, pos, key,
-                           trip_factor=scan_trip_factor(
-                               model.cfg, "decode", 1, 1, 1))
         with tracer.span("decode_host_loop", steps=max_new - 1):
-            t_loop = time.perf_counter()
             for i in range(max_new - 1):
                 if scfg.temperature > 0:
                     key, sub = jax.random.split(key)
@@ -267,28 +243,11 @@ def generate(model, params, batch: dict, scfg: ServeConfig, max_new: int,
                     sub = key
                 tok, cache = step(params, cache, tok, pos + i, sub)
                 out.append(tok)
-            if profile is not None and max_new > 1:
-                jax.block_until_ready(tok)
-                # the loop wall over the step count: per-step mean — the
-                # per-step syncs a per-dispatch join would need distort
-                # exactly the pipelining the host loop is benched for
-                profile.observe("serve_step", (time.perf_counter() - t_loop)
-                                / (max_new - 1))
         return jnp.concatenate(out, axis=1)
 
     if max_new <= 1:
         return tok
     with tracer.span("decode_scan", steps=max_new - 1):
         loop = build_decode_loop(model, scfg, max_new - 1)
-        name = f"decode_loop[steps={max_new - 1}]"
-        if profile is not None:
-            profile.record(name, loop, params, cache, tok, pos, key,
-                           trip_factor=(max_new - 1) * scan_trip_factor(
-                               model.cfg, "decode", 1, 1, 1))
-            t_loop = time.perf_counter()
-            toks, _ = loop(params, cache, tok, pos, key)
-            jax.block_until_ready(toks)
-            profile.observe(name, time.perf_counter() - t_loop)
-        else:
-            toks, _ = loop(params, cache, tok, pos, key)
+        toks, _ = loop(params, cache, tok, pos, key)
     return jnp.concatenate([tok, toks], axis=1)

@@ -137,11 +137,16 @@ class Completion:
     prompt_len: int
     finished_at: float                # seconds after run() start
     arrival: float = 0.0
-    # per-token emission timestamps (seconds after run() start, stamped at
-    # burst/prefill completion — tokens emitted by one burst share one
-    # stamp).  token_times[0] - arrival is the TTFT; successive diffs are
-    # the inter-token (TBT) gaps the chunked-prefill scheduling bounds.
+    # per-token DISPATCH stamps (seconds after run() start): the ``now``
+    # the producing prefill/burst call was started with, before its
+    # device work ran — tokens of one call share one stamp.  The serving
+    # benchmark keys its own re-timing on these stamps.
     token_times: list = dataclasses.field(default_factory=list)
+    # per-token DELIVERY times (seconds after run() start): when the host
+    # held the token, after the producing call's device-to-host copy.
+    # delivered_times[0] - arrival is the TTFT; successive diffs are the
+    # inter-token (TBT) gaps the chunked-prefill scheduling bounds.
+    delivered_times: list = dataclasses.field(default_factory=list)
     # robustness outcome: every request terminates with a definite one —
     # ok (finished), cancelled (host cancel/shutdown, partial tokens), or
     # failure (structured reason, partial tokens)
@@ -158,12 +163,13 @@ class Completion:
 
     @property
     def ttft(self) -> Optional[float]:
-        """Time to first token, or None when the request never emitted one
-        (failed/cancelled/zero-token) — aggregations must skip None rather
-        than fold total latency into the TTFT percentiles."""
-        if not self.token_times:
+        """Time to first token (its delivery), or None when the request
+        never emitted one (failed/cancelled/zero-token) — aggregations must
+        skip None rather than fold total latency into the TTFT
+        percentiles."""
+        if not self.delivered_times:
             return None
-        return self.token_times[0] - self.arrival
+        return self.delivered_times[0] - self.arrival
 
 
 def _bucket(n: int, lo: int = 4) -> int:
@@ -439,7 +445,13 @@ class SlotPoolEngine:
         # tokens out of it, and trie publication reads it at completion
         self.slot_prompt: list[Optional[np.ndarray]] = [None] * n
         self.outputs: dict[int, list] = {}
-        self.out_times: dict[int, list] = {}  # per-token emission stamps
+        self.out_times: dict[int, list] = {}  # per-token dispatch stamps
+        self.deliv_times: dict[int, list] = {}  # per-token delivery times
+        # request-life events (DESIGN.md §15), on the tracer's clock: when
+        # each rid's current wait in the queue began (a requeue), and when
+        # its current admission began with whether it is a resume
+        self._queued_at: dict[int, float] = {}
+        self._admitted_at: dict[int, tuple] = {}
         self.requests: dict[int, Request] = {}
         self.completions: dict[int, Completion] = {}
         self._queue: deque = deque()   # arrived, waiting (bounded)
@@ -480,6 +492,7 @@ class SlotPoolEngine:
         self.straggler = StragglerMonitor()
         self._step_ema = 0.0
         self._t0: Optional[float] = None         # run() epoch, for shutdown
+        self._t0_tr: Optional[float] = None      # the same on tracer clock
         # the fp32 fallback engine must fail structurally, never recurse
         self._allow_fp32_retry = True
         self._zero_pages = None                  # lazy jitted page scrub
@@ -544,12 +557,13 @@ class SlotPoolEngine:
 
     def _record_completion(self, c: Completion) -> None:
         """Latency histograms at completion time — TTFT (skipping None)
-        and per-gap TBT — so metric aggregates reconcile with post-hoc
-        numbers computed from the Completion records by construction."""
+        and per-gap TBT, both from delivery times — so metric aggregates
+        reconcile with post-hoc numbers computed from the Completion
+        records by construction."""
         t = c.ttft
         if t is not None:
             self._hists["ttft_s"].observe(t)
-        tt = c.token_times
+        tt = c.delivered_times
         for i in range(1, len(tt)):
             self._hists["tbt_s"].observe(tt[i] - tt[i - 1])
 
@@ -598,24 +612,12 @@ class SlotPoolEngine:
                                               scfg.cache_dtype)
                 self.cache = self._scatter(self.cache, fresh,
                                            jnp.arange(n, dtype=I32))
-            # cost capture (DESIGN.md §16) must happen BEFORE each
-            # executing call: the executables donate the cache buffer, and
-            # ``lower`` at live args is the only time the shapes are in
-            # hand.  XLA counts scan bodies once, so the trip factor
-            # carries the layers-scan (and burst-steps) product.
-            from repro.roofline.analysis import scan_trip_factor
-            book = self.obs.profile
-            cfg = self.model.cfg
-            layers = scan_trip_factor(cfg, "decode", 1, 1, 1)
             for w in sorted(widths):
                 pc = engine.build_prefill_chunk(
                     self.model, exec_key_cfg(scfg), w)
                 args = (self.params, self.cache, jnp.zeros((n, w), I32),
                         jnp.zeros(n, I32), jnp.ones(n, I32),
                         jnp.zeros(n, bool))
-                book.record(f"prefill_chunk[w={w}]", pc, *args,
-                            trip_factor=scan_trip_factor(
-                                cfg, "prefill", w, 1, 1))
                 # gate all-False: every row computes but none writes, so the
                 # live pool is untouched — no scratch/restore dance needed
                 out, self.cache = pc(*args)
@@ -626,8 +628,6 @@ class SlotPoolEngine:
                         jnp.zeros((n, K), I32), jnp.zeros(n, I32),
                         jnp.zeros(n, I32), jnp.zeros(n, bool),
                         jnp.zeros(n, I32))
-                book.record("spec_step", self._spec_step, *args,
-                            trip_factor=layers)
                 out = self._spec_step(*args)
                 self.cache = out[1]
             else:
@@ -635,8 +635,6 @@ class SlotPoolEngine:
                         jnp.zeros(n, I32), jnp.zeros(n, bool),
                         jnp.zeros(n, I32), jnp.full(n, TTL_NONE, I32),
                         jax.random.PRNGKey(0))
-                book.record("decode_burst", self._burst, *args,
-                            trip_factor=max(1, scfg.decode_burst) * layers)
                 out = self._burst(*args)
                 self.cache = out[2]
             jax.block_until_ready(out[0])
@@ -661,6 +659,7 @@ class SlotPoolEngine:
             self.requests[r.rid] = r
             self.outputs[r.rid] = []
             self.out_times[r.rid] = []
+            self.deliv_times[r.rid] = []
 
     def _start_prefill(self, s: int, r: Request, start: int) -> None:
         """Host bookkeeping that puts ``r`` into slot ``s`` in the
@@ -669,6 +668,14 @@ class SlotPoolEngine:
         if not r.resume:
             self._register(r)
             self._count("admitted")
+        tracer = self.obs.tracer
+        t = tracer.now()
+        if self._t0_tr is not None:
+            # queued from arrival, or from the requeue for a resume
+            tq = self._queued_at.pop(r.rid, self._t0_tr + r.arrival)
+            tracer.complete("request_queued", tq, t, rid=r.rid,
+                            resume=r.resume)
+        self._admitted_at[r.rid] = (t, r.resume)
         self.slot_rid[s] = r.rid
         self.slot_prompt[s] = np.asarray(r.tokens, np.int32)
         self.lengths[s] = start
@@ -836,63 +843,70 @@ class SlotPoolEngine:
         rows = [s for s in range(n) if self.prefilling[s]]
         if not rows:
             return
-        if not scfg.pack_prefill:
-            rows = [min(rows, key=lambda s: (
-                self.requests[self.slot_rid[s]].arrival, self.slot_rid[s]))]
-        rem = {s: len(self.slot_prompt[s]) - int(self.lengths[s])
-               for s in rows}
-        cap = min(_bucket(max(rem.values())), scfg.max_len)
-        width = min(scfg.prefill_chunk, cap) if scfg.prefill_chunk > 0 \
-            else cap
-        toks = np.zeros((n, width), np.int32)
-        n_valid = np.ones(n, np.int32)
-        gate = np.zeros(n, bool)
-        for s in rows:
-            part = self.slot_prompt[s][int(self.lengths[s]):
-                                       int(self.lengths[s]) + width]
-            toks[s, :len(part)] = part
-            n_valid[s] = len(part)
-            gate[s] = True
-        if self.paged:
-            self.cache["block_tables"] = jnp.asarray(self.block_tables)
-        with self.obs.tracer.span("prefill_chunk", width=width,
-                                  rows=len(rows)):
+        tracer = self.obs.tracer
+        with tracer.span("prefill_pack"):
+            if not scfg.pack_prefill:
+                rows = [min(rows, key=lambda s: (
+                    self.requests[self.slot_rid[s]].arrival,
+                    self.slot_rid[s]))]
+            rem = {s: len(self.slot_prompt[s]) - int(self.lengths[s])
+                   for s in rows}
+            cap = min(_bucket(max(rem.values())), scfg.max_len)
+            width = min(scfg.prefill_chunk, cap) if scfg.prefill_chunk > 0 \
+                else cap
+            toks = np.zeros((n, width), np.int32)
+            n_valid = np.ones(n, np.int32)
+            gate = np.zeros(n, bool)
+            for s in rows:
+                part = self.slot_prompt[s][int(self.lengths[s]):
+                                           int(self.lengths[s]) + width]
+                toks[s, :len(part)] = part
+                n_valid[s] = len(part)
+                gate[s] = True
+            if self.paged:
+                self.cache["block_tables"] = jnp.asarray(self.block_tables)
+        with tracer.span("prefill_chunk", width=width, rows=len(rows)):
             pc = engine.build_prefill_chunk(self.model,
                                             exec_key_cfg(scfg), width)
-            # jnp.asarray copies the host mirror, so mutating self.lengths
-            # below cannot race the dispatched call
-            t_in = time.perf_counter()
+            # hand the call a copy of the host mirror: on the CPU backend
+            # jnp.asarray may alias a numpy buffer, and self.lengths is
+            # advanced below while the dispatched call can still read it
             last, self.cache = pc(self.params, self.cache,
                                   jnp.asarray(toks),
-                                  jnp.asarray(self.lengths),
+                                  jnp.asarray(self.lengths.copy()),
                                   jnp.asarray(n_valid), jnp.asarray(gate))
-            exe = f"prefill_chunk[w={width}]"
-            if exe in self.obs.profile:  # cost join needs the real wall
-                jax.block_until_ready(last)
-                self.obs.profile.observe(exe, time.perf_counter() - t_in)
-        self._count("prefills")
-        for s in rows:
-            self.lengths[s] += min(rem[s], width)
-        # numeric health: every gated row's last-lane logits must be finite
-        # — a poisoned KV page / fp2fx8 scale row surfaces here before the
-        # slot ever decodes, and the quarantine ladder takes it
-        finite = np.asarray(jnp.isfinite(last).all(-1))
-        bad = [s for s in rows if not finite[s]]
-        for s in bad:
-            self._quarantine(s, now, where="prefill")
-        fin = [s for s in rows if rem[s] <= width and s not in bad]
-        if fin:
-            tok0 = np.asarray(self._first_token(last), np.int32)
-            for s in fin:
-                self._finish_prefill(s, int(tok0[s]), now)
-        self._peak("peak_active", int(self.active.sum()))
-        self._audit_check()
+        with tracer.span("prefill_finish"):
+            self._count("prefills")
+            for s in rows:
+                self.lengths[s] += min(rem[s], width)
+            # numeric health: every gated row's last-lane logits must be
+            # finite — a poisoned KV page / fp2fx8 scale row surfaces here
+            # before the slot ever decodes, and the quarantine ladder
+            # takes it
+            finite = np.asarray(jnp.isfinite(last).all(-1))
+            bad = [s for s in rows if not finite[s]]
+            for s in bad:
+                self._quarantine(s, now, where="prefill")
+            fin = [s for s in rows if rem[s] <= width and s not in bad]
+            if fin:
+                tok0 = np.asarray(self._first_token(last), np.int32)
+                held, t_held = self._now(), tracer.now()
+                for s in fin:
+                    rid = self.slot_rid[s]
+                    t_adm, resume = self._admitted_at.pop(rid)
+                    tracer.complete("request_prefill", t_adm, t_held,
+                                    rid=rid, resume=resume)
+                    self._finish_prefill(s, int(tok0[s]), now, held)
+            self._peak("peak_active", int(self.active.sum()))
+            self._audit_check()
 
-    def _finish_prefill(self, s: int, tok0: int, now: float) -> None:
+    def _finish_prefill(self, s: int, tok0: int, now: float,
+                        held: float) -> None:
         """Slot ``s``'s whole prompt is cached and its first generated
-        token is in hand: publish the prompt's full pages to the prefix
-        cache, emit the token, and either activate the slot for decode or
-        free it (EOS / budget exhausted on the very first token)."""
+        token is in hand (since ``held``, on the run clock): publish the
+        prompt's full pages to the prefix cache, emit the token, and either
+        activate the slot for decode or free it (EOS / budget exhausted on
+        the very first token)."""
         self.prefilling[s] = False
         rid = self.slot_rid[s]
         if self.trie is not None:
@@ -908,6 +922,7 @@ class SlotPoolEngine:
             self._peak("pages_peak", self.pool.pages_in_use)
         self.outputs[rid].append(tok0)
         self.out_times[rid].append(now)
+        self.deliv_times[rid].append(held)
         self._count("tokens_emitted")
         done = (self.budget[s] <= 1
                 or (self._eos is not None and tok0 == self._eos))
@@ -944,6 +959,7 @@ class SlotPoolEngine:
             rid=rid, tokens=self.outputs.get(rid, []),
             prompt_len=len(r.tokens), finished_at=now, arrival=r.arrival,
             token_times=list(self.out_times.get(rid, [])),
+            delivered_times=list(self.deliv_times.get(rid, [])),
             failure=FailureInfo(reason=reason, detail=detail,
                                 retries=self.retries.get(rid, 0)))
         self._count("failures")
@@ -974,6 +990,7 @@ class SlotPoolEngine:
             rid=rid, tokens=toks, max_new=orig.max_new - len(done),
             frames=orig.frames, arrival=orig.arrival,
             deadline=orig.deadline, resume=True))
+        self._queued_at[rid] = self.obs.tracer.now()
         return True
 
     def _preempt_latest(self) -> bool:
@@ -1029,7 +1046,8 @@ class SlotPoolEngine:
         self.completions[rid] = Completion(
             rid=rid, tokens=self.outputs[rid], prompt_len=len(r.tokens),
             finished_at=now, arrival=r.arrival,
-            token_times=list(self.out_times[rid]))
+            token_times=list(self.out_times[rid]),
+            delivered_times=list(self.deliv_times[rid]))
         self._record_completion(self.completions[rid])
 
     # -- decode --------------------------------------------------------
@@ -1086,19 +1104,21 @@ class SlotPoolEngine:
         device decrements alongside budget; per-step finite flags come back
         with the tokens, and a slot whose logits went non-finite keeps only
         its finite-prefix tokens and is quarantined."""
-        if self.chaos is not None:
-            self.chaos.fire(self, "pre_burst")
         if self.spec:
             self._spec_burst(now)
             return
-        if self.paged:
-            self._ensure_burst_pages(max(1, self.scfg.decode_burst))
-            if not self.active.any():  # everyone preempted: nothing to run
-                return
-            self.cache["block_tables"] = jnp.asarray(self.block_tables)
-        was_active = self.active.copy()
-        with self.obs.tracer.span("decode_burst",
-                                  active=int(self.active.sum())):
+        tracer = self.obs.tracer
+        with tracer.span("burst_prep"):
+            if self.chaos is not None:
+                self.chaos.fire(self, "pre_burst")
+            if self.paged:
+                self._ensure_burst_pages(max(1, self.scfg.decode_burst))
+                if not self.active.any():  # everyone preempted: no burst
+                    return
+                self.cache["block_tables"] = jnp.asarray(self.block_tables)
+            ttl = self._ttl_vector(now)
+            was_active = self.active.copy()
+        with tracer.span("decode_burst", active=int(self.active.sum())):
             t_in = time.perf_counter()
             emits, oks, self.cache, tok, lengths, active, budget, ttl_out, \
                 self.key, tstats = self._burst(
@@ -1107,8 +1127,9 @@ class SlotPoolEngine:
                     jnp.asarray(self.lengths),
                     jnp.asarray(self.active),
                     jnp.asarray(self.budget),
-                    jnp.asarray(self._ttl_vector(now)), self.key)
+                    jnp.asarray(ttl), self.key)
             emits = np.asarray(emits)                   # (steps, n_slots)
+            held = self._now()
             oks = np.asarray(oks)                       # (steps, n_slots)
             ttl_out = np.asarray(ttl_out)
             # np.array (not asarray): jax exports read-only views, but
@@ -1119,38 +1140,40 @@ class SlotPoolEngine:
             self.last_tok = np.array(tok)[:, 0]
             dt = time.perf_counter() - t_in  # np.asarray blocked above
             self._observe_burst(dt, emits.shape[0])
-            self.obs.profile.observe("decode_burst", dt)
-        if tstats:
-            self.obs.numerics.update(tstats)
-        self._count("bursts")
-        self._count("burst_steps", emits.shape[0])
-        self._count("model_calls", emits.shape[0])
-        n_active_steps = int((emits != PAD).sum())
-        self._count("slot_steps_active", n_active_steps)
-        self._count_converts(n_active_steps)
-        for s in np.nonzero(was_active)[0]:
-            col = emits[:, s]
-            bad = np.nonzero(~oks[:, s])[0]
-            # keep only the finite-prefix tokens: the first non-finite
-            # step's sample (and everything after) is garbage
-            col = col[:int(bad[0])] if bad.size else col
-            toks = col[col != PAD].tolist()
-            rid = self.slot_rid[s]
-            self.outputs[rid].extend(toks)
-            self.out_times[rid].extend([now] * len(toks))
-            self._count("tokens_emitted", len(toks))
-            if bad.size:
-                self._quarantine(s, now, where="burst")
-                continue
-            if not self.active[s]:                      # freed on device
-                hit_eos = (self._eos is not None and toks
-                           and toks[-1] == self._eos)
-                if ttl_out[s] <= 0 and self.budget[s] > 0 and not hit_eos:
-                    self._expire_slot(s, now)           # deadline TTL
-                else:
-                    self._finish(rid, now)
-                    self._free_slot(s)
-        self._audit_check()
+        with tracer.span("burst_emit"):
+            if tstats:
+                self.obs.numerics.update(tstats)
+            self._count("bursts")
+            self._count("burst_steps", emits.shape[0])
+            self._count("model_calls", emits.shape[0])
+            n_active_steps = int((emits != PAD).sum())
+            self._count("slot_steps_active", n_active_steps)
+            self._count_converts(n_active_steps)
+            for s in np.nonzero(was_active)[0]:
+                col = emits[:, s]
+                bad = np.nonzero(~oks[:, s])[0]
+                # keep only the finite-prefix tokens: the first non-finite
+                # step's sample (and everything after) is garbage
+                col = col[:int(bad[0])] if bad.size else col
+                toks = col[col != PAD].tolist()
+                rid = self.slot_rid[s]
+                self.outputs[rid].extend(toks)
+                self.out_times[rid].extend([now] * len(toks))
+                self.deliv_times[rid].extend([held] * len(toks))
+                self._count("tokens_emitted", len(toks))
+                if bad.size:
+                    self._quarantine(s, now, where="burst")
+                    continue
+                if not self.active[s]:                  # freed on device
+                    hit_eos = (self._eos is not None and toks
+                               and toks[-1] == self._eos)
+                    if (ttl_out[s] <= 0 and self.budget[s] > 0
+                            and not hit_eos):
+                        self._expire_slot(s, now)       # deadline TTL
+                    else:
+                        self._finish(rid, now)
+                        self._free_slot(s)
+            self._audit_check()
 
     # -- speculative decode (repro/serve/spec.py; DESIGN.md §11) --------
 
@@ -1163,41 +1186,45 @@ class SlotPoolEngine:
         pages the rejected lanes wrote into."""
         scfg = self.scfg
         K = scfg.draft_k
-        if self.paged:
-            # verify writes lanes L..L+m (m <= min(K, budget-1)): cover the
-            # worst case before the call, preempting on pool exhaustion
-            self._ensure_burst_pages(K + 1)
-            if not self.active.any():  # everyone preempted: nothing to run
-                return
-            self.cache["block_tables"] = jnp.asarray(self.block_tables)
-        n = scfg.n_slots
-        want = np.zeros(n, np.int32)
-        contexts: list = [None] * n
-        for s in range(n):
-            if not self.active[s]:
-                continue
-            # drafts past budget-1 can never be emitted, and the verify
-            # write frontier must stay inside max_len
-            want[s] = max(0, min(K, int(self.budget[s]) - 1,
-                                 scfg.max_len - 1 - int(self.lengths[s])))
-            rid = self.slot_rid[s]
-            contexts[s] = np.concatenate(
-                [np.asarray(self.requests[rid].tokens, np.int32),
-                 np.asarray(self.outputs[rid], np.int32)])
-        calls0 = self.drafter.model_calls
-        draft, n_draft = self.drafter.draft_batch(contexts, want, K)
-        # a model drafter's teacher-sync/draft-loop invocations count too,
-        # so tokens-per-model-call never overstates the amortization
-        self._count("model_calls", self.drafter.model_calls - calls0)
-        if self.chaos is not None:
-            # drafter-desync fault: junk drafts are REJECTED by exact
-            # verification, so outputs are provably unchanged
-            draft, n_draft = self.chaos.corrupt_drafts(self, draft, n_draft,
-                                                       want)
-
-        was_active = self.active.copy()
-        with self.obs.tracer.span("spec_verify",
-                                  active=int(self.active.sum())):
+        tracer = self.obs.tracer
+        with tracer.span("burst_prep"):
+            if self.chaos is not None:
+                self.chaos.fire(self, "pre_burst")
+            if self.paged:
+                # verify writes lanes L..L+m (m <= min(K, budget-1)): cover
+                # the worst case before the call, preempting on pool
+                # exhaustion
+                self._ensure_burst_pages(K + 1)
+                if not self.active.any():  # everyone preempted: no burst
+                    return
+                self.cache["block_tables"] = jnp.asarray(self.block_tables)
+            n = scfg.n_slots
+            want = np.zeros(n, np.int32)
+            contexts: list = [None] * n
+            for s in range(n):
+                if not self.active[s]:
+                    continue
+                # drafts past budget-1 can never be emitted, and the verify
+                # write frontier must stay inside max_len
+                want[s] = max(0, min(K, int(self.budget[s]) - 1,
+                                     scfg.max_len - 1 - int(self.lengths[s])))
+                rid = self.slot_rid[s]
+                contexts[s] = np.concatenate(
+                    [np.asarray(self.requests[rid].tokens, np.int32),
+                     np.asarray(self.outputs[rid], np.int32)])
+            calls0 = self.drafter.model_calls
+            draft, n_draft = self.drafter.draft_batch(contexts, want, K)
+            # a model drafter's teacher-sync/draft-loop invocations count
+            # too, so tokens-per-model-call never overstates the
+            # amortization
+            self._count("model_calls", self.drafter.model_calls - calls0)
+            if self.chaos is not None:
+                # drafter-desync fault: junk drafts are REJECTED by exact
+                # verification, so outputs are provably unchanged
+                draft, n_draft = self.chaos.corrupt_drafts(self, draft,
+                                                           n_draft, want)
+            was_active = self.active.copy()
+        with tracer.span("spec_verify", active=int(self.active.sum())):
             t_in = time.perf_counter()
             emitted, self.cache, tok, lengths, active, budget, n_acc, ok, \
                 tstats = self._spec_step(
@@ -1208,6 +1235,7 @@ class SlotPoolEngine:
                     jnp.asarray(self.active),
                     jnp.asarray(self.budget))
             emitted = np.asarray(emitted)               # (n_slots, K + 1)
+            held = self._now()
             n_acc = np.asarray(n_acc)
             ok = np.asarray(ok)                         # per-slot finite bit
             self.lengths = np.array(lengths)
@@ -1216,36 +1244,39 @@ class SlotPoolEngine:
             self.last_tok = np.array(tok)[:, 0]
             dt = time.perf_counter() - t_in
             self._observe_burst(dt, 1)
-            self.obs.profile.observe("spec_step", dt)
-        if tstats:
-            self.obs.numerics.update(tstats)
-        self._count("bursts")
-        self._count("burst_steps")
-        self._count("spec_steps")
-        self._count("model_calls")
-        for s in np.nonzero(was_active)[0]:
-            if not ok[s]:
-                # non-finite verify logits poison every lane's argmax: no
-                # token from this step can be trusted, so emit nothing and
-                # quarantine (the finite prefix already in outputs stands)
-                self._quarantine(s, now, where="spec")
-                continue
-            row = emitted[s]
-            row = row[row != PAD].tolist()
-            self.outputs[self.slot_rid[s]].extend(row)
-            self.out_times[self.slot_rid[s]].extend([now] * len(row))
-            self._count("tokens_emitted", len(row))
-            self._count("draft_tokens", int(n_draft[s]))
-            self._count("accepted_tokens", int(n_acc[s]))
-            self._count_converts(len(row))
-            if row:
-                self._count("slot_steps_active")
-            if not self.active[s]:                      # freed on device
-                self._finish(self.slot_rid[s], now)
-                self._free_slot(s)
-        if self.paged:
-            self._rollback_spec_pages()
-        self._audit_check()
+        with tracer.span("burst_emit"):
+            if tstats:
+                self.obs.numerics.update(tstats)
+            self._count("bursts")
+            self._count("burst_steps")
+            self._count("spec_steps")
+            self._count("model_calls")
+            for s in np.nonzero(was_active)[0]:
+                if not ok[s]:
+                    # non-finite verify logits poison every lane's argmax:
+                    # no token from this step can be trusted, so emit
+                    # nothing and quarantine (the finite prefix already in
+                    # outputs stands)
+                    self._quarantine(s, now, where="spec")
+                    continue
+                row = emitted[s]
+                row = row[row != PAD].tolist()
+                rid = self.slot_rid[s]
+                self.outputs[rid].extend(row)
+                self.out_times[rid].extend([now] * len(row))
+                self.deliv_times[rid].extend([held] * len(row))
+                self._count("tokens_emitted", len(row))
+                self._count("draft_tokens", int(n_draft[s]))
+                self._count("accepted_tokens", int(n_acc[s]))
+                self._count_converts(len(row))
+                if row:
+                    self._count("slot_steps_active")
+                if not self.active[s]:                  # freed on device
+                    self._finish(rid, now)
+                    self._free_slot(s)
+            if self.paged:
+                self._rollback_spec_pages()
+            self._audit_check()
 
     def _rollback_spec_pages(self) -> None:
         """Un-append tail pages past each active slot's post-acceptance
@@ -1368,6 +1399,7 @@ class SlotPoolEngine:
         fin = self._now()
         self.outputs[rid].extend(comp.tokens)
         self.out_times[rid].extend([fin] * len(comp.tokens))
+        self.deliv_times[rid].extend([fin] * len(comp.tokens))
         if comp.failure is None:
             self._finish(rid, fin)
         else:
@@ -1389,7 +1421,9 @@ class SlotPoolEngine:
         self.completions[rid] = Completion(
             rid=rid, tokens=self.outputs.get(rid, []),
             prompt_len=len(r.tokens), finished_at=now, arrival=r.arrival,
-            token_times=list(self.out_times.get(rid, [])), cancelled=True)
+            token_times=list(self.out_times.get(rid, [])),
+            delivered_times=list(self.deliv_times.get(rid, [])),
+            cancelled=True)
         self._count("cancelled")
         self._record_completion(self.completions[rid])
 
@@ -1486,6 +1520,45 @@ class SlotPoolEngine:
         with compile_watch(tracer, enabled=tracer.enabled):
             return self._run(requests)
 
+    def _schedule(self, now: float, continuous: bool) -> None:
+        """One scheduling checkpoint of the serving loop: cancels and
+        deadline expiry, arrivals into the queue, admission into free
+        slots, then the load gauges and the metrics snapshot."""
+        if self.chaos is not None:
+            self.chaos.fire(self, "tick")
+        self._apply_cancels(now)
+        self._expire(now)
+        # arrivals move into the BOUNDED waiting queue: admission
+        # backpressure rejects (reason "queue_full") instead of letting
+        # the queue grow without limit; requeues from preemption /
+        # quarantine bypass this — they already held an admission
+        while self._pending and self._pending[0].arrival <= now:
+            r = self._pending.popleft()
+            if (self.scfg.max_queue
+                    and len(self._queue) >= self.scfg.max_queue):
+                self._fail(r.rid, "queue_full", now,
+                           detail=f"{len(self._queue)} waiting")
+                self._count("rejected")
+            else:
+                self._queue.append(r)
+        free = sum(1 for rid in self.slot_rid if rid is None)
+        busy = self.active.any() or self.prefilling.any()
+        can_admit = continuous or not busy
+        batch = []
+        while can_admit and self._queue and len(batch) < free:
+            batch.append(self._queue.popleft())
+        if batch:
+            # page-starved admissions requeue their tail to the front
+            self.admit(batch, self._now())
+            self._audit_check()
+        # per-iteration load gauges + periodic metrics snapshot export
+        self._gauges["queue_depth"].set(len(self._queue))
+        self._gauges["slot_occupancy"].set(
+            sum(1 for rid in self.slot_rid if rid is not None))
+        if self.paged:
+            self._gauges["pages_in_use"].set(self.pool.pages_in_use)
+        self.obs.maybe_snapshot()
+
     def _run(self, requests: list[Request]) -> dict[int, Completion]:
         ok_reqs = []
         for r in sorted(requests, key=lambda r: r.arrival):
@@ -1502,45 +1575,14 @@ class SlotPoolEngine:
                 ok_reqs.append(r)
         self._pending = deque(ok_reqs)
         self._queue = deque()
+        tracer = self.obs.tracer
         self._t0 = t0 = time.perf_counter()
+        self._t0_tr = tracer.now()
         continuous = self.scfg.scheduler in ("continuous", "spec")
         while (self._pending or self._queue or self.active.any()
                or self.prefilling.any()):
-            now = time.perf_counter() - t0
-            if self.chaos is not None:
-                self.chaos.fire(self, "tick")
-            self._apply_cancels(now)
-            self._expire(now)
-            # arrivals move into the BOUNDED waiting queue: admission
-            # backpressure rejects (reason "queue_full") instead of letting
-            # the queue grow without limit; requeues from preemption /
-            # quarantine bypass this — they already held an admission
-            while self._pending and self._pending[0].arrival <= now:
-                r = self._pending.popleft()
-                if (self.scfg.max_queue
-                        and len(self._queue) >= self.scfg.max_queue):
-                    self._fail(r.rid, "queue_full", now,
-                               detail=f"{len(self._queue)} waiting")
-                    self._count("rejected")
-                else:
-                    self._queue.append(r)
-            free = sum(1 for rid in self.slot_rid if rid is None)
-            busy = self.active.any() or self.prefilling.any()
-            can_admit = continuous or not busy
-            batch = []
-            while can_admit and self._queue and len(batch) < free:
-                batch.append(self._queue.popleft())
-            if batch:
-                # page-starved admissions requeue their tail to the front
-                self.admit(batch, time.perf_counter() - t0)
-                self._audit_check()
-            # per-iteration load gauges + periodic metrics snapshot export
-            self._gauges["queue_depth"].set(len(self._queue))
-            self._gauges["slot_occupancy"].set(
-                sum(1 for rid in self.slot_rid if rid is not None))
-            if self.paged:
-                self._gauges["pages_in_use"].set(self.pool.pages_in_use)
-            self.obs.maybe_snapshot()
+            with tracer.span("schedule"):
+                self._schedule(time.perf_counter() - t0, continuous)
             if self.prefilling.any():
                 # at most ONE chunk per loop iteration: a long prompt's
                 # prefill interleaves with the decode bursts below instead
@@ -1551,9 +1593,10 @@ class SlotPoolEngine:
             elif (not self.prefilling.any() and not self._queue
                     and self._pending):
                 # idle: wait for the next arrival
-                now = time.perf_counter() - t0
-                time.sleep(max(0.0, min(
-                    self._pending[0].arrival - now, 0.01)))
+                with tracer.span("wait_arrival"):
+                    now = time.perf_counter() - t0
+                    time.sleep(max(0.0, min(
+                        self._pending[0].arrival - now, 0.01)))
         self.obs.maybe_snapshot(force=True)
         return self.completions
 

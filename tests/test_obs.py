@@ -4,7 +4,8 @@ scheduler integration.
 Under test:
   * tracer — event shapes (X/i/C), disabled no-op, Perfetto-loadable
     output (NaN args stringified), ``compile_watch`` counting + logger
-    restore;
+    restore, every span a profiler annotation ``<cat>.<name>`` (enabled
+    or not), backdated ``complete`` events, ``run_train``'s phase spans;
   * metrics — histogram sketch percentiles within the documented ~2.5%
     relative error, exact count/sum, one-kind-per-name binding, JSONL
     snapshot export;
@@ -21,6 +22,7 @@ Under test:
   * lint — ``obs.untimed-hot-path`` fires on unspanned hot loops and
     respects span scopes and waivers.
 """
+import glob
 import json
 
 import jax
@@ -61,6 +63,115 @@ def test_tracer_disabled_is_noop():
     NULL_TRACER.counter("z", v=1)
     NULL_TRACER.compile_span("f", 0.1, "xla")
     assert NULL_TRACER.events == []
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the names
+    opened and closed, in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("open", name))
+
+            def __exit__(self, *exc):
+                log.append(("close", name))
+        return _Ann()
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_opens_a_profiler_annotation(monkeypatch, enabled):
+    """Every span is a profiler annotation ``<cat>.<name>`` whether or not
+    the tracer records; only an enabled tracer appends the Chrome event."""
+    from repro.obs import trace
+    ann = _Annotations()
+    monkeypatch.setattr(trace, "TraceAnnotation", ann)
+    tr = trace.Tracer(enabled=enabled)
+    with tr.span("decode_burst", active=2):
+        with tr.span("step", cat="train"):
+            pass
+    assert ann.log == [("open", "serve.decode_burst"), ("open", "train.step"),
+                       ("close", "train.step"),
+                       ("close", "serve.decode_burst")]
+    if enabled:
+        assert [(e["name"], e["cat"]) for e in tr.events] == [
+            ("step", "train"), ("decode_burst", "serve")]
+        assert tr.events[1]["args"] == {"active": 2}
+    else:
+        assert tr.events == []
+
+
+def test_span_annotation_is_on_the_profiler_host_plane(tmp_path):
+    """Under a real profiler capture the span lands on the host plane."""
+    from jax.profiler import ProfileData
+
+    from repro.obs.trace import Tracer
+    jax.profiler.start_trace(str(tmp_path))
+    with Tracer(enabled=False).span("prefill_pack"):
+        jnp.ones(4).block_until_ready()
+    jax.profiler.stop_trace()
+    (f,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for p in ProfileData.from_file(f).planes
+             if p.name == "/host:CPU" for ln in p.lines for e in ln.events}
+    assert "serve.prefill_pack" in names
+
+
+def test_complete_writes_a_backdated_event(tmp_path):
+    from repro.obs.trace import NULL_TRACER, Tracer
+    tr = Tracer(enabled=True, clock=lambda: 100.0)
+    tr.complete("request_queued", 3.25, 4.5, rid=7, resume=False)
+    (e,) = tr.events
+    assert e["ph"] == "X" and e["name"] == "request_queued"
+    assert e["ts"] == pytest.approx(3.25e6) and e["dur"] == pytest.approx(
+        1.25e6)
+    assert e["cat"] == "serve" and e["tid"] == 0
+    assert e["args"] == {"rid": 7, "resume": False}
+    # a compile span is a complete event ending now, as before
+    tr.compile_span("jit_burst", 0.5, "xla")
+    c = tr.events[-1]
+    assert (c["name"], c["cat"], c["tid"]) == ("compile", "compile", 1)
+    assert c["ts"] + c["dur"] == pytest.approx(100.0e6)
+    assert c["args"] == {"fn": "jit_burst", "kind": "xla"}
+    p = tmp_path / "t.json"
+    tr.write(str(p))
+    assert len(json.loads(p.read_text())["traceEvents"]) == 2
+    NULL_TRACER.complete("request_queued", 1.0, 2.0, rid=1)
+    assert NULL_TRACER.events == []
+
+
+def test_run_train_phases_are_spans(monkeypatch):
+    """``run_train`` puts each phase of a step under a ``train`` span: the
+    batch, the step's dispatch, the metrics sync on log steps, the
+    straggler check; annotations open with the default disabled tracer."""
+    from repro.configs.base import TrainConfig
+    from repro.obs import trace
+    from repro.obs.trace import Tracer
+    from repro.train.loop import run_train
+    ann = _Annotations()
+    monkeypatch.setattr(trace, "TraceAnnotation", ann)
+
+    def step(state, batch):
+        return state + batch, {"loss": jnp.float32(state)}
+
+    tcfg = TrainConfig(total_steps=3)
+    tr = Tracer(enabled=True)
+    state, hist = run_train(0, step, lambda s: s, tcfg, log_every=2,
+                            log_fn=lambda *_: None, tracer=tr)
+    assert state == 3 and [h["step"] for h in hist] == [0, 2]
+    names = [(e["name"], e["args"]["step"]) for e in tr.events]
+    assert names == [("batch", 0), ("step", 0), ("log", 0), ("straggler", 0),
+                     ("batch", 1), ("step", 1), ("straggler", 1),
+                     ("batch", 2), ("step", 2), ("log", 2), ("straggler", 2)]
+    assert {e["cat"] for e in tr.events} == {"train"}
+    ann.log.clear()
+    run_train(0, step, lambda s: s, tcfg, log_fn=lambda *_: None)
+    opened = [n for k, n in ann.log if k == "open"]
+    assert opened.count("train.step") == 3 and "train.batch" in opened
 
 
 def test_tracer_write_sanitizes_nonfinite(tmp_path):
@@ -233,8 +344,9 @@ def test_ttft_none_when_no_tokens_emitted():
                    arrival=1.0, cancelled=True)
     assert c.ttft is None
     assert c.latency == 1.0
+    # TTFT is to the token's delivery, not its call's dispatch stamp
     c2 = Completion(rid=1, tokens=[5], prompt_len=4, finished_at=2.0,
-                    arrival=1.0, token_times=[1.25])
+                    arrival=1.0, token_times=[1.0], delivered_times=[1.25])
     assert c2.ttft == pytest.approx(0.25)
 
 
@@ -361,14 +473,16 @@ def test_traced_serve_stats_view_and_reconciliation(tmp_path):
     h = obs.metrics.find("serve.ttft_s", **lab)
     assert h.count == len(ttfts)
     assert h.total == pytest.approx(sum(ttfts))
-    gaps = [g for c in done.values() for g in np.diff(c.token_times)]
+    gaps = [g for c in done.values() for g in np.diff(c.delivered_times)]
     hb = obs.metrics.find("serve.tbt_s", **lab)
     assert hb.count == len(gaps)
     assert hb.total == pytest.approx(sum(gaps))
 
     kinds = obs.tracer.span_kinds()
     assert {"prewarm", "admit", "prefill_chunk", "decode_burst",
-            "compile"} <= kinds, kinds
+            "compile", "schedule", "prefill_pack", "prefill_finish",
+            "burst_prep", "burst_emit", "request_queued",
+            "request_prefill"} <= kinds, kinds
     tpath = tmp_path / "t.json"
     obs.tracer.write(str(tpath))
     evs = json.loads(tpath.read_text())["traceEvents"]

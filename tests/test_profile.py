@@ -61,29 +61,37 @@ def test_join_cost_unknown_device_raises(kind):
         profile.join_cost({"flops": 1.0, "bytes": 1.0}, 1.0, device_kind=kind)
 
 
+def _bound_book():
+    """An enabled cost book emitting into a fresh metrics registry."""
+    obs = Obs()
+    book = profile.CostBook(enabled=True)
+    book.bind(obs.metrics, obs.tracer)
+    return obs, book
+
+
 def test_costbook_off_tpu_reports_no_share():
     """On the CPU backend a dispatch's wall is recorded, never joined."""
-    obs = Obs.enabled()
+    obs, book = _bound_book()
     f, a, b = _matmul()
-    obs.profile.record("mm", f, a, b)
-    assert obs.profile.observe("mm", 1e-3) is None
+    book.record("mm", f, a, b)
+    assert book.observe("mm", 1e-3) is None
     assert obs.metrics.find("perf.roofline_fraction", executable="mm") is None
     assert obs.metrics.find("perf.wall_s", executable="mm").count == 1
-    assert "roofline_fraction" not in obs.profile.summary()["mm"]
+    assert "roofline_fraction" not in book.summary()["mm"]
 
 
 def test_costbook_record_observe_emits_metrics(monkeypatch):
     monkeypatch.setattr(profile, "roofline_kind", lambda: hw.V5E)
-    obs = Obs.enabled()
+    obs, book = _bound_book()
     f, a, b = _matmul()
-    c = obs.profile.record("mm", f, a, b)
-    assert "mm" in obs.profile and c["trip_factor"] == 1.0
-    j = obs.profile.observe("mm", 1e-3)
+    c = book.record("mm", f, a, b)
+    assert "mm" in book and c["trip_factor"] == 1.0
+    j = book.observe("mm", 1e-3)
     assert j is not None
     g = obs.metrics.find("perf.roofline_fraction", executable="mm")
     assert g is not None and g.value == pytest.approx(j["roofline_fraction"])
     assert obs.metrics.find("perf.wall_s", executable="mm").count == 1
-    s = obs.profile.summary()
+    s = book.summary()
     assert s["mm"]["calls"] == 1
     assert s["mm"]["wall_mean_us"] == pytest.approx(1000.0)
 

@@ -12,6 +12,7 @@ one process at a time may load the TPU library, and under pytest-xdist
 every worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -167,3 +168,40 @@ def test_softmax_kernels(one_chip, cfg):
         s = hs.hyft_softmax_fwd_kernel(z, cfg, interpret=False)
         return s, hs.hyft_softmax_bwd_kernel(s, dy, cfg, interpret=False)
     assert _compiled_text(one_chip, fn, z, z).count("tpu_custom_call") >= 2
+
+
+def _kernel_names(text):
+    """Instruction names of the Pallas kernels in compiled HLO text."""
+    return {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_][\w\-]*?)(?:\.\d+)? = [^\n]*"
+        r'custom_call_target="tpu_custom_call"', text)}
+
+
+def test_chunk_kernel_is_named_inside_its_lane_map(one_chip):
+    """A 512-lane paged chunk runs the verify kernel under a ``lax.map``
+    over lane blocks; the kernel keeps its own name there, not the map
+    body's ``closed_call``, so a profile can tell it from other calls."""
+    sq, n_pages = 512, B * NB + 1
+    shapes = [((B, H, sq, D), BF16), ((n_pages, H, PS, D), I8),
+              ((n_pages, H, PS, D), I8), ((B, sq, CTX), F32),
+              ((B, NB), I32), ((n_pages, H, PS), F32),
+              ((n_pages, H, PS), F32)]
+
+    def fn(q, kp, vp, m, bt, ks, vs):
+        return fa.flash_hyft_verify(q, kp, vp, m, CFG, interpret=False,
+                                    block_tables=bt, k_scale=ks, v_scale=vs)
+    text = _compiled_text(one_chip, fn, *shapes)
+    assert _kernel_names(text) == {"flash_hyft_verify"}
+    assert not re.search(r"%closed_call\b", text)
+
+
+def test_training_kernels_are_named(one_chip):
+    S = 512
+    qkv = [((B, H, S, D), BF16)] * 3
+
+    def fn(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(fa.flash_hyft_attention(
+            *a, CFG, interpret=False)), (0, 1, 2))(q, k, v)
+    assert _kernel_names(_compiled_text(one_chip, fn, *qkv)) == {
+        "flash_hyft_attention", "flash_hyft_attention_bwd_dq",
+        "flash_hyft_attention_bwd_dkv"}
