@@ -50,10 +50,12 @@ blocks as carry over the fused (GQA group x q block) inner dimension.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import numerics as nm
 from repro.core.hyft import HyftConfig
@@ -507,6 +509,28 @@ def flash_hyft_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # fused into the kernel's K/V loads so the HBM traffic stays int8.
 
 
+def _fx_scores(z, mask, cfg: HyftConfig):
+    """Masking on the float scores, then FP2FX (Hyft stage 1's converter):
+    invalid scores become ``NEG_BIG`` and saturate to the fixed-point
+    minimum.  ``mask`` broadcasts against ``z``."""
+    z = jnp.where(mask > F32(0), z, NEG_BIG)
+    return nm.fp2fx(z, cfg.frac_bits, cfg.total_bits)
+
+
+def _hyft_split(z_raw, cfg: HyftConfig):
+    """Hyft stages 1-2 of one split over the last axis: the (strided) local
+    max, the exponent unit, the adder tree's fixed-point sum, and the
+    probabilities as assembled floats for the P.V product.  Returns
+    (m_loc raw, l_loc, p), the stats keepdims."""
+    zsub = z_raw[..., :: cfg.step] if cfg.step > 1 else z_raw
+    m_loc = jnp.max(zsub, axis=-1, keepdims=True)
+    e, m = nm.exp_unit(z_raw - m_loc, cfg.frac_bits, cfg.mant_bits)
+    addend = nm.expfloat_to_fx(e, m, cfg.mant_bits, cfg.acc_bits)
+    l_loc = jnp.sum(addend, axis=-1, keepdims=True)
+    p = ((1 << cfg.mant_bits) + m).astype(F32) * nm.pow2_float(e - cfg.mant_bits)
+    return m_loc, l_loc, p
+
+
 def _decode_tile(q, k, v, maskrow, cfg: HyftConfig, sm_scale: float):
     """L1 of the decode tree: local Hyft stages 1-2 for one KV split.
 
@@ -514,20 +538,13 @@ def _decode_tile(q, k, v, maskrow, cfg: HyftConfig, sm_scale: float):
     dequantized); maskrow (1, bk) shared across rows, or (gp, bk) per-row
     (the verify kernel's causal-within-draft mask).  Returns (acc (gp, dh),
     m_loc (gp, 1) raw, l_loc (gp, 1)) — the split-local (max, fixed-sum,
-    acc) stats.  Shared verbatim by the contiguous split-K kernel, the
-    paged kernel, and the verify kernels, so a page IS a split and the
-    bitwise story reduces to the combine order.
+    acc) stats.  Shared by the contiguous split-K kernel and the verify
+    kernels; the paged decode kernel runs the same ops (``_fx_scores``,
+    ``_hyft_split``) on a block of pages at once.
     """
     z = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32) * sm_scale
-    z = jnp.where(maskrow > F32(0), z, NEG_BIG)
-    z_raw = nm.fp2fx(z, cfg.frac_bits, cfg.total_bits)
-    zsub = z_raw[:, :: cfg.step] if cfg.step > 1 else z_raw
-    m_loc = jnp.max(zsub, axis=-1, keepdims=True)
-    e, m = nm.exp_unit(z_raw - m_loc, cfg.frac_bits, cfg.mant_bits)
-    addend = nm.expfloat_to_fx(e, m, cfg.mant_bits, cfg.acc_bits)
-    l_loc = jnp.sum(addend, axis=-1, keepdims=True)
-    p = ((1 << cfg.mant_bits) + m).astype(F32) * nm.pow2_float(e - cfg.mant_bits)
+    m_loc, l_loc, p = _hyft_split(_fx_scores(z, maskrow, cfg), cfg)
     acc = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                               preferred_element_type=F32)
     return acc, m_loc, l_loc
@@ -536,18 +553,22 @@ def _decode_tile(q, k, v, maskrow, cfg: HyftConfig, sm_scale: float):
 def _splitk_combine(acc, m_st, l_st, cfg: HyftConfig):
     """L2 of the decode tree: merge per-split Hyft stats across the split
     axis (axis 1) — integer max over split maxima, per-split rescale by the
-    Hyft-approximated exp of the max delta, fixed-point sum merge, one
-    finalize.  acc (BH, ns, gp, D); m_st (BH, ns, gp, 128) i32; l_st f32.
-    Shared by the contiguous and paged decode kernels: identical inputs in
+    Hyft-approximated exp of the max delta, fixed-point sum merge, the acc
+    sum in split order, one finalize.  acc (..., ns, [rows,] D); m_st (i32)
+    and l_st (f32) of acc's shape, their lane 0 holding the stat.  Shared
+    by the XLA combine of the per-split grid (acc (BH, ns, gp, D), stats
+    broadcast over 128 lanes) and the paged decode kernel, which runs it
+    in VMEM at a slot's last page block (acc (Hq, nb, D), stats (Hq, nb,
+    1): a query head's pages are its splits): identical inputs in
     identical split order give bitwise-identical outputs.
     """
-    m_loc = m_st[..., 0]                        # (BH, ns, gp) i32
-    l_loc = l_st[..., 0]                        # (BH, ns, gp) f32
+    m_loc = m_st[..., :1]
+    l_loc = l_st[..., :1]
     m_glob = jnp.max(m_loc, axis=1, keepdims=True)
     alpha = hyft_alpha(m_loc - m_glob, cfg)     # per-split rescale
     l_glob = jnp.sum(nm.fx_quantize(l_loc * alpha, cfg.acc_bits), axis=1)
-    acc_glob = jnp.sum(acc * alpha[..., None], axis=1)   # (BH, gp, D)
-    return hyft_finalize(acc_glob, l_glob[..., None], cfg)
+    acc_glob = jnp.sum(acc * alpha, axis=1)     # (..., [rows,] D)
+    return hyft_finalize(acc_glob, l_glob, cfg)
 
 
 def _dequant(x, s_ref, h):
@@ -563,8 +584,9 @@ def _dequant(x, s_ref, h):
 def _splitk_kernel(*refs, cfg: HyftConfig, sm_scale: float, quantized: bool,
                    paged: bool, group: int):
     """One (batch, KV split, kv head) step of the split-K machine: the L1
-    tile stats through ``_decode_tile``.  Shared by the contiguous and paged
-    decode and verify kernels, so a page IS a split and the bitwise story
+    tile stats through ``_decode_tile``.  Shared by the contiguous decode
+    kernel, the verify kernels (contiguous and paged) and the paged decode
+    kernel past its VMEM cap, so a page IS a split and the bitwise story
     reduces to the combine order.  ``group`` > 0 marks the verify layout:
     the (sp, bk) per-draft-lane mask expands over the GQA group rows."""
     if paged:
@@ -638,7 +660,6 @@ def _splitk_stats(q3, k, v, mask, *, k_scale, v_scale, block_tables, Hkv: int,
     kern = functools.partial(_splitk_kernel, cfg=cfg, sm_scale=sm_scale,
                              quantized=quantized, paged=paged, group=group)
     if paged:
-        from jax.experimental.pallas import tpu as pltpu
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, ns, Hkv), in_specs=in_specs,
             out_specs=out_specs)
@@ -720,20 +741,139 @@ def flash_hyft_decode(q: jax.Array, k: jax.Array, v: jax.Array,
 # paged decode kernel (Sq = 1, block-table K/V gather)
 # --------------------------------------------------------------------------
 #
-# The split-K decode kernel assumes a contiguous (B, Hkv, Sk, D) KV stripe
-# per sequence.  The paged serving layout instead keeps one global pool of
-# fixed-size pages — (n_pages, Hkv, page_size, D), dense or int8 fp2fx8 —
-# and a per-sequence block table mapping virtual KV block j to a physical
-# page.  The kernel below is the same split-K machine with pages as splits:
-# the block table rides in as a scalar-prefetch operand so the BlockSpec
-# index maps can route grid step (b, j, h) to physical page bt[b, j] (the
-# DMA for the next page issues while this one computes — on TPU the gather
-# is free).  Each page emits the same local (max, fixed-sum, acc) stats via
-# ``_decode_tile`` and the combine is ``_splitk_combine`` — so with pages
-# laid out sequentially (bt[b, j] == j over a contiguous pool) the result
-# is bitwise identical to ``flash_hyft_decode`` at block_k == page_size.
-# The validity mask rides in as (B, nb, 1, ps), one full-width row block
-# per page, so any page size compiles (int8 pages down to 16 tokens).
+# The paged serving layout keeps one global pool of fixed-size pages —
+# (n_pages, Hkv, page_size, D), dense or int8 fp2fx8 with (n_pages, Hkv,
+# page_size) scales — and a per-sequence block table mapping virtual KV
+# block j to a physical page.  Every page is a split of its own: it emits
+# local Hyft (max, fixed-sum, acc) stats, and the L2 tree merges them.
+#
+# Grid: (slot, block of P pages), P derived from the page size so a block
+# fills whole 128-lane rows of tokens (P = 8 at 16-token pages: 2048 tokens
+# of context take 16 steps a slot, where the per-split grid took 128 x Hkv).
+# The block table is scalar-prefetched, and P BlockSpecs — one per page
+# slot j of the block — route grid step (b, i) to physical page
+# bt[b, i * P + j]: each moves the page's contiguous (Hkv, page_size, D)
+# slab, every kv head in one DMA, and its (Hkv, page_size) scales,
+# double-buffered, so the next block's pages arrive while this one
+# computes.
+#
+# Per kv head the block's pages run ``_decode_tile``'s ops with the page as
+# a batch axis: the QK product, FP2FX, and per query row of the GQA group
+# the strided max, exponent unit and adder-tree addends, so every page
+# keeps its own max and its own sum; the group's probability rows then
+# meet V in one (gp, ps) x (ps, D) product a page.  The per-page stats —
+# acc (Hq, pages, D), max and fixed sum (Hq, pages, 1) — stay in VMEM for
+# the slot's whole page range, and at the slot's last block
+# ``_splitk_combine`` merges them there: only each slot's (Hq, D) result
+# leaves the kernel.  Padding page slots (nb not a multiple of P) read the
+# null page 0 and are sliced off before the merge, so the merge sees
+# exactly the block table's nb pages.  With pages laid out sequentially
+# (bt[b, j] == j over a contiguous pool) the interpreter's result is
+# bitwise ``flash_hyft_decode``'s at block_k == page_size; compiled by
+# Mosaic the two agree to the rounding of the merge's f32 sums, which run
+# in another order.
+#
+# The kept stats grow with the context, about 1.5 KB a (query head, page)
+# once Mosaic pads them to (8, 128) tiles, and the call sizes its VMEM
+# limit from the padded shapes (``paged_decode_plan``).  Where that would
+# pass ``PAGED_VMEM_CAP`` (at 16 query heads x 128, contexts past 30,976
+# tokens in 16-token pages) it runs the per-split grid instead: (slot,
+# page, kv head) steps of ``_splitk_kernel``, the stats through HBM and
+# the XLA combine — the same per-page arithmetic and the same merge, at
+# the per-step cost the block grid avoids.
+#
+# Contiguous decode and chunk verify keep the per-split grid
+# (``_splitk_stats``) and the XLA combine: verify masks each chunk lane by
+# its own causal frontier and carries up to ``VERIFY_LANE_BLOCK`` lanes a
+# page, whose per-page stats would not fit a slot's VMEM, and contiguous
+# decode (the model drafter's) has no block table to gather through.
+
+PAGED_VMEM_CAP = 64 << 20  # half a v5e core's 128 MiB of VMEM
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM a buffer of ``shape`` takes once Mosaic tiles its last two
+    dims: rows pad to whole sublane tiles (8 rows of 32 bits, 16 of 16, 32
+    of 8) and columns to 128 lanes."""
+    itemsize = jnp.dtype(dtype).itemsize
+    shape = (1,) * (2 - len(shape)) + tuple(shape)
+    sub = 32 // itemsize
+    rows = -(-shape[-2] // sub) * sub
+    cols = -(-shape[-1] // 128) * 128
+    return math.prod(shape[:-2]) * rows * cols * itemsize
+
+
+def paged_decode_plan(Hq: int, Hkv: int, D: int, page_size: int, nb: int,
+                      kv_dtype, quantized: bool) -> tuple[int, int, int]:
+    """(pages per block P, page blocks per slot, VMEM bytes) of the paged
+    decode kernel's page-block grid: P pages fill whole 128-lane rows of
+    tokens (capped at the block table's width), the grid is (slots, page
+    blocks), and the bytes are the padded per-page stats, the
+    double-buffered blocks, and 16 MiB for the step's and the merge's
+    temporaries (the merge's grow with the stats: 4-8 MiB beside 36 MiB of
+    them in a v5e compile).  Past ``PAGED_VMEM_CAP`` the call runs the
+    per-split grid."""
+    P = min(nb, 128 // math.gcd(page_size, 128))
+    nblk = -(-nb // P)
+    npp = nblk * P
+    gp = -(-(Hq // Hkv) // 8) * 8
+    ps = page_size
+    stats = [((Hq, npp, D), F32), ((Hq, npp, 1), I32), ((Hq, npp, 1), F32)]
+    blocks = [((Hkv, gp, D), F32), ((1, P, 1, ps), F32), ((1, Hq, D), F32)]
+    blocks += [((1, Hkv, ps, D), kv_dtype)] * 2 * P
+    if quantized:
+        blocks += [((1, Hkv, ps), F32)] * 2 * P
+    vmem = (sum(_vmem_bytes(*x) for x in stats)
+            + 2 * sum(_vmem_bytes(*x) for x in blocks) + (16 << 20))
+    return P, nblk, vmem
+
+
+def _paged_decode_kernel(bt_ref, q_ref, mask_ref, *refs, cfg: HyftConfig,
+                         sm_scale: float, P: int, nb: int, group: int,
+                         quantized: bool):
+    pages = [refs[n * P:(n + 1) * P] for n in range(4 if quantized else 2)]
+    o_ref, acc_sc, m_sc, l_sc = refs[len(pages) * P:]
+    _, Hkv, ps, D = pages[0][0].shape
+    gp = q_ref.shape[1]
+    i, nblk = pl.program_id(1), pl.num_programs(1)
+    if quantized:  # each page's (Hkv, ps) scales as (ps, Hkv) token rows
+        scales = [[s[0].T for s in pages[n]] for n in (2, 3)]
+
+    def load(n, h):
+        """Head h's (P, ps, D) K (n = 0) or V (n = 1) pages, fp2fx8
+        dequantization fused: each token row times its own scale."""
+        x = jnp.stack([page[0, h].astype(F32) for page in pages[n]])
+        if quantized:
+            x = x * jnp.stack([jnp.broadcast_to(s[:, h:h + 1], (ps, D))
+                               for s in scales[n]])
+        return x
+
+    mask = mask_ref[0]                                    # (P, 1, ps)
+    rows = pl.ds(i * P, P)
+    row = jax.lax.broadcasted_iota(I32, (P, gp, ps), 1)
+    for h in range(Hkv):
+        k, v = load(0, h), load(1, h)
+        q = jnp.broadcast_to(q_ref[h].astype(F32), (P, gp, D))
+        # one split per page: the page axis is a batch axis of every op
+        z = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=F32) * sm_scale
+        z_raw = _fx_scores(z, mask, cfg)                  # (P, gp, ps)
+        p = jnp.zeros((P, gp, ps), F32)
+        for r in range(group):  # the datapath on the group's real rows
+            m_loc, l_loc, p_r = _hyft_split(z_raw[:, r], cfg)  # (P, ps) rows
+            p = jnp.where(row == r, p_r[:, None], p)
+            m_sc[h * group + r, rows, :] = m_loc
+            l_sc[h * group + r, rows, :] = l_loc
+        # P.V for the whole group, ``_decode_tile``'s (gp, ps) x (ps, D)
+        acc = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+                                  preferred_element_type=F32)
+        for r in range(group):
+            acc_sc[h * group + r, rows, :] = acc[:, r]
+
+    @pl.when(i == nblk - 1)
+    def _merge():  # L2 over exactly the block table's nb pages
+        o_ref[0] = _splitk_combine(acc_sc[:, :nb], m_sc[:, :nb],
+                                   l_sc[:, :nb], cfg)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "sm_scale", "interpret"))
@@ -750,15 +890,17 @@ def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
       q: (B, Hq, 1, D);  k_pages, v_pages: (n_pages, Hkv, page_size, D)
         float — or int8 FP2FX raws with ``k_scale``/``v_scale``
         (n_pages, Hkv, page_size) fp32 scales (the fp2fx8 page layout),
-        in which case dequantization fuses into the page loads.
+        in which case dequantization fuses into the page gather.
       block_tables: (B, nb) int32 — virtual KV block j of sequence b lives
-        in physical page ``block_tables[b, j]`` (scalar-prefetched so the
-        grid's BlockSpec index maps do the gather).
+        in physical page ``block_tables[b, j]`` (scalar-prefetched; the
+        kernel gathers a block of pages per grid step).
       kv_len_mask: optional (B, nb * page_size) validity mask over the
         *virtual* KV axis (nonzero = valid); missing means all-valid.
     Returns (B, Hq, 1, D) fp32.  With ``block_tables[b, j] == j`` over a
-    contiguous pool this is bitwise identical to ``flash_hyft_decode`` at
-    ``block_k == page_size`` (same tile arithmetic, same combine order).
+    contiguous pool, the same per-page arithmetic and the same merge as
+    ``flash_hyft_decode`` at ``block_k == page_size``: bitwise its result
+    in interpret mode, and to the rounding of the merge's summation order
+    when compiled.
     """
     B, Hq, Sq, D = q.shape
     _, Hkv, ps, _ = k_pages.shape
@@ -767,17 +909,60 @@ def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
     g = Hq // Hkv
     scale = sm_scale if sm_scale is not None else D ** -0.5
     gp = -(-g // 8) * 8  # sublane-aligned group rows
+    quantized = k_scale is not None
     maskf = (kv_len_mask.astype(F32) if kv_len_mask is not None
              else jnp.ones((B, nb * ps), F32))
+    P, nblk, vmem = paged_decode_plan(Hq, Hkv, D, ps, nb, k_pages.dtype,
+                                      quantized)
+    if vmem > PAGED_VMEM_CAP:  # too many pages to keep: the per-split grid
+        acc, m_st, l_st = _splitk_stats(
+            _group_rows(q, Hkv, gp), k_pages, v_pages,
+            maskf.reshape(B, nb, 1, ps), k_scale=k_scale, v_scale=v_scale,
+            block_tables=block_tables, Hkv=Hkv, bk=ps, cfg=cfg,
+            sm_scale=scale, group=0, interpret=interpret,
+            name="flash_hyft_decode_paged")
+        out = _splitk_combine(acc, m_st, l_st, cfg)
+        return out[:, :g].reshape(B, Hq, 1, D)
 
-    acc, m_st, l_st = _splitk_stats(
-        _group_rows(q, Hkv, gp), k_pages, v_pages,
-        maskf.reshape(B, nb, 1, ps), k_scale=k_scale, v_scale=v_scale,
-        block_tables=block_tables, Hkv=Hkv, bk=ps, cfg=cfg, sm_scale=scale,
-        group=0, interpret=interpret, name="flash_hyft_decode_paged")
+    npp = nblk * P
+    pad = npp - nb  # padding page slots: the null page, fully masked
+    maskf = _pad0(maskf, ((0, 0), (0, pad * ps))).reshape(B, npp, 1, ps)
+    bt = _pad0(block_tables.astype(I32), ((0, 0), (0, pad))).reshape(-1)
 
-    out = _splitk_combine(acc, m_st, l_st, cfg)
-    return out[:, :g].reshape(B, Hkv, g, D).reshape(B, Hq, 1, D)
+    def page_specs(block):
+        """One BlockSpec per page slot j of a block: the block table routes
+        grid step (b, i) to physical page bt[b, i * P + j], all kv heads
+        in one DMA, double-buffered by the pipeline."""
+        tail = (0,) * (len(block) - 1)
+        return [pl.BlockSpec(block, lambda b, i, bt, j=j:
+                             (bt[(b * nblk + i) * P + j],) + tail)
+                for j in range(P)]
+    in_specs = [pl.BlockSpec((Hkv, gp, D), lambda b, i, bt: (b, 0, 0)),
+                pl.BlockSpec((1, P, 1, ps), lambda b, i, bt: (b, i, 0, 0))]
+    in_specs += page_specs((1, Hkv, ps, D)) * 2
+    operands = [_group_rows(q, Hkv, gp), maskf]
+    operands += [k_pages] * P + [v_pages] * P
+    if quantized:
+        in_specs += page_specs((1, Hkv, ps)) * 2
+        operands += [k_scale] * P + [v_scale] * P
+    scratch = [pltpu.VMEM((Hq, npp, D), F32),     # per-page acc
+               pltpu.VMEM((Hq, npp, 1), I32),     # per-page max (raw)
+               pltpu.VMEM((Hq, npp, 1), F32)]     # per-page fixed sum
+    kern = functools.partial(_paged_decode_kernel, cfg=cfg, sm_scale=scale,
+                             P=P, nb=nb, group=g, quantized=quantized)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, nblk), in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, Hq, D), lambda b, i, bt: (b, 0, 0)),
+            scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=interpret, name="flash_hyft_decode_paged",
+    )(bt, *operands)
+    return out.reshape(B, Hq, 1, D)
 
 
 # --------------------------------------------------------------------------

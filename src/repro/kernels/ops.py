@@ -109,11 +109,12 @@ def hyft_paged_decode_attention(q, k_pages, v_pages, block_tables,
                                 kv_len_mask=None, k_scale=None, v_scale=None):
     """Split-K fused decode attention over a paged KV pool (Sq = 1).
 
-    The block table is scalar-prefetched so the kernel's index maps gather
-    physical pages directly; each page emits local Hyft (max, fixed-sum,
-    acc) stats and the cross-page combine is the same L1/L2 tree as the
-    contiguous split-K kernel — bitwise-equal to it when pages are laid out
-    sequentially.  Pass int8 pages + ``k_scale``/``v_scale`` pools (the
+    The block table is scalar-prefetched and each grid step gathers a
+    block of physical pages; each page emits local Hyft (max, fixed-sum,
+    acc) stats and the kernel merges them with the same L1/L2 tree as the
+    contiguous split-K kernel — bitwise-equal to it in interpret mode when
+    pages are laid out sequentially (compiled, to the rounding of the
+    merge's summation order).  Pass int8 pages + ``k_scale``/``v_scale`` pools (the
     fp2fx8 page layout) to fuse dequantization into the page loads.
     """
     return flash_hyft_decode_paged(q, k_pages, v_pages, block_tables, cfg,

@@ -298,6 +298,65 @@ def test_paged_kernel_bitwise_vs_contiguous(quantized):
     assert jnp.all(dense == paged), "paged kernel != contiguous split-K"
 
 
+@pytest.mark.parametrize("grid", ["page_blocks", "per_split"])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "fp2fx8"])
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (2, 2), (6, 1)],
+                         ids=["gqa", "mha", "gqa6"])
+def test_paged_kernel_page_blocks_bitwise(hq, hkv, cache_dtype, grid,
+                                          monkeypatch):
+    """The paged kernel against ``flash_hyft_decode`` at block_k ==
+    page_size on the same pages, gathered into contiguous stripes: 12
+    pages in blocks of 8, so four padding page slots that must stay out of
+    the merge; slot 0's mask ends mid-block, slot 1's on the block edge,
+    and slot 2 is idle (its table all null page 0) with a stale mask that
+    still marks 150 positions valid.  ``per_split`` lowers the VMEM cap
+    below these shapes' plan, so the call takes the per-split grid that
+    long contexts take."""
+    from repro.core.registry import hyft_config_for
+    from repro.kernels import flash_attention as fa
+    from repro.models.attention import fp2fx8_quantize
+    cfg = hyft_config_for("hyft16")
+    B, D, ps, nb = 3, 16, 16, 12
+    P, nblk, vmem = fa.paged_decode_plan(hq, hkv, D, ps, nb, jnp.int8,
+                                         cache_dtype == "fp2fx8")
+    assert (P, nblk) == (8, 2) and nblk * P > nb and vmem < fa.PAGED_VMEM_CAP
+    paged_fn = fa.flash_hyft_decode_paged
+    if grid == "per_split":  # unjitted, so the lowered cap is read
+        monkeypatch.setattr(fa, "PAGED_VMEM_CAP", vmem - 1)
+        paged_fn = paged_fn.__wrapped__
+    n_pages = 1 + 2 * nb
+    key = jax.random.PRNGKey(11)
+    q = jax.random.normal(key, (B, hq, 1, D))
+    kp = jax.random.normal(jax.random.fold_in(key, 1), (n_pages, hkv, ps, D))
+    vp = jax.random.normal(jax.random.fold_in(key, 2), (n_pages, hkv, ps, D))
+    # large values on the null page: a padding page slot that entered the
+    # merge, even at its alpha of about 2^-54, would move the result
+    vp = vp.at[0].multiply(1e12)
+    ks = vs = None
+    if cache_dtype == "fp2fx8":
+        kp, ks = fp2fx8_quantize(kp)
+        vp, vs = fp2fx8_quantize(vp)
+    else:
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    seq = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(2, nb)
+    bt = jnp.concatenate([seq, jnp.zeros((1, nb), jnp.int32)])
+    lens = jnp.array([100, P * ps, 150])
+    mask = (jnp.arange(nb * ps)[None, :] < lens[:, None]).astype(F32)
+
+    def stripes(pool):  # (n_pages, Hkv, ps, ...) -> (B, Hkv, nb * ps, ...)
+        x = jnp.moveaxis(pool[bt], 2, 1)
+        return x.reshape(B, hkv, nb * ps, *pool.shape[3:])
+    dense = fa.flash_hyft_decode(
+        q, stripes(kp), stripes(vp), cfg, block_k=ps, interpret=True,
+        kv_len_mask=mask, k_scale=None if ks is None else stripes(ks),
+        v_scale=None if vs is None else stripes(vs))
+    paged = paged_fn(q, kp, vp, bt, cfg, interpret=True, kv_len_mask=mask,
+                     k_scale=ks, v_scale=vs)
+    assert paged.shape == (B, hq, 1, D)
+    assert jnp.all(jnp.isfinite(paged))
+    assert jnp.all(dense == paged), "paged kernel != per-page split-K"
+
+
 def test_paged_kernel_invariant_to_page_placement():
     """Physically permuting the pool (with the block table following) must
     not change a bit — the kernel reads pages only through the table."""

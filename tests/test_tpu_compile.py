@@ -27,6 +27,7 @@ F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
 H, D, CTX = 16, 128, 2048       # olmo-1b heads, head dim, context
 B = 2
 PS = 16                         # ServeConfig.page_size default
+SLOTS = 8                       # the serving cell's slot pool
 NB = CTX // PS
 CFG = hyft_config_for("hyft16")
 
@@ -122,7 +123,49 @@ def test_decode_paged(one_chip, quantized):
                                           kv_len_mask=m,
                                           k_scale=s[0] if s else None,
                                           v_scale=s[1] if s else None)
-    _assert_kernel(one_chip, fn, *shapes)
+    text = _compiled_text(one_chip, fn, *shapes)
+    # one kernel, one result: the merged (B * Hkv, rows, D) = (B, Hq, D)
+    # f32 output, no per-page stats (a tuple result would start with "(")
+    results = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call", text)
+    assert len(results) == 1 and results[0].startswith(f"f32[{B},{H},{D}]{{")
+    # the grid at the serving shapes (8 slots, 2048-token block tables)
+    P, blocks, vmem = fa.paged_decode_plan(H, H, D, PS, NB, kv_dt, quantized)
+    assert P * PS % 128 == 0 and SLOTS * blocks <= 512
+    assert vmem <= fa.PAGED_VMEM_CAP
+
+
+@pytest.mark.parametrize("hq,hkv,dh,nb,quantized,grid", [
+    (12, 2, 128, 2048, True, "page_blocks"),    # qwen2-1.5b, 32k tokens
+    (14, 2, 64, 512, False, "page_blocks"),     # internvl2-1b, head_dim 64
+    (32, 8, 128, 8192, True, "per_split"),      # mistral-nemo-12b, 128k
+], ids=["qwen2-1.5b", "internvl2-1b", "mistral-nemo-12b"])
+def test_decode_paged_long_context(one_chip, hq, hkv, dh, nb, quantized,
+                                   grid):
+    """Other configurations' widths at long block tables: the call keeps
+    every page's stats in VMEM while they fit under ``PAGED_VMEM_CAP``
+    (one kernel, the merged (B, Hq, D) result) and past it runs the
+    per-split grid (the kernel returns per-page stats for the XLA
+    combine); either way the kernel compiles."""
+    kv_dt = I8 if quantized else BF16
+    _, _, vmem = fa.paged_decode_plan(hq, hkv, dh, PS, nb, kv_dt, quantized)
+    assert (vmem <= fa.PAGED_VMEM_CAP) == (grid == "page_blocks")
+    n_pages = B * nb + 1
+    shapes = [((B, hq, 1, dh), BF16), ((n_pages, hkv, PS, dh), kv_dt),
+              ((n_pages, hkv, PS, dh), kv_dt), ((B, nb), I32),
+              ((B, nb * PS), F32)]
+    if quantized:
+        shapes += [((n_pages, hkv, PS), F32)] * 2
+
+    def fn(q, kp, vp, bt, m, *s):
+        return fa.flash_hyft_decode_paged(q, kp, vp, bt, CFG, interpret=False,
+                                          kv_len_mask=m,
+                                          k_scale=s[0] if s else None,
+                                          v_scale=s[1] if s else None)
+    text = _compiled_text(one_chip, fn, *shapes)
+    assert "tpu_custom_call" in text
+    merged = re.search(rf"= f32\[{B},{hq},{dh}\]\{{\S* custom-call\("
+                       r"[^\n]*tpu_custom_call", text)
+    assert bool(merged) == (grid == "page_blocks")
 
 
 @pytest.mark.parametrize("sq,quantized", [(5, False), (512, False),
