@@ -29,10 +29,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def serve_seeds(m, mix, seeds, seconds):
     import numpy as np
-    from bench import serve_cell, traffic, weights
+    from bench import arch, serve_cell, traffic
     eng, _, _ = serve_cell.prepare(m, mix, seeds[0])
     for i, seed in enumerate(seeds):
-        params = weights.make(m, m["serve"]["param_dtype"], seed)
+        params = arch.load(m).make_weights(m, m["serve"]["param_dtype"], seed)
         eng.params = params
         reqs = traffic.serve_requests(mix, seed, seconds, m["vocab"])
         for r in reqs:
@@ -59,7 +59,7 @@ def train_seeds(m, job, seeds):
     import jax
     import jax.numpy as jnp
 
-    from bench import train_cell, weights
+    from bench import arch, train_cell
     mesh, tcfg, state, step, state_sh, _, _ = train_cell.build(m, job,
                                                                seeds[0])
     fresh = jax.jit(lambda p: {
@@ -74,7 +74,7 @@ def train_seeds(m, job, seeds):
              jax.tree_util.tree_flatten_with_path(state["params"])[0]]
     del state
     for seed in seeds:
-        params0 = weights.make(m, m["train"]["param_dtype"], seed)
+        params0 = arch.load(m).make_weights(m, m["train"]["param_dtype"], seed)
         feed = train_cell.make_feed(m, job, seed)
         batches = [feed(i) for i in range(job["check_steps"])]
         half = [dict(b, mask=b["mask"].at[job["global_batch"] // 2:].set(0))

@@ -7,11 +7,18 @@ can see (Q.K and P.V), and a kernel's bytes are its operands read once and
 its result written once.  Padded lanes, masked keys, recomputation and the
 integer work of a softmax datapath count for nothing, so a program that
 does such work shows a lower share, which is the signal.
+
+The model enters through its architecture module (``bench/arch/``): the
+attention of each layer (``layers``: heads, width and the window of keys a
+query sees) and the matrix-product weights a token uses
+(``active_matmul_params``: for sparse experts, only those it is routed to).
 """
 from __future__ import annotations
 
 import json
 import os
+
+from bench import arch
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -35,10 +42,23 @@ def causal_pairs(seq: int) -> int:
     return seq * (seq + 1) // 2
 
 
-def chunk_pairs(start: int, n: int) -> int:
+def chunk_pairs(start: int, n: int, window: int | None = None) -> int:
     """Pairs of ``n`` queries at positions ``start .. start + n - 1`` that
-    each see every key up to and including their own position."""
-    return n * start + n * (n + 1) // 2
+    each see every key up to and including their own position, or with
+    ``window`` the last ``window`` of them: ``min(p + 1, window)`` keys for
+    the query at ``p``."""
+    if window is None:
+        return n * start + n * (n + 1) // 2
+    full = max(0, min(n, window - start))    # queries that see every key
+    return full * start + full * (full + 1) // 2 + (n - full) * window
+
+
+def keys_read(start: int, n: int, window: int | None = None) -> int:
+    """Key positions the queries ``start .. start + n - 1`` see between
+    them (each read once)."""
+    if window is None:
+        return start + n
+    return min(start + n, n + window - 1)
 
 
 def attention_flops(heads: int, d_head: int, pairs: int) -> float:
@@ -74,28 +94,26 @@ def _itemsize(dtype: str) -> int:
 
 # --- whole model ------------------------------------------------------------
 
-def layer_matmul_params(m: dict) -> int:
-    """Weights of one block that take part in a matrix product."""
-    d, h, kv, dh, ff = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                        m["d_head"], m["d_ff"])
-    attn = d * h * dh * 2 + d * kv * dh * 2
-    mlp = d * ff * (3 if m["mlp_gated"] else 2)
-    return attn + mlp
-
-
 def matmul_params(m: dict) -> int:
-    """Every matrix-product weight of the model: the blocks and the
-    unembedding (the embedding lookup is a gather)."""
-    return m["n_layers"] * layer_matmul_params(m) + m["vocab"] * m["d_model"]
+    """Every matrix-product weight one token uses: the blocks' active
+    weights and the unembedding (the embedding lookup is a gather)."""
+    per_block = arch.load(m).active_matmul_params(m)
+    return m["n_layers"] * per_block + m["vocab"] * m["d_model"]
+
+
+def _attention_flops(m: dict, pairs) -> float:
+    """Forward attention FLOPs of every layer, where ``pairs(window)`` counts
+    the (query, visible key) pairs of a layer with that window."""
+    return sum(attention_flops(ly.heads, ly.d_head, pairs(ly.window))
+               for ly in arch.load(m).layers(m))
 
 
 def train_flops_per_token(m: dict, seq: int) -> float:
     """Forward plus backward FLOPs per token of a causal LM step at
-    ``seq``: 6 per matrix-product weight, and 12 * H * D per visible key
-    in every layer.  Recomputation is not counted."""
+    ``seq``: 6 per matrix-product weight a token uses, and 12 * H * D per
+    visible key in every layer.  Recomputation is not counted."""
     dense = 6.0 * matmul_params(m)
-    attn = 3.0 * m["n_layers"] * attention_flops(
-        m["n_heads"], m["d_head"], causal_pairs(seq)) / seq
+    attn = 3.0 * _attention_flops(m, lambda w: chunk_pairs(0, seq, w)) / seq
     return dense + attn
 
 
@@ -103,20 +121,20 @@ def prefill_flops(m: dict, prompt_lens, logits_rows: int) -> float:
     """Forward FLOPs of the real prompt tokens of ``prompt_lens``: the
     blocks for every token, causal attention over each prompt, and the
     unembedding for the ``logits_rows`` positions whose logits are used."""
-    layer = 2.0 * m["n_layers"] * layer_matmul_params(m)
+    layer = 2.0 * m["n_layers"] * arch.load(m).active_matmul_params(m)
     toks = sum(prompt_lens)
-    pairs = sum(causal_pairs(n) for n in prompt_lens)
-    attn = m["n_layers"] * attention_flops(m["n_heads"], m["d_head"], pairs)
+    attn = _attention_flops(m, lambda w: sum(chunk_pairs(0, n, w)
+                                             for n in prompt_lens))
     return layer * toks + attn + 2.0 * m["vocab"] * m["d_model"] * logits_rows
 
 
 def decode_flops(m: dict, positions) -> float:
     """Forward FLOPs of decoding one token at each of ``positions``: the
-    blocks, the unembedding, and attention over the ``p + 1`` positions a
-    token at ``p`` can see."""
+    blocks, the unembedding, and attention over the positions a token at
+    ``p`` can see (``min(p + 1, window)`` in each layer)."""
     per_tok = 2.0 * matmul_params(m)
-    attn = m["n_layers"] * attention_flops(m["n_heads"], m["d_head"],
-                                           sum(p + 1 for p in positions))
+    attn = _attention_flops(m, lambda w: sum(chunk_pairs(p, 1, w)
+                                             for p in positions))
     return per_tok * len(positions) + attn
 
 

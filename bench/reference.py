@@ -1,34 +1,30 @@
-"""Plain reference of the dense causal decoder the configurations describe,
-in ``jax.numpy`` at float32 and ``highest`` matmul precision.
+"""The plain reference's shared parts, in ``jax.numpy`` at float32 and
+``highest`` matmul precision: what every architecture of ``bench/arch/``
+scores against, so that each is held to the same written-out Hyft.
 
-It imports nothing of the program.  It follows the published descriptions
-(OLMo: non-parametric LayerNorm, SwiGLU, RoPE, tied embeddings; the BERT-base
-proxy: LayerNorm with scale and bias, GELU, tied embeddings) with the
-departures the configuration files list under ``assumed``: causal
-next-token training with RoPE for the BERT proxy, GELU in its tanh form,
-and RoPE rotating the two halves of each head.
-
-The attention softmax is the one the configuration states
-(``softmax_impl``): exact, or the Hyft16 datapath of the paper (fixed-point
-scores, exp by shift-add and field assembly, fixed-point sums, division by
-log-subtraction), written out below from the paper's description.  Hyft's
-partial results are merged where the configuration's deployment merges
-them, as the paper's tree of Hyft units does: over the KV cache's pages in
-serving (each page a split with its own max and sum), over blocks of keys
-in training (a running max and sum).  Hyft16's own approximation is far
-larger than bfloat16's rounding, so a reference with an exact softmax could
-not tell the program from a lower-precision one.
+It imports nothing of the program.  The attention softmax is the one the
+configuration states (``softmax_impl``): exact, or the Hyft16 datapath of
+the paper (fixed-point scores, exp by shift-add and field assembly,
+fixed-point sums, division by log-subtraction), written out below from the
+paper's description.  Hyft's partial results are merged where the
+configuration's deployment merges them, as the paper's tree of Hyft units
+does: over the KV cache's pages in serving (``hyft_splits``: each page a
+split with its own max and sum), over blocks of keys in training
+(``hyft_online``: a running max and sum).  Hyft16's own approximation is
+far larger than bfloat16's rounding, so a reference with an exact softmax
+could not tell the program from a lower-precision one.
 
 For serving, K and V are rounded to the cache format the configuration
-states (fp2fx8: int8 with a scale per position and head) before attention
-reads them, as a deployment of that configuration stores them.
+states (``kv_round``; fp2fx8: int8 with a scale per position and head)
+before attention reads them, as a deployment of that configuration stores
+them.
 
 ``quant="fp8"`` is the control: the same reference computed in float8,
 the next precision below the bfloat16 the configurations compute in, with
 the program's own split between compute and storage: the residual stream
-and every matrix product's operands in e4m3 (weights per output channel,
-activations per row), a product's incoming gradient in e5m2, products and
-norms accumulated in float32.
+(``act_round``) and every matrix product's operands (``mm``) in e4m3
+(weights per output channel, activations per row), a product's incoming
+gradient in e5m2, products and norms accumulated in float32.
 """
 from __future__ import annotations
 
@@ -46,10 +42,12 @@ NEG = -3.0e38          # a masked score; fixed-point conversion saturates it
 
 
 def sizes_key(m: dict) -> tuple:
-    """The scalar entries of a configuration, hashable, for the jitted
-    functions below to take as a static argument."""
-    return tuple(sorted((k, v) for k, v in m.items()
-                        if isinstance(v, (int, float, str, bool))))
+    """The scalar and list entries of a configuration (lists as tuples),
+    hashable, for the jitted references to take as a static argument."""
+    def frozen(v):
+        return tuple(map(frozen, v)) if isinstance(v, list) else v
+    return tuple(sorted((k, frozen(v)) for k, v in m.items()
+                        if isinstance(v, (int, float, str, bool, list))))
 
 
 def _round(x, axes, dtype, top):
@@ -85,7 +83,7 @@ def _fp8_bwd(spec, w_axes, res, g):
 _fp8_einsum.defvjp(_fp8_fwd, _fp8_bwd)
 
 
-def _mm(spec, a, w, quant, w_axes):
+def mm(spec, a, w, quant, w_axes):
     """einsum at float32/highest, or with ``quant="fp8"`` as float8
     training computes it (``_fp8_einsum``)."""
     a, w = a.astype(F32), w.astype(F32)
@@ -94,7 +92,7 @@ def _mm(spec, a, w, quant, w_axes):
     return jnp.einsum(spec, a, w, precision=HIGHEST)
 
 
-def _act_round(x, quant):
+def act_round(x, quant):
     """The residual stream as the compute dtype stores it: with ``fp8``,
     e4m3 with one scale per row (the gradient passes unrounded here; it is
     rounded where it enters a matrix product)."""
@@ -104,7 +102,7 @@ def _act_round(x, quant):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def _kv_round(x, kv):
+def kv_round(x, kv):
     """K or V (S, H, D) as the configuration's cache stores it: for
     ``fp2fx8``, int8 with one scale per (position, head), the row's largest
     magnitude mapping to 127."""
@@ -186,7 +184,7 @@ def _finalize(acc, l, hy):
     return jnp.where(acc == 0, F32(0), jnp.where(acc < 0, -r, r))
 
 
-def _exact(q, k, v):
+def exact(q, k, v):
     """Causal attention with an exact softmax; q, k, v (S, H, D)."""
     S, _, D = q.shape
     pos = jnp.arange(S)
@@ -196,12 +194,14 @@ def _exact(q, k, v):
                       precision=HIGHEST)
 
 
-def _hyft_splits(q, k, v, hy, split, rows=128):
+def hyft_splits(q, k, v, hy, split, rows=128, window=None):
     """Causal Hyft attention with the keys taken in splits of ``split``
     positions: each split's max, exponentials and sum are its own, and the
     splits are merged by the Hyft exp of each split's max below the row's,
     their fixed-point sums and their products with V added, then one
-    division.  q, k, v (S, H, D); queries ``rows`` at a time."""
+    division.  With ``window`` a query sees only the last ``window``
+    positions (its own included); a split wholly outside them adds nothing.
+    q, k, v (S, H, D); queries ``rows`` at a time."""
     S, H, D = q.shape
     ns = S // split
     ks, vs = k.reshape(ns, split, H, D), v.reshape(ns, split, H, D)
@@ -211,7 +211,11 @@ def _hyft_splits(q, k, v, hy, split, rows=128):
         qb, qpos = args
         z = jnp.einsum("qhd,jshd->hqjs", qb, ks,
                        precision=HIGHEST) * D ** -0.5
-        z = jnp.where(kpos[None, None] <= qpos[None, :, None, None], z, NEG)
+        kp, qp = kpos[None, None], qpos[None, :, None, None]
+        seen = kp <= qp
+        if window is not None:
+            seen &= kp > qp - window
+        z = jnp.where(seen, z, NEG)
         zr = _fx(z, hy)
         mj = jnp.max(zr, -1, keepdims=True)
         p = _value(*_exp_fields(zr - mj, hy), hy)
@@ -229,7 +233,7 @@ def _hyft_splits(q, k, v, hy, split, rows=128):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _hyft_online(q, k, v, hy, block):
+def hyft_online(q, k, v, hy, block):
     """Causal Hyft attention over blocks of ``block`` keys in order, with a
     running max and fixed-point sum rescaled by the Hyft exp of the max's
     rise; its gradient recomputes each probability from the final max and
@@ -273,7 +277,7 @@ def _online_bwd(hy, block, res, do):
     return dq, dk, dv
 
 
-_hyft_online.defvjp(_online_fwd, _online_bwd)
+hyft_online.defvjp(_online_fwd, _online_bwd)
 
 
 def attention(m: dict, split: int | None = None, block: int | None = None):
@@ -282,16 +286,18 @@ def attention(m: dict, split: int | None = None, block: int | None = None):
     online over blocks of ``block`` keys (training)."""
     impl = m["softmax_impl"]
     if impl == "exact":
-        return _exact
+        return exact
     if impl not in HYFT:
         raise ValueError(f"the reference has no softmax {impl!r}")
     hy = HYFT[impl]
     if split:
-        return lambda q, k, v: _hyft_splits(q, k, v, dict(hy), split)
-    return lambda q, k, v: _hyft_online(q, k, v, hy, block)
+        return lambda q, k, v: hyft_splits(q, k, v, dict(hy), split)
+    return lambda q, k, v: hyft_online(q, k, v, hy, block)
 
 
-def _norm(kind, p, x):
+def norm(kind, p, x):
+    """RMSNorm (``rms``), LayerNorm (``ln``) or non-parametric LayerNorm
+    (``np_ln``) of ``x`` over its last axis, with the parameters ``p``."""
     mu = jnp.mean(x, -1, keepdims=True)
     if kind == "rms":
         y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
@@ -303,7 +309,7 @@ def _norm(kind, p, x):
     return y * p["scale"].astype(F32) + p["bias"].astype(F32)
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x (S, H, D), pos (S,): rotate the two halves of each head."""
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
@@ -313,7 +319,8 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
 
 
-def _act(m, x):
+def act(m, x):
+    """The activation ``m`` states: SiLU, or GELU in its tanh form."""
     if m["act"] == "silu":
         return x * jax.nn.sigmoid(x)
     if m["act"] == "gelu":
@@ -322,66 +329,9 @@ def _act(m, x):
     raise ValueError(m["act"])
 
 
-def _block(m, lp, x, quant, kv, attn):
-    """One pre-norm block over one sequence x (S, d); K and V are rounded
-    to the cache format ``kv`` before ``attn`` (see :func:`attention`)
-    reads them."""
-    S = x.shape[0]
-    pos = jnp.arange(S)
-    h = _norm(m["norm"], lp["norms"]["pre_attn"], x)
-    q = _rope(_mm("sd,dhe->she", h, lp["attn"]["wq"], quant, (0,)), pos,
-              m["rope_theta"])
-    k = _rope(_mm("sd,dhe->she", h, lp["attn"]["wk"], quant, (0,)), pos,
-              m["rope_theta"])
-    v = _mm("sd,dhe->she", h, lp["attn"]["wv"], quant, (0,))
-    k, v = _kv_round(k, kv), _kv_round(v, kv)
-    g = m["n_heads"] // m["n_kv_heads"]
-    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
-    o = attn(q, k, v)
-    x = _act_round(x + _mm("she,hed->sd", o, lp["attn"]["wo"], quant,
-                           (0, 1)), quant)
-    h = _norm(m["norm"], lp["norms"]["pre_mlp"], x)
-    up = _mm("sd,df->sf", h, lp["mlp"]["w_up"], quant, (0,))
-    if m["mlp_gated"]:
-        a = _act(m, _mm("sd,df->sf", h, lp["mlp"]["w_gate"], quant, (0,))) * up
-    else:
-        a = _act(m, up)
-    return _act_round(x + _mm("sf,fd->sd", a, lp["mlp"]["w_down"], quant,
-                              (0,)), quant)
-
-
-def hidden(m, params, tokens, quant=None, kv=None, attn=_exact):
-    """Final-normed hidden states (S, d) of one sequence ``tokens`` (S,),
-    one layer at a time (weights are cast to float32 inside the layer)."""
-    x = _act_round(params["embed"]["table"][tokens].astype(F32), quant)
-
-    def body(x, lp):
-        return _block(m, lp, x, quant, kv, attn), None
-    x, _ = jax.lax.scan(body, x, params["blocks"])
-    return _norm(m["norm"], params["final_norm"], x)
-
-
-def logits(m, params, h, quant=None):
-    table = params["embed" if m["tie_embeddings"] else "unembed"]["table"]
-    return _mm("sd,vd->sv", h, table, quant, (1,))
-
-
-@functools.partial(jax.jit, static_argnames=("mk", "quant", "kv", "split"))
-def served_gaps(mk, params, tokens, valid, quant=None, kv=None, split=None):
-    """For one sequence ``tokens`` (S,) = prompt + served tokens (padded),
-    the gap by which each served token's float32 logit lies below the
-    float32 reference's best; ``valid`` (S,) marks the positions whose next
-    token is a served one.  Attention merges Hyft over splits of ``split``
-    cache positions.  With ``quant`` the control's own first choice is
-    scored instead of the served token."""
-    m = dict(mk)
-    attn = attention(m, split=split)
-    h = hidden(m, params, tokens, kv=kv, attn=attn)
-    ref = logits(m, params, h)                       # (S, V) float32
-    nxt = jnp.roll(tokens, -1)
-    if quant is not None:
-        hq = hidden(m, params, tokens, quant, kv, attn)
-        nxt = jnp.argmax(logits(m, params, hq, quant), -1)
+def gap_below_best(ref, nxt, valid):
+    """Per position, the gap by which the logit of ``nxt`` (S,) lies below
+    the best of ``ref`` (S, V); 0 where ``valid`` (S,) is false."""
     picked = jnp.take_along_axis(ref, nxt[:, None], -1)[:, 0]
     gap = jnp.max(ref, -1) - picked
     return jnp.where(valid, gap, 0.0)
@@ -389,23 +339,16 @@ def served_gaps(mk, params, tokens, valid, quant=None, kv=None, split=None):
 
 # --- training -----------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("mk", "rows", "quant", "block"))
-def loss_and_grad(mk, params, batch, z_loss, rows, quant=None, block=None):
-    """Mean next-token NLL over the mask plus the z-loss on log Z, as the
-    configuration's training objective states it, and its gradient: the
-    batch is taken ``rows`` sequences at a time so that the float32 logits
-    fit, and the blocks' sums are added.  Attention runs Hyft online over
-    blocks of ``block`` keys."""
-    m = dict(mk)
-    attn = attention(m, block=block)
+def lm_loss_and_grad(one, params, batch, z_loss, rows):
+    """Mean next-token NLL over the mask plus the z-loss on log Z, and its
+    gradient, for the logits ``one(params, tokens)`` (S, V) of one
+    sequence: the batch is taken ``rows`` sequences at a time so that the
+    float32 logits fit, and the blocks' sums are added."""
     mask = batch["mask"].astype(F32)
     denom = jnp.maximum(jnp.sum(mask), 1.0)
 
     def block_sum(p, blk):
-        def one(tokens):
-            return logits(m, p, hidden(m, p, tokens, quant, attn=attn),
-                          quant)
-        lg = jax.vmap(one)(blk["tokens"])            # (rows, S, V)
+        lg = jax.vmap(lambda tokens: one(p, tokens))(blk["tokens"])
         lse = jax.nn.logsumexp(lg, -1)
         gold = jnp.take_along_axis(lg, blk["targets"][..., None], -1)[..., 0]
         msk = blk["mask"].astype(F32)

@@ -20,7 +20,7 @@ import time
 
 import numpy as np
 
-from bench import costs, traffic
+from bench import arch, costs, traffic
 
 
 def build(m: dict, serve: dict, seed: int):
@@ -37,7 +37,7 @@ def build(m: dict, serve: dict, seed: int):
     from bench import weights
     cfg = model_config(get_config, m, serve)
     model = build_model(cfg)
-    params = weights.make(m, serve["param_dtype"], seed)
+    params = arch.load(m).make_weights(m, serve["param_dtype"], seed)
     weights.check_layout(params, jax.eval_shape(
         lambda: unbox(model.init(jax.random.PRNGKey(0)))))
     scfg = ServeConfig(
@@ -51,16 +51,13 @@ def build(m: dict, serve: dict, seed: int):
     return eng, params
 
 
-SIZE_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_head",
-             "d_ff", "vocab", "act", "mlp_gated", "norm", "rope_theta",
-             "max_seq", "tie_embeddings", "softmax_impl")
-
-
 def model_config(get_config, m: dict, dtypes: dict):
-    """The program's model configuration with every size taken from the
-    configuration file ``m`` (the file is what runs)."""
+    """The program's model configuration with every size its architecture
+    hands over (``SIZE_KEYS``) taken from the configuration file ``m`` (the
+    file is what runs)."""
+    keys = arch.load(m).SIZE_KEYS
     return get_config(m["model"]).with_(
-        **{k: m[k] for k in SIZE_KEYS}, param_dtype=dtypes["param_dtype"],
+        **{k: m[k] for k in keys}, param_dtype=dtypes["param_dtype"],
         compute_dtype=dtypes["compute_dtype"], attn_mode=dtypes["attn_mode"])
 
 
@@ -233,10 +230,21 @@ def run(cfgfile: dict, mix: dict, seed: int, seconds: float, trace_dir,
 def serve_work(m: dict, serve: dict, reqs, served) -> dict:
     """The useful work of the requests served, from their shapes
     (``costs``): prefill FLOPs of the real prompt tokens, and attention
-    FLOPs and bytes of the prefill chunks and of the decode steps."""
-    L, H, KV, D = m["n_layers"], m["n_heads"], m["n_kv_heads"], m["d_head"]
+    FLOPs and bytes of the prefill chunks and of the decode steps, summed
+    over the layers of the architecture (each with its window of keys)."""
+    layers = arch.load(m).layers(m)
     act, kvd, w = serve["compute_dtype"], serve["cache_dtype"], \
         serve["prefill_chunk"]
+
+    def attn(s, q):
+        """Attention FLOPs and bytes of queries ``s .. s + q - 1``."""
+        f = sum(costs.attention_flops(ly.heads, ly.d_head, costs.chunk_pairs(
+            s, q, ly.window)) for ly in layers)
+        b = sum(costs.attention_bytes(
+            ly.heads, ly.kv_heads, ly.d_head, q,
+            costs.keys_read(s, q, ly.window), act, kvd) for ly in layers)
+        return f, b
+
     pre_f = pre_b = dec_f = dec_b = 0.0
     lens, rows, dec_pos = [], 0, []
     for r in reqs:
@@ -246,13 +254,14 @@ def serve_work(m: dict, serve: dict, reqs, served) -> dict:
         lens.append(n)
         rows += 1
         for s in range(0, n, w):
-            q = min(w, n - s)
-            pre_f += L * costs.attention_flops(H, D, costs.chunk_pairs(s, q))
-            pre_b += L * costs.attention_bytes(H, KV, D, q, s + q, act, kvd)
+            f, b = attn(s, min(w, n - s))
+            pre_f += f
+            pre_b += b
         for p in range(n, n + len(served[r.rid]) - 1):
             dec_pos.append(p)
-            dec_f += L * costs.attention_flops(H, D, p + 1)
-            dec_b += L * costs.attention_bytes(H, KV, D, 1, p + 1, act, kvd)
+            f, b = attn(p, 1)
+            dec_f += f
+            dec_b += b
     return {"prefill_model_flops": costs.prefill_flops(m, lens, rows),
             "decode_model_flops": costs.decode_flops(m, dec_pos),
             "attn_prefill_flops": pre_f, "attn_prefill_bytes": pre_b,
@@ -285,6 +294,7 @@ def gaps(m: dict, params, seqs, max_len: int, quant=None) -> list:
     the control's own choice).  ``seqs``: (prompt, served tokens)."""
     import jax.numpy as jnp
     from bench import reference
+    served_gaps = arch.load(m).served_gaps
     mk = reference.sizes_key(m)
     out = []
     for prompt, toks in seqs:
@@ -294,10 +304,9 @@ def gaps(m: dict, params, seqs, max_len: int, quant=None) -> list:
         pad[:n] = seq
         valid = np.zeros(max_len, bool)
         valid[first - 1:n - 1] = True
-        g = reference.served_gaps(mk, params, jnp.asarray(pad),
-                                  jnp.asarray(valid), quant=quant,
-                                  kv=m["serve"]["cache_dtype"],
-                                  split=m["serve"]["page_size"])
+        g = served_gaps(mk, params, jnp.asarray(pad), jnp.asarray(valid),
+                        quant=quant, kv=m["serve"]["cache_dtype"],
+                        split=m["serve"]["page_size"])
         out.append(np.asarray(g)[first - 1:n - 1])
     return out
 

@@ -3,6 +3,7 @@ same configuration files and mixes with tiny widths, short prompts and a
 window of a few seconds.  The kernels run in the Pallas interpreter.
 
     python -m bench.tests.smoke serve    # prints one JSON line
+    python -m bench.tests.smoke toy      # the toy architecture's cell
 
 The serving cell runs in a process of its own with JAX's asynchronous CPU
 dispatch off: on the CPU backend the served tokens of the program's slot
@@ -19,6 +20,7 @@ import time
 SMOKE_SIZES = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
                    d_head=16, d_ff=128, vocab=512, max_seq=256)
 SEED = 2**31 + 11       # larger than 32 signed bits hold
+TOY_WINDOWS = [None, 8]  # the layers of bench/tests/toy_arch.py
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -108,12 +110,36 @@ def serve_results() -> dict:
     return out
 
 
+def toy_results() -> dict:
+    """The serving cell with its configuration naming the toy architecture
+    of ``bench/tests/toy_arch.py``, registered for this process alone: one
+    run through ``bench/run.py``'s ``run_cell``, and the check of a run
+    whose weights leave the windowed layer's attention in play."""
+    from bench import run as bench_run, serve_cell as sc, traffic
+    from bench.tests import toy_arch
+    sys.modules["bench.arch.toy"] = toy_arch
+    m, mix = serve_cell()
+    m.update(arch="toy", windows=TOY_WINDOWS)
+    bm = bench_run.benchmark()
+    cell = bench_run.cell_of(bm, "olmo-1b.serve-long")
+    bench_run.load_config = lambda name: m
+    traffic.load = lambda name: mix
+    rec = bench_run.run_cell(bm, cell, SEED, 2.0, False)
+    reqs = traffic.serve_requests(mix, SEED, 2.0, m["vocab"])
+    toy_arch.make_weights = toy_arch.make_weights_unmuted
+    unmuted = sc.run(m, mix, SEED, 2.0, None, clock())["check"]["numbers"]
+    return {"result": rec["result"], "work": rec["work"],
+            "requests": [[len(r.prompt), r.max_new] for r in reqs],
+            "unmuted": unmuted}
+
+
 def main(argv) -> int:
     import jax
     jax.config.update("jax_cpu_enable_async_dispatch", False)
-    if argv[1:] != ["serve"]:
-        raise SystemExit("usage: python -m bench.tests.smoke serve")
-    print(json.dumps(serve_results()))
+    runs = {"serve": serve_results, "toy": toy_results}
+    if len(argv) != 2 or argv[1] not in runs:
+        raise SystemExit("usage: python -m bench.tests.smoke serve|toy")
+    print(json.dumps(runs[argv[1]]()))
     return 0
 
 

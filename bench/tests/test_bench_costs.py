@@ -14,6 +14,27 @@ def test_chunk_pairs_counts_the_visible_keys(start, n):
     assert costs.chunk_pairs(start, n) == int((k <= q).sum())
 
 
+@pytest.mark.parametrize("start,n,window", [
+    (0, 7, 3), (5, 3, 1), (5, 3, 8), (0, 1, 1), (512, 512, 1024),
+    (1536, 256, 1024), (1000, 48, 1024), (100, 50, 4096)])
+def test_windowed_chunk_counts_the_keys_in_the_window(start, n, window):
+    """The causal mask above, cut to the last ``window`` keys a query
+    sees: the pairs, and the keys the chunk reads between its queries."""
+    q = np.arange(start, start + n)[:, None]
+    k = np.arange(start + n)[None, :]
+    seen = (k <= q) & (k > q - window)
+    assert costs.chunk_pairs(start, n, window) == int(seen.sum())
+    assert costs.keys_read(start, n, window) == int(seen.any(0).sum())
+
+
+@pytest.mark.parametrize("window", [None, 1, 16, 1024])
+def test_a_decode_step_sees_the_lesser_of_its_position_and_window(window):
+    for p in (0, 1, 15, 16, 1023, 1024, 5000):
+        want = p + 1 if window is None else min(p + 1, window)
+        assert costs.chunk_pairs(p, 1, window) == want
+        assert costs.keys_read(p, 1, window) == want
+
+
 @pytest.mark.parametrize("seq,chunk", [(1792, 512), (100, 32), (64, 64)])
 def test_attention_work_is_the_same_however_it_is_chunked(seq, chunk):
     whole = costs.attention_flops(16, 128, costs.causal_pairs(seq))

@@ -56,10 +56,10 @@ def test_paged_kernels_match_the_reference_over_page_splits():
     last = fa.flash_hyft_decode_paged(
         _heads_first(q[-1:])[None], kp, vp, bt, HYFT16, interpret=True,
         k_scale=ks, v_scale=vs)[0]
-    ref = R._hyft_splits(q, kd, vd, dict(HY), ps, rows=16)
+    ref = R.hyft_splits(q, kd, vd, dict(HY), ps, rows=16)
     assert _close(_heads_first(chunk), ref)
     assert _close(last[:, 0], ref[-1])
-    exact = R._exact(q, kd, vd)
+    exact = R.exact(q, kd, vd)
     assert float(jnp.max(jnp.abs(exact - ref))) > 0.05
 
 
@@ -75,14 +75,33 @@ def test_fused_training_kernel_matches_the_online_reference():
             t(q), t(k), t(v), HYFT16, interpret=True)[0])
 
     o1, vjp1 = jax.vjp(prog, q, k, v)
-    o2, vjp2 = jax.vjp(lambda *a: R._hyft_online(*a, HY, 128), q, k, v)
+    o2, vjp2 = jax.vjp(lambda *a: R.hyft_online(*a, HY, 128), q, k, v)
     assert _close(o1, o2)
     assert all(_close(a, b) for a, b in zip(vjp1(do), vjp2(do)))
-    o3, vjp3 = jax.vjp(R._exact, q, k, v)
+    o3, vjp3 = jax.vjp(R.exact, q, k, v)
     assert float(jnp.max(jnp.abs(o3 - o2))) > 0.05
 
 
 def test_attention_follows_the_configuration():
-    assert R.attention({"softmax_impl": "exact"}) is R._exact
+    assert R.attention({"softmax_impl": "exact"}) is R.exact
     with pytest.raises(ValueError):
         R.attention({"softmax_impl": "koca"})
+
+
+def test_a_window_hides_the_keys_before_it():
+    """With ``window`` the last query reads only its last ``window``
+    positions: K and V before them change nothing, bit for bit, and one
+    inside them does; a window as long as the sequence is no window."""
+    S, ps, w = 64, 16, 24
+    q, k, v = _qkv(3, S)
+    ref = R.hyft_splits(q, k, v, dict(HY), ps, rows=16, window=w)
+    assert (R.hyft_splits(q, k, v, dict(HY), ps, rows=16, window=S)
+            == R.hyft_splits(q, k, v, dict(HY), ps, rows=16)).all()
+    early = S - w
+    far = R.hyft_splits(q, k.at[:early].multiply(-3.0),
+                        v.at[:early].add(5.0), dict(HY), ps, rows=16,
+                        window=w)
+    assert (far[-1] == ref[-1]).all()
+    near = R.hyft_splits(q, k, v.at[early].add(5.0), dict(HY), ps, rows=16,
+                         window=w)
+    assert float(jnp.max(jnp.abs(near[-1] - ref[-1]))) > 0.01

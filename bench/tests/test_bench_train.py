@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import train_cell, weights
+from bench import arch, train_cell
 from bench.tests import smoke
 
 
@@ -50,7 +50,8 @@ def test_fault_is_not_correct(fault):
 
 def test_the_control_is_not_correct():
     m, job = smoke.train_cell()
-    params0 = weights.make(m, m["train"]["param_dtype"], smoke.SEED)
+    params0 = arch.load(m).make_weights(m, m["train"]["param_dtype"],
+                                        smoke.SEED)
     feed = train_cell.make_feed(m, job, smoke.SEED)
     batches = [feed(i) for i in range(job["check_steps"])]
     ref = train_cell.reference_steps(m, job, params0, batches)

@@ -20,7 +20,7 @@ import time
 
 import numpy as np
 
-from bench import costs
+from bench import arch, costs
 from bench.serve_cell import peak_bytes
 
 
@@ -51,7 +51,7 @@ def build(m: dict, job: dict, seed: int):
                            weight_decay=job["weight_decay"])
     mesh = _mesh(job["mesh"])
     _, state, step, state_sh = build_trainer(cfg, tcfg, ocfg, mesh, seed=seed)
-    params0 = weights.make(m, tr["param_dtype"], seed)
+    params0 = arch.load(m).make_weights(m, tr["param_dtype"], seed)
     weights.check_layout(params0, state["params"])
     with mesh:
         # fresh buffers: the step donates its state, and params0 must
@@ -189,6 +189,7 @@ def reference_steps(m: dict, job: dict, params0, batches, quant=None):
     import jax.numpy as jnp
 
     from bench import reference
+    loss_and_grad = arch.load(m).loss_and_grad
     mk = reference.sizes_key(m)
     hp = {k: job[k] for k in ("lr", "b1", "b2", "eps", "weight_decay",
                               "grad_clip", "warmup_steps", "total_steps")}
@@ -199,9 +200,9 @@ def reference_steps(m: dict, job: dict, params0, batches, quant=None):
                   static_argnames=())
     losses, g1 = [], None
     for i in range(job["check_steps"]):
-        val, g = reference.loss_and_grad(mk, p, batches[i], job["z_loss"],
-                                         job["reference_rows"], quant=quant,
-                                         block=m["train"]["attention_block"])
+        val, g = loss_and_grad(mk, p, batches[i], job["z_loss"],
+                               job["reference_rows"], quant=quant,
+                               block=m["train"]["attention_block"])
         p, opt, gc_ = upd(opt, p, g, i)
         losses.append(float(val))
         if i == 0:

@@ -1,5 +1,6 @@
 """Weights made by the benchmark from ``--seed``, on the device, in one
-jitted call, in the layout the program reads.
+jitted call, in the layout the program reads: each architecture's
+``make_weights`` (``bench/arch/``) draws them with ``key_of`` and ``normal``.
 
 Both the program and the plain reference are given these arrays; neither
 makes its own.  The distribution is the benchmark's, chosen so that a
@@ -15,52 +16,9 @@ import jax
 import jax.numpy as jnp
 
 
-def _normal(key, shape, std, dtype):
+def normal(key, shape, std, dtype):
+    """``shape`` drawn from N(0, std**2) at float32, stored in ``dtype``."""
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
-def _norm(key, m: dict, layers: int | None, dtype):
-    """The program's parameters of one norm ({} for OLMo's non-parametric
-    LayerNorm), stacked over ``layers`` when given."""
-    if m["norm"] == "np_ln":
-        return {}
-    lead = () if layers is None else (layers,)
-    k1, k2 = jax.random.split(key)
-    p = {"scale": (1.0 + _normal(k1, lead + (m["d_model"],), 0.1,
-                                 jnp.float32)).astype(dtype)}
-    if m["norm"] == "ln":
-        p["bias"] = _normal(k2, lead + (m["d_model"],), 0.1, dtype)
-    return p
-
-
-def make(m: dict, dtype: str, seed: int):
-    """The parameter tree of the dense decoder ``m`` (a configuration's
-    sizes) in ``dtype``, drawn from ``seed`` in one jitted call."""
-    dt = jnp.dtype(dtype)
-    L, d, h, kv, dh, ff, v = (m["n_layers"], m["d_model"], m["n_heads"],
-                              m["n_kv_heads"], m["d_head"], m["d_ff"],
-                              m["vocab"])
-
-    def build(key):
-        ks = iter(jax.random.split(key, 16))
-        attn = {"wq": _normal(next(ks), (L, d, h, dh), d ** -0.5, dt),
-                "wk": _normal(next(ks), (L, d, kv, dh), d ** -0.5, dt),
-                "wv": _normal(next(ks), (L, d, kv, dh), d ** -0.5, dt),
-                "wo": _normal(next(ks), (L, h, dh, d), (h * dh) ** -0.5, dt)}
-        mlp = {"w_up": _normal(next(ks), (L, d, ff), d ** -0.5, dt),
-               "w_down": _normal(next(ks), (L, ff, d), ff ** -0.5, dt)}
-        if m["mlp_gated"]:
-            mlp["w_gate"] = _normal(next(ks), (L, d, ff), d ** -0.5, dt)
-        p = {"embed": {"table": _normal(next(ks), (v, d), d ** -0.5, dt)},
-             "final_norm": _norm(next(ks), m, None, dt),
-             "blocks": {"norms": {"pre_attn": _norm(next(ks), m, L, dt),
-                                  "pre_mlp": _norm(next(ks), m, L, dt)},
-                        "attn": attn, "mlp": mlp}}
-        if not m["tie_embeddings"]:
-            p["unembed"] = {"table": _normal(next(ks), (v, d), d ** -0.5, dt)}
-        return p
-
-    return jax.jit(build)(key_of(seed))
 
 
 def key_of(seed: int, *salt: int):
