@@ -51,9 +51,11 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -517,16 +519,17 @@ def _fx_scores(z, mask, cfg: HyftConfig):
     return nm.fp2fx(z, cfg.frac_bits, cfg.total_bits)
 
 
-def _hyft_split(z_raw, cfg: HyftConfig):
-    """Hyft stages 1-2 of one split over the last axis: the (strided) local
-    max, the exponent unit, the adder tree's fixed-point sum, and the
+def _hyft_split(z_raw, cfg: HyftConfig, axis: int = -1):
+    """Hyft stages 1-2 of one split over ``axis`` (the keys): the (strided)
+    local max, the exponent unit, the adder tree's fixed-point sum, and the
     probabilities as assembled floats for the P.V product.  Returns
     (m_loc raw, l_loc, p), the stats keepdims."""
-    zsub = z_raw[..., :: cfg.step] if cfg.step > 1 else z_raw
-    m_loc = jnp.max(zsub, axis=-1, keepdims=True)
+    zsub = (jax.lax.slice_in_dim(z_raw, 0, None, cfg.step, axis)
+            if cfg.step > 1 else z_raw)
+    m_loc = jnp.max(zsub, axis=axis, keepdims=True)
     e, m = nm.exp_unit(z_raw - m_loc, cfg.frac_bits, cfg.mant_bits)
     addend = nm.expfloat_to_fx(e, m, cfg.mant_bits, cfg.acc_bits)
-    l_loc = jnp.sum(addend, axis=-1, keepdims=True)
+    l_loc = jnp.sum(addend, axis=axis, keepdims=True)
     p = ((1 << cfg.mant_bits) + m).astype(F32) * nm.pow2_float(e - cfg.mant_bits)
     return m_loc, l_loc, p
 
@@ -550,23 +553,30 @@ def _decode_tile(q, k, v, maskrow, cfg: HyftConfig, sm_scale: float):
     return acc, m_loc, l_loc
 
 
-def _splitk_combine(acc, m_st, l_st, cfg: HyftConfig):
-    """L2 of the decode tree: merge per-split Hyft stats across the split
-    axis (axis 1) — integer max over split maxima, per-split rescale by the
-    Hyft-approximated exp of the max delta, fixed-point sum merge, the acc
-    sum in split order, one finalize.  acc (..., ns, [rows,] D); m_st (i32)
-    and l_st (f32) of acc's shape, their lane 0 holding the stat.  Shared
-    by the XLA combine of the per-split grid (acc (BH, ns, gp, D), stats
-    broadcast over 128 lanes) and the paged decode kernel, which runs it
-    in VMEM at a slot's last page block (acc (Hq, nb, D), stats (Hq, nb,
-    1): a query head's pages are its splits): identical inputs in
-    identical split order give bitwise-identical outputs.
-    """
-    m_loc = m_st[..., :1]
-    l_loc = l_st[..., :1]
+def _splitk_weights(m_loc, l_loc, cfg: HyftConfig):
+    """The split weights of the L2 tree, over the split axis (axis 1):
+    integer max over split maxima, each split's rescale ``alpha`` by the
+    Hyft-approximated exp of its max delta, and the merged fixed-point sum
+    (the split axis summed out)."""
     m_glob = jnp.max(m_loc, axis=1, keepdims=True)
     alpha = hyft_alpha(m_loc - m_glob, cfg)     # per-split rescale
     l_glob = jnp.sum(nm.fx_quantize(l_loc * alpha, cfg.acc_bits), axis=1)
+    return alpha, l_glob
+
+
+def _splitk_combine(acc, m_loc, l_loc, cfg: HyftConfig):
+    """L2 of the decode tree: merge per-split Hyft stats across the split
+    axis (axis 1) — ``_splitk_weights``, the acc sum in split order, one
+    finalize.  acc (..., ns, [rows,] D); m_loc (i32) and l_loc (f32)
+    broadcast against it.  Shared by the XLA combine of the per-split grid
+    (acc (BH, ns, gp, D), stats (BH, ns, gp, 1)) and the paged decode
+    kernel, which runs it in VMEM at a slot's last page block (acc (Hq,
+    nb, D), stats (Hq, nb, 1): a query head's pages are its splits); the
+    paged verify kernel sums the acc in the same split order in a loop:
+    identical inputs in identical split order give bitwise-identical
+    outputs.
+    """
+    alpha, l_glob = _splitk_weights(m_loc, l_loc, cfg)
     acc_glob = jnp.sum(acc * alpha, axis=1)     # (..., [rows,] D)
     return hyft_finalize(acc_glob, l_glob, cfg)
 
@@ -616,7 +626,8 @@ def _splitk_stats(q3, k, v, mask, *, k_scale, v_scale, block_tables, Hkv: int,
                   bk: int, cfg: HyftConfig, sm_scale: float, group: int,
                   interpret: bool, name: str):
     """Run ``_splitk_kernel`` over a (batch, KV split, kv head) grid and
-    return the per-split local stats (acc, m, l) for ``_splitk_combine``.
+    return the per-split local stats (acc, m, l) for ``_splitk_combine``
+    (the stats as (..., 1) columns).
 
     q3: (B * Hkv, rows, D).  Contiguous layout: k/v (B * Hkv, Sk, D),
     scales (B, Hkv, Sk), mask (B, mr, Sk), splits of ``bk``.  Paged layout
@@ -663,12 +674,15 @@ def _splitk_stats(q3, k, v, mask, *, k_scale, v_scale, block_tables, Hkv: int,
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B, ns, Hkv), in_specs=in_specs,
             out_specs=out_specs)
-        return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                              interpret=interpret, name=name)(
-            block_tables.astype(I32), *operands)
-    return pl.pallas_call(kern, grid=(B, ns, Hkv), in_specs=in_specs,
-                          out_specs=out_specs, out_shape=out_shape,
-                          interpret=interpret, name=name)(*operands)
+        acc, m_st, l_st = pl.pallas_call(
+            kern, grid_spec=grid_spec, out_shape=out_shape,
+            interpret=interpret, name=name)(block_tables.astype(I32),
+                                            *operands)
+    else:
+        acc, m_st, l_st = pl.pallas_call(
+            kern, grid=(B, ns, Hkv), in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, interpret=interpret, name=name)(*operands)
+    return acc, m_st[..., :1], l_st[..., :1]  # lane 0 holds each stat
 
 
 def _pad_kv(k, v, k_scale, v_scale, pad_k):
@@ -782,11 +796,11 @@ def flash_hyft_decode(q: jax.Array, k: jax.Array, v: jax.Array,
 # the XLA combine — the same per-page arithmetic and the same merge, at
 # the per-step cost the block grid avoids.
 #
-# Contiguous decode and chunk verify keep the per-split grid
-# (``_splitk_stats``) and the XLA combine: verify masks each chunk lane by
-# its own causal frontier and carries up to ``VERIFY_LANE_BLOCK`` lanes a
-# page, whose per-page stats would not fit a slot's VMEM, and contiguous
-# decode (the model drafter's) has no block table to gather through.
+# The paged verify kernel below runs the same page-block grid with the
+# chunk's lanes as a further grid axis, and falls back to the per-split
+# grid past the same cap.  Contiguous decode and verify (the model
+# drafter's dense cache) keep the per-split grid and the XLA combine: they
+# have no block table to gather through.
 
 PAGED_VMEM_CAP = 64 << 20  # half a v5e core's 128 MiB of VMEM
 
@@ -994,13 +1008,44 @@ def flash_hyft_decode_paged(q: jax.Array, k_pages: jax.Array,
 # stripes split by ``block_k``, or a paged pool + scalar-prefetched block
 # tables with pages as splits.  fp2fx8 dequantization fuses into the K/V
 # loads exactly as in the decode kernels.
+#
+# Paged, the chunk is a prompt chunk of up to 512 lanes against 2048-token
+# tables, and the kernel is the paged decode kernel's page-block machine
+# with two more grid axes: (slot, lane block, kv head, block of P pages).
+# A lane block holds ``VERIFY_LANE_BLOCK // group`` chunk lanes, so its
+# (group x lane) rows fill one tile of 128 lanes at olmo-1b's group of 1.
+# The scores run transposed, (keys, rows): one (P * ps, D) x (rows, D)^T
+# product a block, then every page's strided max, exponent unit and
+# fixed-point sum reduce over its ps key sublanes with the rows dense on
+# the lanes; the page's probabilities, back as (rows, ps), meet V in
+# ``_decode_tile``'s (rows, ps) x (ps, D) product.  Each page is a split
+# of its own, exactly as ``_hyft_split`` computes it on the per-split
+# grid.  A (slot, lane block, kv head)'s per-page stats — acc (pages,
+# rows, D), max and fixed sum (pages, 1, rows), 72 KB a page at 128 rows
+# — stay in VMEM, and at the last page block ``_splitk_weights`` and the
+# acc sum in page order merge them there: only the (rows, D) result
+# leaves the kernel.
+#
+# A lane block sees no key past its last lane's causal frontier.  The
+# wrapper reads from the mask how many pages each (slot, lane block) can
+# see (``verify_live_pages``) and scalar-prefetches it; past them the
+# index maps repeat the last live block, so no DMA is issued, the step's
+# compute is skipped, and the merge covers the live pages alone.  A page
+# past every lane's frontier would enter the per-split merge with the
+# weight ``hyft_alpha(fx_lo - m_glob)``, about 2^-46 for a row whose
+# visible max is near 0 (the fx floor is -32 at hyft16): its fixed sum
+# quantizes to 0 and its acc falls below half an ulp of any result not
+# near 0, so the skip leaves those bitwise unchanged.  A lane that sees no
+# key at all has no defined result (its caller discards it); here it
+# reads 0.
 
 
-# Most chunk lanes per verify kernel call.  The per-split stats are
-# (B * Hkv, splits, Hq // Hkv * lanes, D) fp32, so a 512-token chunk over
-# 16-token pages would hold ~3 GB of them per layer at olmo-1b widths; a
-# longer chunk runs as a ``lax.map`` over lane blocks, each lane's
-# arithmetic unchanged.
+# Chunk lanes per verify kernel call at a GQA group of 1.  The contiguous
+# layout's per-split stats are (B * Hkv, splits, Hq // Hkv * lanes, D)
+# fp32, so a longer chunk runs as a ``lax.map`` over lane blocks, each
+# lane's arithmetic unchanged.  The paged kernel takes ``VERIFY_LANE_BLOCK
+# // group`` lanes a block (``paged_verify_plan``), so a tile's (group x
+# lane) rows stay near this many, and runs the lane blocks as a grid axis.
 VERIFY_LANE_BLOCK = 128
 
 
@@ -1012,6 +1057,183 @@ def _verify_mask_rows(mask, group: int):
     sp, bk = mask.shape
     return jnp.broadcast_to(mask[None], (group, sp, bk)).reshape(
         group * sp, bk)
+
+
+class VerifyPlan(NamedTuple):
+    """The paged verify kernel's grid: ``lanes`` chunk lanes a lane block,
+    ``nq`` lane blocks, ``P`` pages a page block, ``nblk`` page blocks,
+    and its VMEM limit in bytes."""
+    lanes: int
+    nq: int
+    P: int
+    nblk: int
+    vmem: int
+
+
+def paged_verify_plan(Hq: int, Hkv: int, D: int, page_size: int, nb: int,
+                      Sq: int, kv_dtype, quantized: bool) -> VerifyPlan:
+    """The paged verify kernel's grid and VMEM, from the shapes alone.
+    Lanes a block: ``VERIFY_LANE_BLOCK // group`` in whole sublane tiles
+    (at least 8, at most the chunk), so (group x lanes) rows stay near 128.
+    Pages a block as ``paged_decode_plan``.  The bytes are the padded
+    per-page stats, the double-buffered blocks and 16 MiB for the step's
+    and the merge's temporaries; past ``PAGED_VMEM_CAP`` the call runs the
+    per-split grid."""
+    g = Hq // Hkv
+    lanes = min(-(-Sq // 8) * 8, max(8, VERIFY_LANE_BLOCK // g // 8 * 8))
+    rows = g * lanes
+    P = min(nb, 128 // math.gcd(page_size, 128))
+    nblk = -(-nb // P)
+    npp = nblk * P
+    stats = [((npp, rows, D), F32), ((npp, 1, rows), I32),
+             ((npp, 1, rows), F32)]
+    blocks = [((rows, D), F32), ((P * page_size, rows), jnp.int8),
+              ((rows, D), F32)]
+    blocks += [((page_size, D), kv_dtype)] * 2 * P
+    if quantized:
+        blocks += [((page_size, 1), F32)] * 2 * P
+    vmem = (sum(_vmem_bytes(*x) for x in stats)
+            + 2 * sum(_vmem_bytes(*x) for x in blocks) + (16 << 20))
+    return VerifyPlan(lanes, -(-Sq // lanes), P, nblk, vmem)
+
+
+def verify_live_pages(frontier, lanes: int, page_size: int):
+    """Pages each lane block of a paged verify call can see: one past the
+    page that holds the last key visible to any of its lanes, 0 where none
+    sees a key.  ``frontier`` (B, Sq) is each chunk lane's last visible KV
+    index (-1 for none); returns (B, lane blocks).  Takes numpy on the host
+    (the scheduler's counters) and jax arrays in the kernel's wrapper."""
+    xp = np if isinstance(frontier, np.ndarray) else jnp
+    B, Sq = frontier.shape
+    pad = -Sq % lanes
+    f = xp.concatenate([frontier, xp.full((B, pad), -1, frontier.dtype)], 1)
+    return f.reshape(B, -1, lanes).max(-1) // page_size + 1
+
+
+def _paged_verify_kernel(bt_ref, live_ref, q_ref, mask_ref, *refs,
+                         cfg: HyftConfig, sm_scale: float, P: int, nq: int,
+                         quantized: bool):
+    pages = [refs[n * P:(n + 1) * P] for n in range(4 if quantized else 2)]
+    o_ref, acc_sc, m_sc, l_sc = refs[len(pages) * P:]
+    ps = pages[0][0].shape[2]
+    b, qi, h, i = (pl.program_id(a) for a in range(4))
+    live = live_ref[b * nq + qi]                  # pages this block can see
+
+    def load(n):
+        """Head h's pages of the block, K (n = 0) or V (n = 1), each (ps,
+        D) fp32, fp2fx8 dequant fused: each token row times its scale."""
+        x = [page[0, 0].astype(F32) for page in pages[n]]
+        if quantized:
+            x = [t * s[0, 0] for t, s in zip(x, pages[n + 2])]
+        return x
+
+    @pl.when(i * P < live)
+    def _step():
+        q = q_ref[0, 0].astype(F32)                       # (rows, D)
+        k = jnp.concatenate(load(0), axis=0)              # (P * ps, D)
+        z = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                preferred_element_type=F32) * sm_scale
+        z_raw = _fx_scores(z, mask_ref[0, 0].astype(F32), cfg)
+        # one split per page: its ps key sublanes, the rows on the lanes
+        m_loc, l_loc, p = _hyft_split(z_raw.reshape(P, ps, -1), cfg, axis=1)
+        # P.V a page as ``_decode_tile``'s (rows, ps) x (ps, D) product
+        p = jnp.swapaxes(p, 1, 2)
+        for j, v in enumerate(load(1)):
+            acc_sc[i * P + j] = jax.lax.dot_general(
+                p[j], v, (((1,), (0,)), ((), ())), preferred_element_type=F32)
+        blk = pl.ds(i * P, P)
+        m_sc[blk] = m_loc
+        l_sc[blk] = l_loc
+
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _merge():  # L2 over the live pages; the rest weigh nothing
+        valid = jax.lax.broadcasted_iota(I32, m_sc.shape, 0) < live
+        m = jnp.where(valid, m_sc[...], -(1 << (cfg.total_bits - 1)))
+        l = jnp.where(valid, l_sc[...], F32(0))
+        alpha, l_glob = _splitk_weights(m[None], l[None], cfg)
+        l_sc[...] = alpha[0]
+        acc = jax.lax.fori_loop(
+            0, live, lambda j, a: a + acc_sc[j] * _column(l_sc[j]),
+            jnp.zeros(acc_sc.shape[1:], F32))             # (rows, D)
+        o_ref[0, 0] = hyft_finalize(acc, _column(l_glob[0]), cfg)
+
+
+def _column(x):
+    """A (1, rows) lane row as a (rows, 1) column."""
+    return jnp.broadcast_to(x, (8, x.shape[1])).T[:, :1]
+
+
+def _verify_paged_blocks(q, k_pages, v_pages, maskf, block_tables, k_scale,
+                         v_scale, plan: VerifyPlan, cfg: HyftConfig,
+                         scale: float, interpret: bool):
+    """``flash_hyft_verify`` on a paged pool through the page-block grid
+    of ``_paged_verify_kernel``: (slot, lane block, kv head, page block)."""
+    B, Hq, Sq, D = q.shape
+    _, Hkv, ps, _ = k_pages.shape
+    nb = block_tables.shape[1]
+    g = Hq // Hkv
+    lanes, nq, P, nblk, vmem = plan
+    rows, npp = g * lanes, nblk * P
+    pad_q, pad_k = nq * lanes - Sq, (npp - nb) * ps
+    quantized = k_scale is not None
+    # a lane block's rows are (group member, lane), as the per-split grid's
+    q3 = _pad0(q.reshape(B, Hkv, g, Sq, D),
+               ((0, 0), (0, 0), (0, 0), (0, pad_q), (0, 0)))
+    q3 = q3.reshape(B, Hkv, g, nq, lanes, D).transpose(0, 1, 3, 2, 4, 5)
+    q3 = q3.reshape(B * Hkv, nq, rows, D)
+    front = jnp.max(jnp.where(maskf > 0, jnp.arange(nb * ps), -1), axis=-1)
+    live = verify_live_pages(front, lanes, ps).astype(I32).reshape(-1)
+    # the mask transposed to (keys, rows) tiles, as int8 0/1; padding lanes
+    # and padding page slots (the null page) are masked
+    mask = _pad0(maskf, ((0, 0), (0, pad_q), (0, pad_k))) > 0
+    mask = jnp.broadcast_to(mask.reshape(B, nq, 1, lanes, npp * ps),
+                            (B, nq, g, lanes, npp * ps))
+    mask = mask.reshape(B, nq, rows, npp * ps).transpose(0, 1, 3, 2)
+    mask = mask.astype(jnp.int8)
+    bt = _pad0(block_tables.astype(I32), ((0, 0), (0, npp - nb))).reshape(-1)
+
+    def blk(b, qi, i, live):
+        """Page block i, or past the lane block's live pages its last live
+        block again (the same block index: no new DMA)."""
+        n = jax.lax.div(live[b * nq + qi] + (P - 1), P)
+        return jnp.minimum(i, jnp.maximum(n - 1, 0))
+
+    def page_specs(block):
+        """One BlockSpec per page slot j of a block, routed through the
+        block table to head h of the page."""
+        return [pl.BlockSpec(block, lambda b, qi, h, i, bt, live, j=j: (
+            bt[b * npp + blk(b, qi, i, live) * P + j], h, 0, 0))
+            for j in range(P)]
+    rows_spec = pl.BlockSpec((1, 1, rows, D),
+                             lambda b, qi, h, i, *_: (b * Hkv + h, qi, 0, 0))
+    in_specs = [rows_spec,
+                pl.BlockSpec((1, 1, P * ps, rows),
+                             lambda b, qi, h, i, bt, live:
+                             (b, qi, blk(b, qi, i, live), 0))]
+    in_specs += page_specs((1, 1, ps, D)) * 2
+    operands = [q3, mask] + [k_pages] * P + [v_pages] * P
+    if quantized:  # scales as (ps, 1) token columns, broadcast over D
+        in_specs += page_specs((1, 1, ps, 1)) * 2
+        operands += [k_scale[..., None]] * P + [v_scale[..., None]] * P
+    kern = functools.partial(_paged_verify_kernel, cfg=cfg, sm_scale=scale,
+                             P=P, nq=nq, quantized=quantized)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, nq, Hkv, nblk),
+            in_specs=in_specs, out_specs=rows_spec,
+            scratch_shapes=[pltpu.VMEM((npp, rows, D), F32),  # per-page acc
+                            pltpu.VMEM((npp, 1, rows), I32),  # max (raw)
+                            pltpu.VMEM((npp, 1, rows), F32)]),  # fixed sum
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, nq, rows, D), F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=interpret, name="flash_hyft_verify",
+    )(bt, live, *operands)
+    out = out.reshape(B, Hkv, nq, g, lanes, D).transpose(0, 1, 3, 2, 4, 5)
+    return out.reshape(B, Hq, nq * lanes, D)[:, :, :Sq]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -1042,18 +1264,34 @@ def flash_hyft_verify(q: jax.Array, k: jax.Array, v: jax.Array,
         ``kv_index <= pos + t`` plus any cache-length masking.  Ragged
         draft lengths across the batch ride in here (a padded draft row's
         outputs are discarded by the caller).
-    A chunk longer than ``VERIFY_LANE_BLOCK`` runs in lane blocks.
-    Returns (B, Hq, Sq, D) fp32.  Forward-only.  At Sq == 1 this is bitwise
-    identical to ``flash_hyft_decode`` (same splits) / ``_decode_paged``
-    (pages as splits): the tile arithmetic is the shared ``_decode_tile``
-    and the combine the shared ``_splitk_combine``; only the mask gained a
-    row axis.
+    Paged, the call runs the page-block grid (slot, lane block, kv head,
+    page block) with the page merge in the kernel, and skips the page
+    blocks past each lane block's last visible key; past
+    ``PAGED_VMEM_CAP`` of kept per-page stats it runs the per-split grid.
+    Contiguous, a chunk longer than ``VERIFY_LANE_BLOCK`` runs in lane
+    blocks of the per-split grid.  Returns (B, Hq, Sq, D) fp32.
+    Forward-only.  Every page (contiguous: every ``block_k`` block) is a
+    split with the shared per-split arithmetic (``_fx_scores``,
+    ``_hyft_split``) and the shared merge (``_splitk_weights``, the acc
+    summed in split order), so at Sq == 1 this is ``flash_hyft_decode``
+    (same splits) / ``flash_hyft_decode_paged`` (pages as splits) with
+    only the mask gaining a row axis: bitwise theirs in interpret mode
+    wherever the f32 products and merge round in the same order.
     """
     maskf = kv_pos_mask.astype(F32)       # (B, Sq, Lk)
+    B, Hq, Sq, D = q.shape
+    if block_tables is not None:
+        _, Hkv, ps, _ = k.shape
+        plan = paged_verify_plan(Hq, Hkv, D, ps, block_tables.shape[1], Sq,
+                                 k.dtype, k_scale is not None)
+        if plan.vmem <= PAGED_VMEM_CAP:
+            scale = sm_scale if sm_scale is not None else D ** -0.5
+            return _verify_paged_blocks(q, k, v, maskf, block_tables,
+                                        k_scale, v_scale, plan, cfg, scale,
+                                        interpret)
     kw = dict(cfg=cfg, sm_scale=sm_scale, block_k=block_k,
               interpret=interpret, block_tables=block_tables,
               k_scale=k_scale, v_scale=v_scale)
-    B, Hq, Sq, D = q.shape
     block_q = VERIFY_LANE_BLOCK
     if Sq <= block_q:
         return _verify_lanes(q, k, v, maskf, **kw)
@@ -1071,7 +1309,9 @@ def flash_hyft_verify(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _verify_lanes(q, k, v, maskf, *, cfg, sm_scale, block_k, interpret,
                   block_tables, k_scale, v_scale):
-    """``flash_hyft_verify`` on one block of chunk lanes (one kernel call)."""
+    """``flash_hyft_verify`` on one block of chunk lanes through the
+    per-split grid (one kernel call): the contiguous layout, and the paged
+    one past ``PAGED_VMEM_CAP``."""
     B, Hq, Sq, D = q.shape
     Hkv = k.shape[1]
     assert Hq % Hkv == 0

@@ -87,6 +87,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ServeConfig
+from repro.kernels import flash_attention as fa
 from repro.obs import Obs, compile_watch
 from repro.obs import numerics as obs_numerics
 from repro.serve import engine, kvpool
@@ -350,7 +351,8 @@ _STAT_COUNTERS = (
     "tokens_emitted", "prompt_tokens", "prefill_tokens", "cached_tokens",
     "prefix_hits", "preemptions", "model_calls", "spec_steps",
     "draft_tokens", "accepted_tokens", "rejected", "expired", "cancelled",
-    "quarantines", "fp32_retries", "failures", "stragglers", "audits")
+    "quarantines", "fp32_retries", "failures", "stragglers", "audits",
+    "verify_page_blocks_live", "verify_page_blocks_total")
 _STAT_GAUGES = ("peak_active", "pages_peak")
 
 
@@ -551,7 +553,8 @@ class SlotPoolEngine:
         for k in ("model_calls", "spec_steps", "draft_tokens",
                   "accepted_tokens", "rejected", "expired", "cancelled",
                   "quarantines", "fp32_retries", "failures", "stragglers",
-                  "audits"):
+                  "audits", "verify_page_blocks_live",
+                  "verify_page_blocks_total"):
             d[k] = self._counters[k].value
         return d
 
@@ -866,6 +869,8 @@ class SlotPoolEngine:
             if self.paged:
                 self.cache["block_tables"] = jnp.asarray(self.block_tables)
         with tracer.span("prefill_chunk", width=width, rows=len(rows)):
+            if self.paged:
+                self._count_verify_blocks(width)
             pc = engine.build_prefill_chunk(self.model,
                                             exec_key_cfg(scfg), width)
             # hand the call a copy of the host mirror: on the CPU backend
@@ -899,6 +904,29 @@ class SlotPoolEngine:
                     self._finish_prefill(s, int(tok0[s]), now, held)
             self._peak("peak_active", int(self.active.sum()))
             self._audit_check()
+
+    def _count_verify_blocks(self, width: int) -> None:
+        """Page blocks the chunk call's paged verify kernel computes
+        (``verify_page_blocks_live``) and all its grid holds
+        (``verify_page_blocks_total``), per (slot, lane block) of one
+        layer's call: planned here from the slots' lengths with the helpers
+        the kernel's wrapper plans with from its mask (every lane ``t`` of
+        slot ``b`` sees ``kv_index <= lengths[b] + t``).  Both are counters
+        of the metrics registry and a tracer counter event; past the VMEM
+        cap the per-split grid visits every page, so live == total."""
+        cfg, ps = self.model.cfg, self.scfg.page_size
+        plan = fa.paged_verify_plan(
+            cfg.n_heads, cfg.n_kv_heads, cfg.d_head, ps, self.n_blocks, width,
+            self.cache["blocks"]["k"].dtype, self._quantized)
+        front = np.minimum(self.lengths[:, None] + np.arange(width),
+                           self.n_blocks * ps - 1)
+        pages = fa.verify_live_pages(front, plan.lanes, ps)
+        total = pages.size * plan.nblk
+        live = (int((-(-pages // plan.P)).sum())
+                if plan.vmem <= fa.PAGED_VMEM_CAP else total)
+        self._count("verify_page_blocks_live", live)
+        self._count("verify_page_blocks_total", total)
+        self.obs.tracer.counter("verify_page_blocks", live=live, total=total)
 
     def _finish_prefill(self, s: int, tok0: int, now: float,
                         held: float) -> None:

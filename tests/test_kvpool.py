@@ -377,6 +377,83 @@ def test_paged_kernel_invariant_to_page_placement():
     assert jnp.all(base == shuf)
 
 
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "fp2fx8"])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2)], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("sq", [1, 5, 128, 200])
+def test_paged_verify_page_blocks(sq, hq, hkv, cache_dtype, monkeypatch):
+    """The paged verify kernel's page-block grid on a shuffled pool: 12
+    pages in blocks of 8 (four padding page slots reading a poisoned null
+    page), chunks whose rows start at 0, mid-page (37) and near the
+    table's end (150).
+
+    * the live-page plan from host lengths equals a count from the mask;
+    * skipping the page blocks past each lane block's frontier gives
+      bitwise the result of merging every page;
+    * against the per-split grid it replaced (still run past
+      ``PAGED_VMEM_CAP``): the same per-page splits and merge, so equal up
+      to f32 rounding order — on the CPU the dot products and the XLA
+      combine round in an order that depends on the program's shapes —
+      which moves an output by a few steps of its 10-bit mantissa."""
+    from repro.core.registry import hyft_config_for
+    from repro.kernels import flash_attention as fa
+    from repro.models.attention import fp2fx8_quantize
+    cfg = hyft_config_for("hyft16")
+    B, D, ps, nb = 3, 16, 16, 12
+    quantized = cache_dtype == "fp2fx8"
+    plan = fa.paged_verify_plan(hq, hkv, D, ps, nb, sq, jnp.int8, quantized)
+    assert (plan.P, plan.nblk) == (8, 2) and plan.vmem < fa.PAGED_VMEM_CAP
+    n_pages = 1 + B * nb
+    key = jax.random.PRNGKey(sq * 10 + hq)
+    q = jax.random.normal(key, (B, hq, sq, D))
+    kp = jax.random.normal(jax.random.fold_in(key, 1), (n_pages, hkv, ps, D))
+    vp = jax.random.normal(jax.random.fold_in(key, 2), (n_pages, hkv, ps, D))
+    vp = vp.at[0].multiply(1e12)  # the null page: padding slots read it
+    ks = vs = None
+    if quantized:
+        kp, ks = fp2fx8_quantize(kp)
+        vp, vs = fp2fx8_quantize(vp)
+    else:
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    perm = jax.random.permutation(jax.random.fold_in(key, 3), n_pages - 1)
+    bt = (1 + perm).astype(jnp.int32).reshape(B, nb)
+    start = np.array([0, 37, 150])
+    # each lane's frontier as prefill_chunk builds it: kv_index <= start + t
+    front = np.minimum(start[:, None] + np.arange(sq), nb * ps - 1)
+    mask = jnp.arange(nb * ps)[None, None, :] <= front[:, :, None]
+    kw = dict(interpret=True, block_tables=bt, k_scale=ks, v_scale=vs)
+    verify = fa.flash_hyft_verify.__wrapped__  # reads the patches below
+    out = verify(q, kp, vp, mask, cfg, **kw)
+    assert out.shape == (B, hq, sq, D) and jnp.all(jnp.isfinite(out))
+
+    live = fa.verify_live_pages(front, plan.lanes, ps)
+    assert live.shape == (B, plan.nq)
+    for b in range(B):
+        for i in range(plan.nq):
+            seen = np.nonzero(np.asarray(
+                mask[b, i * plan.lanes:(i + 1) * plan.lanes]).any(0))[0]
+            assert live[b, i] == seen[-1] // ps + 1
+    assert live.min() < nb  # some lane blocks skip pages
+
+    with monkeypatch.context() as mp:  # every page of the table is live
+        mp.setattr(fa, "verify_live_pages",
+                   lambda f, lanes, ps: jnp.full(
+                       (f.shape[0], -(-f.shape[1] // lanes)), nb))
+        whole = verify(q, kp, vp, mask, cfg, **kw)
+    # a dead page weighs about 2^-46 here: bitwise wherever that is below
+    # half an ulp, i.e. all but the results near 0 (an fp2fx8 V of 0 under
+    # a lane's only visible key), where it is the masked keys' leak alone
+    near_zero = jnp.abs(whole) < 2.0 ** -20
+    assert jnp.all((out == whole) | near_zero), "skipping moved a bit"
+    assert jnp.max(jnp.abs(out - whole)) < 2.0 ** -30
+
+    monkeypatch.setattr(fa, "PAGED_VMEM_CAP", plan.vmem - 1)
+    kept = verify(q, kp, vp, mask, cfg, **kw)
+    # rounding apart: a few steps of the 10-bit mantissa, absolute at the
+    # outputs' scale (about 1) where a lane's sum cancels toward 0
+    np.testing.assert_allclose(out, kept, rtol=2.0 ** -8, atol=2.0 ** -12)
+    assert jnp.mean(out == kept) > 0.99
+
+
 # --------------------------------------------------------------------------
 # paged serving: parity, prefix-cache skip, preemption
 # --------------------------------------------------------------------------
@@ -431,6 +508,36 @@ def test_paged_matches_dense_slot_pool(cache_dtype):
     solo_cfg = ServeConfig(max_len=32, cache_dtype=cache_dtype)
     for r in reqs:
         assert outs["paged"][r.rid] == _solo(model, params, r, solo_cfg)
+
+
+def test_verify_page_block_counters():
+    """Each paged chunk call counts the verify kernel's page blocks: all
+    its grid holds (n_slots x lane blocks x page blocks, from the call's
+    width) and the live ones, fewer once short prompts leave pages past
+    every frontier; a tracer counter event carries both per call."""
+    from repro.kernels import flash_attention as fa
+    from repro.obs import Obs
+    from repro.serve.scheduler import SlotPoolEngine
+    cfg, model, params = _setup()
+    scfg = ServeConfig(max_len=256, cache_dtype="fp2fx8",
+                       scheduler="continuous", n_slots=3, decode_burst=4,
+                       kv_layout="paged", page_size=16, prefill_chunk=4)
+    eng = SlotPoolEngine(model, params, scfg, obs=Obs.enabled())
+    eng.run(_requests(cfg, 4, np.random.default_rng(1)))
+    ev = eng.obs.tracer.events
+    widths = [e["args"]["width"] for e in ev if e["name"] == "prefill_chunk"]
+    counts = [e["args"] for e in ev if e["name"] == "verify_page_blocks"]
+    assert len(widths) == len(counts) == eng.stats["prefills"] > 0
+    total = 0
+    for w in widths:
+        plan = fa.paged_verify_plan(cfg.n_heads, cfg.n_kv_heads, cfg.d_head,
+                                    16, 16, w, jnp.int8, True)
+        total += scfg.n_slots * plan.nq * plan.nblk
+    st = eng.stats
+    assert st["verify_page_blocks_total"] == total
+    assert sum(c["total"] for c in counts) == total
+    assert sum(c["live"] for c in counts) == st["verify_page_blocks_live"]
+    assert 0 < st["verify_page_blocks_live"] < total  # 2 blocks, 1 live
 
 
 def test_prefix_cache_skips_prefill_and_matches():

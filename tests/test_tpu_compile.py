@@ -184,14 +184,19 @@ def test_verify_dense(one_chip, sq, quantized):
     _assert_kernel(one_chip, fn, *shapes)
 
 
-@pytest.mark.parametrize("quantized", [False, True])
-def test_verify_paged(one_chip, quantized):
-    """The prefill-chunk path of paged serving, at the default page size."""
-    sq, n_pages = 512, B * NB + 1
+def _stats_buffers(text, bh, nb):
+    """Per-split stats buffers (B * Hkv, splits, rows, 128) in compiled HLO
+    text: what the per-split grid hands its XLA combine."""
+    return re.findall(rf"[fs]32\[{bh},{nb},\d+,128\]", text)
+
+
+def _verify_paged(one_chip, slots, nb, quantized):
+    """The plan and compiled text of a paged 512-lane verify call."""
+    sq, n_pages = 512, slots * nb + 1
     kv_dt = I8 if quantized else BF16
-    shapes = [((B, H, sq, D), BF16), ((n_pages, H, PS, D), kv_dt),
-              ((n_pages, H, PS, D), kv_dt), ((B, sq, CTX), F32),
-              ((B, NB), I32)]
+    shapes = [((slots, H, sq, D), BF16), ((n_pages, H, PS, D), kv_dt),
+              ((n_pages, H, PS, D), kv_dt), ((slots, sq, nb * PS), F32),
+              ((slots, nb), I32)]
     if quantized:
         shapes += [((n_pages, H, PS), F32)] * 2
 
@@ -200,7 +205,34 @@ def test_verify_paged(one_chip, quantized):
                                     block_tables=bt,
                                     k_scale=s[0] if s else None,
                                     v_scale=s[1] if s else None)
-    _assert_kernel(one_chip, fn, *shapes)
+    plan = fa.paged_verify_plan(H, H, D, PS, nb, sq, kv_dt, quantized)
+    return plan, _compiled_text(one_chip, fn, *shapes)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_verify_paged(one_chip, quantized):
+    """The prefill-chunk path of paged serving at the serving cell's shapes
+    (8 slots, 512-lane chunks, 2048-token tables of 16-token pages): the
+    page-block grid, one kernel whose one result is the merged (B * Hkv,
+    lane blocks, rows, D) output, and no per-split stats anywhere in the
+    program."""
+    plan, text = _verify_paged(one_chip, SLOTS, NB, quantized)
+    assert plan.vmem <= fa.PAGED_VMEM_CAP
+    assert _kernel_names(text) == {"flash_hyft_verify"}
+    results = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call", text)
+    assert (plan.lanes, plan.nq) == (128, 4) and len(results) == 1
+    assert results[0].startswith(f"f32[{SLOTS * H},4,128,{D}]{{")
+    assert not _stats_buffers(text, SLOTS * H, NB)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_verify_paged_past_the_vmem_cap(one_chip, quantized):
+    """16k-token tables keep more per-page stats than ``PAGED_VMEM_CAP``:
+    the call runs the per-split grid, whose kernel returns them."""
+    plan, text = _verify_paged(one_chip, 1, 1024, quantized)
+    assert plan.vmem > fa.PAGED_VMEM_CAP
+    assert _kernel_names(text) == {"flash_hyft_verify"}
+    assert _stats_buffers(text, H, 1024)
 
 
 @pytest.mark.parametrize("cfg", [HYFT16, HYFT32], ids=["hyft16", "hyft32"])
@@ -221,9 +253,11 @@ def _kernel_names(text):
 
 
 def test_chunk_kernel_is_named_inside_its_lane_map(one_chip):
-    """A 512-lane paged chunk runs the verify kernel under a ``lax.map``
-    over lane blocks; the kernel keeps its own name there, not the map
-    body's ``closed_call``, so a profile can tell it from other calls."""
+    """A 512-lane paged chunk runs its four 128-lane blocks as an axis of
+    the verify kernel's grid (they ran under a ``lax.map`` before the
+    page-block grid); the program holds that one kernel under its own
+    name, no map body's ``closed_call``, and no per-split stats buffer,
+    so a profile can tell it from other calls."""
     sq, n_pages = 512, B * NB + 1
     shapes = [((B, H, sq, D), BF16), ((n_pages, H, PS, D), I8),
               ((n_pages, H, PS, D), I8), ((B, sq, CTX), F32),
@@ -236,6 +270,7 @@ def test_chunk_kernel_is_named_inside_its_lane_map(one_chip):
     text = _compiled_text(one_chip, fn, *shapes)
     assert _kernel_names(text) == {"flash_hyft_verify"}
     assert not re.search(r"%closed_call\b", text)
+    assert not _stats_buffers(text, B * H, NB)
 
 
 def test_training_kernels_are_named(one_chip):
